@@ -45,7 +45,6 @@ CUDA kernels of the reference are Pallas kernels over a flat parameter arena.
 
 __version__ = "0.1.0"
 
-from apex_tpu import _compat  # noqa: F401  (installs jax API shims first)
 from apex_tpu import amp
 from apex_tpu import arena
 from apex_tpu import ckpt

@@ -1,84 +1,13 @@
-"""Version bridging for the range of jax releases apex_tpu runs on.
-
-The framework targets current jax (`jax.shard_map`, ``check_vma``,
-``jax_num_cpu_devices``); CI containers and user sites may pin older
-releases where the same capabilities live under experimental names
-(`jax.experimental.shard_map.shard_map` with ``check_rep``, the
-``--xla_force_host_platform_device_count`` XLA flag). This module
-installs the forward-looking spelling on import so the rest of the
-codebase is written once, against the modern API.
-
-Imported for its side effects at the top of ``apex_tpu/__init__``.
-"""
+"""Virtual CPU devices for the test and audit meshes."""
 
 from __future__ import annotations
 
 import jax
 
-__all__ = ["install", "request_cpu_devices"]
-
-
-def _shard_map_shim():
-    """Expose ``jax.shard_map(..., check_vma=...)`` on jax releases that
-    only ship ``jax.experimental.shard_map.shard_map(..., check_rep=...)``."""
-    if hasattr(jax, "shard_map"):
-        return
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, mesh=None, in_specs=None, out_specs=None,
-                  check_vma=None, **kwargs):
-        if check_vma is not None:
-            kwargs.setdefault("check_rep", check_vma)
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kwargs)
-
-    jax.shard_map = shard_map
-
-
-def _axis_size_shim():
-    """Expose ``jax.lax.axis_size(name)`` on jax releases that predate it
-    (an O(1) mesh-shape lookup; ``psum(1, name)`` is the portable
-    equivalent and compiles to the same constant inside collectives)."""
-    if hasattr(jax.lax, "axis_size"):
-        return
-
-    def axis_size(axis_name):
-        try:
-            from jax.core import get_axis_env  # very old spelling
-            return get_axis_env().axis_size(axis_name)
-        except Exception:
-            return jax.lax.psum(1, axis_name)
-
-    jax.lax.axis_size = axis_size
+__all__ = ["request_cpu_devices"]
 
 
 def request_cpu_devices(n: int) -> None:
-    """Ask for ``n`` virtual CPU devices, on whatever jax is installed.
-
-    Newer jax has the ``jax_num_cpu_devices`` config; older releases only
-    honor the XLA flag, which must land in the environment before the CPU
-    backend initializes (callers must invoke this before touching
-    ``jax.devices()``).
-    """
-    import os
-    import re
-    try:
-        jax.config.update("jax_num_cpu_devices", n)
-    except AttributeError:
-        flag = f"--xla_force_host_platform_device_count={n}"
-        flags = os.environ.get("XLA_FLAGS", "")
-        # replace an inherited count (e.g. a parent test process asked
-        # for a different mesh) rather than silently keeping it
-        flags, n_subbed = re.subn(
-            r"--xla_force_host_platform_device_count=\d+", flag, flags)
-        if not n_subbed:
-            flags = (flags + " " + flag).strip()
-        os.environ["XLA_FLAGS"] = flags
-
-
-def install() -> None:
-    _shard_map_shim()
-    _axis_size_shim()
-
-
-install()
+    """Ask for ``n`` virtual CPU devices. Call before anything touches
+    ``jax.devices()``: the count is read when the CPU backend starts."""
+    jax.config.update("jax_num_cpu_devices", n)

@@ -42,9 +42,10 @@ RANDOM_PRIMS = frozenset({
 #: operand IS the key material, so two wraps of one buffer is reuse
 KEY_WRAP_PRIMS = frozenset({"random_wrap"})
 
+# jax 0.9.0 traces ``jax.debug.print`` to its own ``debug_print``
+# primitive (``jax.debug.callback`` is still ``debug_callback``)
 CALLBACK_PRIMS = frozenset({
-    "debug_callback", "pure_callback", "io_callback", "outside_call",
-    "host_callback",
+    "debug_callback", "debug_print", "pure_callback", "io_callback",
 })
 
 MATMUL_PRIMS = frozenset({"dot_general", "conv_general_dilated"})

@@ -247,9 +247,14 @@ def _group_shape_findings(rank: int, schedule: Sequence[CollectiveInstr],
                           n_ranks: int) -> List[Finding]:
     """Per-module well-formedness: groups must be disjoint and (with
     global device ids) cover every rank — a rank left out of all groups
-    of an instruction it executes never joins the rendezvous."""
+    of an instruction it executes never joins the rendezvous.
+
+    Channel ids are not audited within a module: XLA (jaxlib 0.9.0)
+    numbers every collective of an SPMD-partitioned module
+    ``channel_id=1`` whatever its replica groups, so the id says
+    nothing about one module — it is compared only between ranks'
+    matched collectives (:func:`_first_mismatch`)."""
     out: List[Finding] = []
-    chan_groups: Dict[int, Tuple[Tuple[Tuple[int, ...], ...], str]] = {}
     for instr in schedule:
         seen: Set[int] = set()
         dup = [m for g in instr.replica_groups for m in g
@@ -276,20 +281,6 @@ def _group_shape_findings(rank: int, schedule: Sequence[CollectiveInstr],
                         f"no group",
                 op=instr.opcode, scope=instr.scope or instr.name,
                 ranks=[rank, missing[0]] if missing else None))
-        if instr.channel_id is not None and instr.replica_groups:
-            prev = chan_groups.get(instr.channel_id)
-            if prev is not None and prev[0] != instr.replica_groups:
-                out.append(Finding(
-                    rule="spmd-divergence",
-                    message=f"channel {instr.channel_id} is used with "
-                            f"two different replica-group sets "
-                            f"({prev[1]} vs {instr.name}) in one "
-                            f"module",
-                    op=instr.opcode,
-                    scope=instr.scope or instr.name))
-            else:
-                chan_groups[instr.channel_id] = (instr.replica_groups,
-                                                 instr.name)
     return out
 
 

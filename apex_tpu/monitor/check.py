@@ -16,11 +16,17 @@ from apex_tpu.prof import hlo as _hlo
 
 __all__ = ["HOST_TRAFFIC_MARKERS", "module_count_and_host_ops"]
 
-# HLO spellings of device→host traffic inside a compiled module: outfeed/
-# infeed pairs, raw send/recv, and the python-callback custom-call targets
+# HLO spellings of device→host traffic inside a compiled module. On the
+# TPU (libtpu 0.0.34, read off a compiled module on a v5e) every jax
+# callback — debug.print, pure_callback, io_callback — is a send/recv
+# pair with ``is_host_transfer=true``; XLA:CPU/GPU compile the same
+# callbacks to a custom call whose target ends ``python_cpu_callback`` /
+# ``python_gpu_callback`` (``xla_ffi_…`` on jax 0.9.0, ``xla_…`` before).
+# ``monitor/no-extra-dispatch`` seeds a jax.debug.print and asserts these
+# markers see it, so a renamed target cannot blind the detector again.
 HOST_TRAFFIC_MARKERS = (
     " outfeed(", " infeed(", " send(", " send-done(", " recv(",
-    " recv-done(", "xla_python_cpu_callback", "xla_python_gpu_callback",
+    " recv-done(", "python_cpu_callback", "python_gpu_callback",
     "tpu_host_callback", "HostCompute",
 )
 

@@ -15,7 +15,7 @@ On top of the in-graph counters the logger derives host-side health:
 - **MFU**, when the per-step model FLOPs are known — call ``attach()``
   with the jitted step and example args and they are taken from XLA's
   cost analysis (reusing :mod:`apex_tpu.prof.hlo`), the peak from
-  :func:`apex_tpu.prof.device_peak_flops` (unknown chips report
+  :data:`apex_tpu.prof.PEAK_FLOPS` (unknown chips report
   ``mfu=None``, never a misleading 0 — same contract as
   ``StepReport.table``);
 - **collective bytes per step** from the compiled HLO (see
@@ -248,8 +248,9 @@ class MetricsLogger:
         #: "Array has been deleted" by flush time.
         self.donation_safe = donation_safe
         if peak_flops is None:
-            from apex_tpu.prof.report import device_peak_flops
-            peak_flops = device_peak_flops() or None
+            from apex_tpu.prof.report import PEAK_FLOPS, lookup_peak
+            peak_flops = lookup_peak(
+                PEAK_FLOPS, jax.devices()[0].device_kind) or None
         self.peak_flops = peak_flops
         # buffered device snapshots + their host receipt times
         self._buf: List[Metrics] = []

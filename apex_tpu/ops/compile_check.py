@@ -10,8 +10,8 @@ the known-nasty shapes (short multi-head sequences, odd hidden widths,
 non-power-of-two block preferences, tail partitions) — on the attached
 accelerator, checking outputs against interpret-mode or jnp oracles.
 
-The driver-visible artifact is ``COMPILECHECK.json`` (written by
-``--json``; bench.py also triggers this after the headline metric).
+The artifact is the ``--json`` file. The command refuses to run off a
+TPU unless ``--interpret`` asks for the interpreted (CPU) run by name.
 """
 
 from __future__ import annotations
@@ -774,7 +774,8 @@ def _():
     monitored and unmonitored toy train steps compile to the same number
     of HLO modules (one executable each), and the monitored module
     contains no host traffic (outfeed/infeed/host callbacks) — telemetry
-    leaves the device only when the host logger flushes."""
+    leaves the device only when the host logger flushes. A seeded
+    ``jax.debug.print`` twin proves the detectors can fire at all."""
     from apex_tpu import amp
     from apex_tpu.monitor.check import module_count_and_host_ops
     from apex_tpu.optim import FusedSGD
@@ -803,6 +804,26 @@ def _():
     n_plain, _ = module_count_and_host_ops(plain_step, plain_state, x, y)
     assert n_mon == n_plain, (n_mon, n_plain)
     assert not host_mon, f"monitored step compiled host traffic: {host_mon}"
+
+    # the positive twin every "zero host ops" assert rests on: the same
+    # step with one seeded jax.debug.print MUST be seen — by the marker
+    # scan and by apexlint's jaxpr (APX004) and HLO (APX103) rules — on
+    # whatever spelling this jax/XLA gives a host callback
+    from apex_tpu import lint
+
+    def seeded_step(state, x, y):
+        state, loss = plain_step(state, x, y)
+        jax.debug.print("loss={l}", l=loss)
+        return state, loss
+
+    _, host_seeded = module_count_and_host_ops(
+        jax.jit(seeded_step), plain_state, x, y)
+    assert host_seeded, \
+        "seeded jax.debug.print invisible to HOST_TRAFFIC_MARKERS"
+    rep = lint.lint_step(jax.jit(seeded_step), plain_state, x, y)
+    for rule in ("host-callback-in-step", "host-transfer"):
+        assert rep.by_rule(rule), \
+            f"seeded jax.debug.print invisible to apexlint {rule}"
 
 
 # --- trace: span/probe zero-dispatch contract --------------------------------
@@ -1839,8 +1860,11 @@ def _():
         return
     n = len(local)
     mesh = Mesh(np.array(local), ("data",))
-    hlo_ddp, _ = _ddp_toy_step(mesh, n)
-    hlo_none, _ = _ddp_toy_step(mesh, n, comm_plan=None)
+    # compiled from ONE call site: the module text records the Python
+    # stack it was traced under (StackFrames tables; on a TPU also inside
+    # each Mosaic kernel's payload), so two lines give two texts
+    hlo_ddp, hlo_none = (_ddp_toy_step(mesh, n, **kw)[0]
+                         for kw in ({}, {"comm_plan": None}))
     assert hlo_none == hlo_ddp, (
         "comm_plan=None changed the compiled default DDP program")
 
@@ -1914,52 +1938,48 @@ def _():
             return y.sum() + scaled.sum() + ok.astype(jnp.float32)
         return step
 
+    # positive twin: an exact-key hit changes the realized block, hence
+    # the program
+    entry = autotune.TuningEntry(
+        family="layer_norm", dims=(96, 72), dtype="float32",
+        chip=autotune.chip_kind(), block={"block_rows": 32})
+    seeded = autotune.TuningDB({entry.fingerprint: entry})
+    # off: no consult. db: the committed DB, which these shapes are not
+    # in — exact-key miss, defaults. hit: the seeded DB.
+    variants = (("off", "off", None), ("db", "db", None),
+                ("hit", "db", seeded))
+
     prev = os.environ.get("APEX_TPU_AUTOTUNE")
-
-    def _set(mode):
-        if mode is None:
-            os.environ.pop("APEX_TPU_AUTOTUNE", None)
-        else:
-            os.environ["APEX_TPU_AUTOTUNE"] = mode
-
     try:
         for donate in (False, True):
             kw = {"donate_argnums": (0,)} if donate else {}
-
-            _set("off")
-            hlo_off = jax.jit(make_step(), **kw).lower(
-                x, w, b, buf).compile().as_text()
-
-            # db mode against the committed DB: these shapes are not
-            # in it — exact-key miss, defaults, bit-identical HLO
-            _set("db")
-            autotune.reset_counters()
-            hlo_db = jax.jit(make_step(), **kw).lower(
-                x, w, b, buf).compile().as_text()
-            assert hlo_db == hlo_off, (
+            hlo, counts = {}, {}
+            for name, mode, db in variants:
+                os.environ["APEX_TPU_AUTOTUNE"] = mode
+                with (autotune.use_db(db) if db is not None
+                      else contextlib.nullcontext()):
+                    autotune.reset_counters()
+                    # ONE call site for every variant: the text records
+                    # the Python stack it was traced under, down to the
+                    # Mosaic kernels' payloads
+                    hlo[name] = jax.jit(make_step(), **kw).lower(
+                        x, w, b, buf).compile().as_text()
+                    counts[name] = autotune.counters()
+            assert hlo["db"] == hlo["off"], (
                 f"DB-miss path compiled a different program than "
                 f"APEX_TPU_AUTOTUNE=off (donate={donate})")
-            c = autotune.counters()
+            c = counts["db"]
             assert c["misses"] >= 2 and c["hits"] == 0, (
                 f"expected pure trace-time misses, got {c}")
-
-            # positive twin: an exact-key hit changes the realized
-            # block, hence the program
-            entry = autotune.TuningEntry(
-                family="layer_norm", dims=(96, 72), dtype="float32",
-                chip=autotune.chip_kind(), block={"block_rows": 32})
-            with autotune.use_db(autotune.TuningDB(
-                    {entry.fingerprint: entry})):
-                autotune.reset_counters()
-                hlo_hit = jax.jit(make_step(), **kw).lower(
-                    x, w, b, buf).compile().as_text()
-                assert autotune.counters()["hits"] == 1, \
-                    autotune.counters()
-            assert hlo_hit != hlo_off, (
+            assert counts["hit"]["hits"] == 1, counts["hit"]
+            assert hlo["hit"] != hlo["off"], (
                 "an exact-key tuned hit left the program unchanged — "
                 "the consult is not reaching the dispatch seam")
     finally:
-        _set(prev)
+        if prev is None:
+            os.environ.pop("APEX_TPU_AUTOTUNE", None)
+        else:
+            os.environ["APEX_TPU_AUTOTUNE"] = prev
 
 
 # --- driver ------------------------------------------------------------------
@@ -2002,18 +2022,32 @@ def main(argv=None):
     argv = sys.argv[1:] if argv is None else argv
     pattern = None
     json_path = None
+    interpret = False
     it = iter(argv)
     for a in it:
         if a == "--json":
             json_path = next(it)
         elif a in ("-k", "--filter"):
             pattern = next(it)
+        elif a == "--interpret":
+            interpret = True
         elif a == "--compile-check":
             pass
         else:
             print(f"usage: python -m apex_tpu.ops [--compile-check] "
-                  f"[-k PATTERN] [--json PATH]")
+                  f"[-k PATTERN] [--json PATH] [--interpret]")
             return 2
+    # the point of this command is to COMPILE the kernels; off a TPU the
+    # library interprets them all, which proves nothing about Mosaic —
+    # that run has to be asked for by name
+    if jax.default_backend() != "tpu" and not interpret:
+        print(f"python -m apex_tpu.ops: no TPU (backend is "
+              f"{jax.default_backend()!r}), so every kernel would run in "
+              f"interpret mode and none would be compiled; pass "
+              f"--interpret if that is what you want", file=sys.stderr)
+        return 2
+    from apex_tpu.utils import enable_compile_cache
+    enable_compile_cache()
     return 0 if run(pattern, json_path) else 1
 
 

@@ -58,7 +58,13 @@ _lock = threading.Lock()
 _installed = False
 _globals = {"traces": 0, "lowerings": 0, "compiles": 0,
             "trace_secs": 0.0, "lower_secs": 0.0, "compile_secs": 0.0,
-            "autotune_compiles": 0, "autotune_secs": 0.0}
+            "autotune_compiles": 0, "autotune_secs": 0.0,
+            "cache_hits": 0}
+# jax fires the backend-compile duration event around
+# compile_or_get_cached, so "compiles" counts compile REQUESTS; the ones
+# the persistent cache answered are counted here, and
+# compiles - cache_hits is what the backend actually compiled
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _SECS_KEY = {"traces": "trace_secs", "lowerings": "lower_secs",
              "compiles": "compile_secs"}
 
@@ -124,6 +130,12 @@ def _on_duration(name: str, secs: float, **_kw) -> None:
         st[-1]._count_event(key, secs, autotune=autotune)
 
 
+def _on_event(name: str, **_kw) -> None:
+    if name == _CACHE_HIT_EVENT:
+        with _lock:
+            _globals["cache_hits"] += 1
+
+
 def install() -> bool:
     """Register the process-wide ``jax.monitoring`` listener (idempotent;
     listeners cannot be unregistered, so a module flag guards against
@@ -136,6 +148,7 @@ def install() -> bool:
         try:
             jax.monitoring.register_event_duration_secs_listener(
                 _on_duration)
+            jax.monitoring.register_event_listener(_on_event)
         except Exception:
             return False
         _installed = True
@@ -148,7 +161,9 @@ def installed() -> bool:
 
 def global_counters() -> Dict[str, float]:
     """Process-wide compile-pipeline counters since :func:`install` /
-    the last reset: {"traces", "lowerings", "compiles", "*_secs"}."""
+    the last reset: {"traces", "lowerings", "compiles", "*_secs",
+    "cache_hits"} — ``compiles`` counts compile requests, of which
+    ``cache_hits`` were answered by the persistent compilation cache."""
     with _lock:
         return dict(_globals)
 

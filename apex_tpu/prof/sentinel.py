@@ -1,9 +1,8 @@
 """Noise-aware perf-regression sentinel over bench JSON trajectories.
 
-The repo accumulates one driver-captured bench row per round
-(``BENCH_r01.json`` …) and, until now, a human eyeballed them. This
-module is the automated gate: it extracts the perf-relevant columns
-from each row (throughput, ms/step, MFU, peak HBM bytes, wire ratios,
+A trajectory is one ``python bench.py`` JSON row per file, oldest
+first. This module is the automated gate over it: it extracts the
+perf-relevant columns from each row (throughput, ms/step, MFU, peak HBM bytes, wire ratios,
 goodput fraction, lint error counts, compile counts), builds a
 **robust median/MAD baseline** per metric over the trajectory, and
 judges the newest row with **direction-aware** thresholds — only the
@@ -207,9 +206,7 @@ def load_rows(paths: Sequence[str],
             row = obj.get("parsed")
             if row is None:
                 why = obj.get("failure_reason")
-                att = obj.get("attempts")
                 note = (f"no parsed bench row (rc={obj.get('rc')}"
-                        + (f"; {att} probe attempts" if att else "")
                         + (f"; {why}" if why else "") + ") — skipped")
         metrics = extract_metrics(row, specs)
         if row is not None and not metrics and note is None:

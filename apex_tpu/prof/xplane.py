@@ -134,6 +134,16 @@ class TraceProfile:
     module_runs: int                  # XLA Modules line event count
     module_total_us: float            # wall device time inside XLA modules
 
+    def module_us_per_run(self) -> float:
+        """Device µs per XLA module run. A trace with no module runs is
+        a broken (or CPU) trace, not a device time: it raises rather than
+        let a caller substitute host wall."""
+        if not self.module_runs:
+            raise ValueError(
+                f"the trace at {self.path} holds no device module runs "
+                f"(device plane {self.device or 'absent'!r})")
+        return self.module_total_us / self.module_runs
+
     def by_category(self) -> Dict[str, float]:
         out: Dict[str, float] = {}
         for r in self.ops:

@@ -1,5 +1,6 @@
 from apex_tpu.utils.backoff import backoff_sleep
 from apex_tpu.utils.bits import uint_view_dtype
+from apex_tpu.utils.compile_cache import enable_compile_cache
 from apex_tpu.utils.fsio import fsync_dir, write_atomic
 from apex_tpu.utils.tree import (
     tree_cast,
@@ -19,6 +20,7 @@ __all__ = [
     "global_norm",
     "backoff_sleep",
     "uint_view_dtype",
+    "enable_compile_cache",
     "write_atomic",
     "fsync_dir",
 ]

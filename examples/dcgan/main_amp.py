@@ -29,6 +29,7 @@ import numpy as np
 
 from apex_tpu import amp, models
 from apex_tpu.optim import FusedAdam
+from apex_tpu.utils import enable_compile_cache
 
 
 def parse_args():
@@ -60,6 +61,7 @@ def bce_with_logits(logits, target):
 
 def main():
     args = parse_args()
+    enable_compile_cache()
     rng = np.random.RandomState(args.manualSeed)
 
     netG = models.Generator(nz=args.nz, ngf=args.ngf)

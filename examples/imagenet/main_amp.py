@@ -46,6 +46,7 @@ from apex_tpu import amp, models, ops, parallel
 from apex_tpu.data import (DevicePrefetcher, ImageFolderSource,
                            measure_source, synthetic_source)
 from apex_tpu.optim import FusedSGD
+from apex_tpu.utils import enable_compile_cache
 
 
 ARCHS = {
@@ -55,7 +56,7 @@ ARCHS = {
 }
 
 
-def parse_args():
+def parse_args(argv=None):
     parser = argparse.ArgumentParser(description="apex_tpu ImageNet")
     parser.add_argument("--data", metavar="DIR", default=None,
                         help="path to dataset (synthetic if omitted)")
@@ -84,7 +85,7 @@ def parse_args():
     parser.add_argument("--prefetch", default=2, type=int)
     parser.add_argument("--loader-workers", default=None, type=int,
                         help="decode threads for --data (default: cores)")
-    return parser.parse_args()
+    return parser.parse_args(argv)
 
 
 # the device-put prefetcher lives in apex_tpu.data now; keep the example
@@ -93,14 +94,21 @@ Prefetcher = DevicePrefetcher
 synthetic_batches = synthetic_source
 
 
-def main():
-    args = parse_args()
+def main(argv=None, devices=None):
+    """Train; ``argv`` defaults to the command line and ``devices`` to
+    every local device. Returns a summary of the run (losses, timings,
+    the final state, the last batch and the jitted step — which
+    ``step.lower(state, batch_stats, *last_batch)`` lowers again) for
+    callers that check it —
+    ``chip_smoke.py`` drives this function rather than a copy of it."""
+    args = parse_args(argv)
+    enable_compile_cache()
     if args.deterministic:
         # one seed, highest matmul precision — the cudnn.deterministic
         # analogue (`main_amp.py:120-128`)
         jax.config.update("jax_default_matmul_precision", "highest")
 
-    mesh = parallel.data_parallel_mesh()
+    mesh = parallel.data_parallel_mesh(devices)
     n_dev = mesh.shape[parallel.DATA_AXIS]
     if args.batch_size % n_dev:
         raise SystemExit(f"global batch {args.batch_size} must divide "
@@ -130,7 +138,10 @@ def main():
     variables = model.init(jax.random.PRNGKey(0), x0, train=True)
     params, batch_stats = variables["params"], variables["batch_stats"]
     amp_opt = amp.Amp(policy, tx)
-    state = amp_opt.init(params)
+    # the DDP construction-time broadcast: state starts replicated on the
+    # mesh, so the first step already runs the steady-state executable
+    state = parallel.replicate(amp_opt.init(params), mesh)
+    batch_stats = parallel.replicate(batch_stats, mesh)
 
     def step(state, batch_stats, xb, yb):
         if xb.dtype == jnp.uint8:
@@ -197,6 +208,8 @@ def main():
         print(f"loader: {probe:.0f} img/s with {folder.workers} "
               f"{'cache-read' if args.cache else 'decode'} threads "
               f"(training is input-bound below this rate)")
+    losses, last_batch = [], None
+    first_step_s = t_steady = None
     for epoch in range(args.epochs):
         src = (folder.batches(args.steps_per_epoch)
                if folder is not None else
@@ -224,6 +237,13 @@ def main():
                 prof_ctx.__enter__()
             state, batch_stats, loss, acc = spmd_step(
                 state, batch_stats, xb, yb)
+            losses.append(loss)
+            last_batch = (xb, yb)
+            if first_step_s is None:
+                # the one step that pays the compile, timed on its own
+                jax.block_until_ready(loss)
+                first_step_s = time.perf_counter() - t0
+                t_steady = time.perf_counter()
             seen += args.batch_size
             if prof_ctx is not None and i + 1 == args.prof:
                 float(loss)
@@ -237,7 +257,19 @@ def main():
                       f"({seen/dt/n_dev:.1f}/chip)")
         if prof_ctx is not None:
             prof_ctx.__exit__(None, None, None)
+    jax.block_until_ready(state)
+    steady_step_s = ((time.perf_counter() - t_steady) / (len(losses) - 1)
+                     if len(losses) > 1 else None)
     print("done. amp state_dict:", amp_opt.state_dict(state))
+    return {
+        "global_batch": args.batch_size,
+        "losses": [float(l) for l in losses],
+        # the first step pays the compile; the rest are timed together,
+        # closed once by block_until_ready
+        "first_step_s": first_step_s, "steady_step_s": steady_step_s,
+        "state": state, "batch_stats": batch_stats,
+        "last_batch": last_batch, "step": spmd_step,
+    }
 
 
 if __name__ == "__main__":

@@ -44,6 +44,7 @@ from jax.sharding import PartitionSpec as P
 
 from apex_tpu import amp, monitor, parallel, trace
 from apex_tpu.optim import FusedSGD
+from apex_tpu.utils import enable_compile_cache
 
 
 def main():
@@ -61,6 +62,7 @@ def main():
                         help="watchdog deadline (s) when --crash-dumps "
                              "is set")
     args = parser.parse_args()
+    enable_compile_cache()
 
     # FOR DISTRIBUTED: form the cluster first (no-op single-process;
     # honors MASTER_ADDR/RANK/WORLD_SIZE) — rank resolution below (per-

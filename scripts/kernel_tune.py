@@ -405,7 +405,9 @@ def main_cpu8():
 def main_update_db():
     from apex_tpu.ops import autotune
     from apex_tpu.prof import compile_watch
+    from apex_tpu.utils import enable_compile_cache
 
+    enable_compile_cache()
     compile_watch.install()
     print(f"== sweeping {len(autotune.FAMILIES)} families "
           f"(chip={autotune.chip_kind()})")

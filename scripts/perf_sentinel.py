@@ -3,8 +3,8 @@
 trajectories (apex_tpu.prof.sentinel as a CLI; pure stdlib — CI and
 log-shipping hosts run it without jax).
 
-    python scripts/perf_sentinel.py --check BENCH_r01.json ... BENCH_r05.json
-    python scripts/perf_sentinel.py --check BENCH_r0*.json --replay
+    python scripts/perf_sentinel.py --check row01.json ... row05.json
+    python scripts/perf_sentinel.py --check rows/*.json --replay
     python scripts/perf_sentinel.py --check ... --write-baseline "reason"
 
 Judges the NEWEST metric-bearing row against robust median/MAD
@@ -22,9 +22,9 @@ one ``kind="regress"`` event per verdict
 (``check_metrics_schema.py --kind roofline`` validates).
 
 Exit status: 0 clean (or waived), 1 unwaived regression, 2 usage/IO.
-Run by ``run_tier1.sh --smoke`` over the committed r01–r05 trajectory;
-``scripts/roofline_audit.py --cpu8`` asserts the seeded-regression
-positive and the no-change negative twin.
+The repository commits no trajectory (PR 21); ``tests/test_roofline.py``
+pins the gate — seeded-regression positive, no-change negative twin — on
+trajectories it builds in a temp directory.
 """
 
 import importlib.util

@@ -34,7 +34,11 @@ def main():
         top = int(argv[argv.index("--top") + 1])
 
     from apex_tpu import prof
+    from apex_tpu.utils import enable_compile_cache
     import bench
+
+    enable_compile_cache()
+    peak = prof.device_peak_flops()     # unknown device: raises
 
     # the ONE construction of this step (bench row + apexlint flagship
     # share it — see bench._bert_step_builder)
@@ -78,8 +82,7 @@ def main():
 
     from apex_tpu.prof import xplane as _xplane
     profile = _xplane.parse_trace(logdir)
-    dev_us = (profile.module_total_us / profile.module_runs
-              if profile.module_runs else wall * 1e6)
+    dev_us = profile.module_us_per_run()    # no device runs: raises
     n_params = sum(int(np.prod(l.shape)) for l in
                    jax.tree_util.tree_leaves(variables["params"]))
     model_flops = 6.0 * n_params * batch * seq
@@ -92,7 +95,6 @@ def main():
                      for k, v in list(profile.by_category().items())[:8])
     print(cats)
     print(profile.table(top=top))
-    peak = prof.device_peak_flops() or float("inf")
     print("model-flops MFU:", model_flops / (dev_us * 1e-6) / peak)
     print("seq/s:", batch / (dev_us * 1e-6))
 

@@ -30,11 +30,13 @@ def main():
 
     import bench as B
     from apex_tpu import prof
+    from apex_tpu.utils import enable_compile_cache
 
+    enable_compile_cache()
+    peak = prof.device_peak_flops()     # unknown device: raises
     logdir = tempfile.mkdtemp(prefix="apex_tpu_prof_dcgan_")
     with prof.trace(logdir):
         img_s, dt, flops_s = B._bench_dcgan(batch, iters=3)
-    peak = prof.device_peak_flops() or float("inf")
     print(f"batch={batch} img/s={img_s:.0f} ms/step={dt * 1e3:.3f} "
           f"MFU={flops_s / peak:.3f}")
 

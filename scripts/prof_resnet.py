@@ -26,6 +26,10 @@ def main():
 
     from apex_tpu import amp, models, ops, prof
     from apex_tpu.optim import FusedSGD
+    from apex_tpu.utils import enable_compile_cache
+
+    enable_compile_cache()
+    peak = prof.device_peak_flops()     # unknown device: raises
 
     policy = amp.Policy.from_opt_level("O2")
     dx_dist = os.environ.get("APEX_TPU_DX_DISTRIBUTE") or None
@@ -90,8 +94,7 @@ def main():
 
     from apex_tpu.prof import xplane as _xplane
     profile = _xplane.parse_trace(logdir)
-    dev_us = (profile.module_total_us / profile.module_runs
-              if profile.module_runs else wall * 1e6)
+    dev_us = profile.module_us_per_run()    # no device runs: raises
     print(f"fused_bn={fused} batch={batch}")
     print(f"wall/iter={wall*1e6:.0f}us device/iter={dev_us:.0f}us "
           f"flops={cost['flops']:.3g} bytes={cost['bytes_accessed']:.3g}")
@@ -99,7 +102,6 @@ def main():
                      for k, v in list(profile.by_category().items())[:8])
     print(cats)
     print(profile.table(top=top))
-    peak = prof.device_peak_flops() or float("inf")
     print("MFU:", cost["flops"] / (dev_us * 1e-6) / peak)
     print("img/s:", batch / (dev_us * 1e-6))
 

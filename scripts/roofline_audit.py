@@ -1,11 +1,11 @@
 #!/usr/bin/env python
 """roofline_audit — the asserting CI audit of the roofline observatory
-+ perf sentinel (run by ``run_tier1.sh --smoke``; exit status is the
-verdict).
+(run by ``run_tier1.sh --smoke``; exit status is the verdict).
 
-Four asserted legs, CPU-only off committed artifacts (live capture
-happens on TPU; the committed ``tests/fixtures/*.xplane.pb`` and
-``BENCH_r0*.json`` make the whole loop regression-testable tf-free):
+Three asserted legs, CPU-only off committed artifacts (live capture
+happens on TPU; the committed ``tests/fixtures/*.xplane.pb`` make the
+join regression-testable tf-free). The perf sentinel is pinned by
+``tests/test_roofline.py`` on trajectories it builds itself:
 
 (a) **attribution closure + the known gap**: the BERT-layer fixture's
     per-op roofline join must close over the trace's module device
@@ -20,20 +20,12 @@ happens on TPU; the committed ``tests/fixtures/*.xplane.pb`` and
     classes; the attention-free toy attributes its dot FLOPs into the
     calling fusion.
 
-(c) **sentinel replay, seeded positive + negative twin**: the
-    committed BENCH_r01→r05 trajectory replays CLEAN through
-    ``scripts/perf_sentinel.py`` (exit 0 — the r05 failed-bench row is
-    skipped with a note, not flagged), and the same trajectory with a
-    seeded 45% MFU/throughput drop appended exits 1 naming ``mfu``.
-
-(d) every emitted stream validates under
+(c) every emitted stream validates under
     ``check_metrics_schema.py --kind roofline``.
 
 Usage: JAX_PLATFORMS=cpu python scripts/roofline_audit.py --cpu8
 """
 
-import glob
-import json
 import os
 import subprocess
 import sys
@@ -167,73 +159,6 @@ def audit_aot_only(tmp: str) -> None:
           f"measured_us null on every row, events validate")
 
 
-def audit_sentinel(tmp: str) -> None:
-    print("== perf sentinel: committed trajectory clean, seeded "
-          "regression fires")
-    traj = sorted(glob.glob(os.path.join(_REPO, "BENCH_r0*.json")))
-    assert len(traj) >= 4, f"expected the committed r01.. trajectory, " \
-                           f"got {traj}"
-    baseline = os.path.join(_REPO, "scripts", "perf_baseline.json")
-
-    def run_sentinel(files, jsonl):
-        return subprocess.run(
-            [sys.executable,
-             os.path.join(_REPO, "scripts", "perf_sentinel.py"),
-             "--check", *files, "--baseline", baseline,
-             "--jsonl", jsonl],
-            capture_output=True, text=True)
-
-    # negative twin: the unmodified trajectory must pass clean
-    clean_events = os.path.join(tmp, "sentinel_clean.jsonl")
-    r = run_sentinel(traj, clean_events)
-    assert r.returncode == 0, (
-        f"sentinel flagged the UNMODIFIED committed trajectory:\n"
-        f"{r.stdout}{r.stderr}")
-    assert "skipped" in r.stdout, (
-        "the failed r05 row should be skipped with a note:\n" + r.stdout)
-    _run_schema(clean_events)
-    print("  unmodified r01->r05 trajectory: clean (exit 0, failed "
-          "r05 row skipped with a note)")
-
-    # seeded positive: last good row degraded 45% in MFU + throughput
-    last_good = None
-    for p in reversed(traj):
-        obj = json.load(open(p))
-        if obj.get("parsed"):
-            last_good = obj["parsed"]
-            break
-    assert last_good is not None
-    seeded = json.loads(json.dumps(last_good))
-    seeded["value"] *= 0.55
-    seeded["extra"]["mfu"] *= 0.55
-    seeded_path = os.path.join(tmp, "BENCH_seeded.json")
-    json.dump({"n": 99, "rc": 0, "parsed": seeded},
-              open(seeded_path, "w"))
-    seed_events = os.path.join(tmp, "sentinel_seeded.jsonl")
-    r = run_sentinel(traj + [seeded_path], seed_events)
-    assert r.returncode == 1, (
-        f"sentinel MISSED the seeded 45% MFU regression:\n"
-        f"{r.stdout}{r.stderr}")
-    assert "mfu" in r.stdout and "REGRESSED" in r.stdout, r.stdout
-    _run_schema(seed_events)
-    regressed = [json.loads(l) for l in open(seed_events)
-                 if json.loads(l).get("regressed")]
-    assert {"mfu", "device_img_s"} <= {e["metric"] for e in regressed}, \
-        regressed
-    print("  seeded 45% MFU drop: flagged (exit 1; mfu + device_img_s "
-          "regressed, direction-aware median/MAD baseline)")
-
-    # library-level replay backtest: every committed row judged
-    # against its prefix stays quiet
-    from apex_tpu.prof import sentinel as sn
-    rows = sn.load_rows(traj)
-    reports = sn.replay_trajectory(rows)
-    assert reports and all(rep.ok for rep in reports), \
-        [(rep.subject, [v.metric for v in rep.regressions])
-         for rep in reports]
-    print(f"  replay backtest: {len(reports)} judged rows, all quiet")
-
-
 def main_cpu8() -> None:
     import jax
     jax.config.update("jax_platforms", "cpu")
@@ -243,7 +168,6 @@ def main_cpu8() -> None:
     with tempfile.TemporaryDirectory() as tmp:
         audit_fixture_join(tmp)
         audit_aot_only(tmp)
-        audit_sentinel(tmp)
     print("\nroofline audit ok")
 
 

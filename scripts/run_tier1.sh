@@ -101,10 +101,6 @@
 #                                   #   steady-state autotune compiles,
 #                                   #   tune_report covers the fused-
 #                                   #   backward roofline candidate
-#                                   # + the perf sentinel gate over the
-#                                   #   committed BENCH_r0*.json
-#                                   #   trajectory (exit 1 on unwaived
-#                                   #   regression)
 #
 # Exit status is pytest's (or the first failing smoke step). The full
 # run prints DOTS_PASSED=<n> — the count of passing-test dots the driver
@@ -366,19 +362,6 @@ EOF
     # (~549 us vs ~436 us) shows as COVERED — and both tune-event
     # streams pass --kind roofline
     JAX_PLATFORMS=cpu python scripts/kernel_tune.py --cpu8 --interpret
-
-    echo "== smoke: perf sentinel gate over the committed trajectory"
-    # the noise-aware regression gate (robust median/MAD baselines,
-    # direction-aware thresholds, fingerprinted waivers in
-    # scripts/perf_baseline.json) judging the newest committed bench
-    # row — exit 1 here means a landed change regressed a judged
-    # column (ms/step, MFU, peak HBM, wire ratio, goodput_frac, lint
-    # counts) without an explicit waiver
-    python scripts/perf_sentinel.py --check BENCH_r0*.json \
-        --baseline scripts/perf_baseline.json \
-        --jsonl "$tmp/sentinel.jsonl"
-    python scripts/check_metrics_schema.py --kind roofline \
-        "$tmp/sentinel.jsonl"
 
     echo "smoke ok"
     exit 0

@@ -582,6 +582,16 @@ class TestCausalOffset:
             np.testing.assert_allclose(a, b, atol=5e-4, rtol=1e-3)
 
 
+def test_lse_variant_offsets_are_keyword_only():
+    """A ninth positional argument used to be ``causal_offset`` and would
+    now bind to ``dropout_rate``: it must fail at the call site."""
+    from apex_tpu.ops.attention import flash_attention_lse
+
+    q = k = v = jnp.zeros((1, 128, 2, 64), jnp.float32)
+    with pytest.raises(TypeError):
+        flash_attention_lse(q, k, v, None, None, True, 128, 128, 0)
+
+
 def test_lse_variant_bias_cotangent():
     """flash_attention_lse returns a bias gradient that folds the lse
     cotangent (ds = p*(dp - (delta - dlse))) — round-5; previously the

@@ -84,8 +84,7 @@ class TestRngKeyReuse:
 
 class TestF64Creep:
     def test_fires_on_f64(self):
-        from jax.experimental import enable_x64
-        with enable_x64():
+        with jax.enable_x64(True):
             fs = lint.lint_jaxpr(
                 lambda x: jnp.sum(x.astype(jnp.float64)),
                 jnp.zeros(4, jnp.float32))
@@ -139,7 +138,7 @@ class TestHostCallback:
         fs = lint.lint_jaxpr(f, jnp.ones(4))
         hits = [f_ for f_ in fs if f_.rule == "host-callback-in-step"]
         assert len(hits) == 1 and hits[0].severity == "error"
-        assert hits[0].op == "debug_callback"
+        assert hits[0].op == "debug_print"      # the primitive on jax 0.9.0
 
     def test_clean_step_does_not_fire(self):
         fs = lint.lint_jaxpr(lambda x: x * 2, jnp.ones(4))

@@ -132,6 +132,19 @@ def test_zero_optimizer_scatter_gather(mesh8):
         f"ZeRO path still moves full-size all-reduces: {big_ar}")
 
 
+# What PR 21 established (PERF.md, "Bring-up on the chip"): the XLA:CPU of
+# jaxlib 0.9.0 drops the optimization-barrier chain and merges the
+# per-bucket all-reduces into one variadic all-reduce, so the two
+# structural asserts below cannot hold on the CI mesh. The TPU compiler
+# keeps them apart — 4 all-reduces for 4 buckets on four v5e chips, and
+# `ddp/overlap-start-done` passes compiled. Whether bucketed sync survives
+# at all is ROADMAP D5's call; strict, so a jaxlib that stops merging
+# shows up here.
+_XLA_CPU_MERGES_BUCKETS = pytest.mark.xfail(
+    strict=True, reason="XLA:CPU (jaxlib 0.9.0) merges the per-bucket "
+                        "all-reduces; the TPU compiler does not")
+
+
 class TestBucketedOverlap:
     """Overlap-audit assertions for the bucketed backward-ordered sync
     (apex ``allreduce_bucket`` parity) on the CI mesh. The async
@@ -140,6 +153,7 @@ class TestBucketedOverlap:
     compile-check case; here the structure (per-bucket all-reduces that
     the combiner cannot re-merge, wire dtype/bytes) is pinned."""
 
+    @_XLA_CPU_MERGES_BUCKETS
     def test_per_bucket_allreduces_not_merged(self, mesh8):
         from apex_tpu.parallel import comm
 
@@ -160,6 +174,7 @@ class TestBucketedOverlap:
         # ...but together they still cover it
         assert sum(c[3] for c in ars) >= int(grad_bytes * 0.95)
 
+    @_XLA_CPU_MERGES_BUCKETS
     def test_bucket_bytes_bounded_by_message_size(self, mesh8):
         from apex_tpu.parallel import comm
 

@@ -281,8 +281,8 @@ def test_profile_step_cpu():
     assert rep.cost["flops"] > 0
     assert rep.wall_us > 0
     assert isinstance(rep.table(), str)
-    # CPU: no device plane → mfu computes to 0 (peak unknown)
-    assert rep.mfu() == 0.0
+    # CPU: peak unknown → an explicit peak still gives a number
+    assert rep.mfu(peak_flops=1e12) > 0
 
 
 def test_profile_step_cleans_its_tempdir():
@@ -318,11 +318,13 @@ def test_mfu_prints_na_on_unknown_device():
         return (x @ x).sum()
 
     rep = prof.profile_step(f, jnp.ones((32, 32)), iters=1, warmup=1)
-    if prof.device_peak_flops():
-        assert "mfu=n/a" not in rep.table()
-    else:
-        assert "mfu=n/a" in rep.table()
-        assert "mfu=0.0%" not in rep.table()
+    assert "mfu=n/a" in rep.table()
+    assert "mfu=0.0%" not in rep.table()
+    # anything that would divide by the peak refuses the unknown device
+    with pytest.raises(ValueError, match="peak table"):
+        prof.device_peak_flops()
+    with pytest.raises(ValueError, match="peak table"):
+        rep.mfu()
 
 
 def test_opcode_categories_modern_traces():
