@@ -32,7 +32,8 @@ from typing import Dict, List, Optional, Sequence, Set, Tuple
 from apex_tpu.lint.findings import Finding
 from apex_tpu.prof import hlo as _hlo
 from apex_tpu.prof import memory as _mem
-from apex_tpu.prof.xplane import COLLECTIVE_PREFIXES, strip_scope
+from apex_tpu.prof.xplane import (COLLECTIVE_PREFIXES, HLO_TEXT_SCOPE_RE,
+                                  strip_scope)
 
 __all__ = ["lint_hlo_text", "parse_input_output_alias",
            "parse_entry_output_shapes", "donation_findings",
@@ -200,7 +201,7 @@ def resharding_findings(hlo_text: str,
                 continue
             if op.endswith("-start"):
                 break          # counted at the matching -done
-            sm = _mem._OP_NAME_RE.search(line)
+            sm = HLO_TEXT_SCOPE_RE.search(line)
             scope = strip_scope(sm.group(1)) if sm else ""
             if any(p.search(scope) for p in pats):
                 break

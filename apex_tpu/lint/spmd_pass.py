@@ -54,7 +54,8 @@ import numpy as np
 from apex_tpu.lint.findings import Finding
 from apex_tpu.lint.mesh_model import MeshModel
 from apex_tpu.prof import memory as _mem
-from apex_tpu.prof.xplane import COLLECTIVE_PREFIXES, strip_scope
+from apex_tpu.prof.xplane import (COLLECTIVE_PREFIXES, HLO_TEXT_SCOPE_RE,
+                                  strip_scope)
 
 __all__ = ["CollectiveInstr", "extract_collective_schedule",
            "parse_replica_groups", "rank_schedule",
@@ -205,7 +206,7 @@ def extract_collective_schedule(hlo_text: str) -> List[CollectiveInstr]:
             nbytes = _mem.shape_bytes(shape)
         cm = _CHANNEL_RE.search(line)
         gm = _GROUPS_RE.search(line)
-        sm = _mem._OP_NAME_RE.search(line)
+        sm = HLO_TEXT_SCOPE_RE.search(line)
         out.append(CollectiveInstr(
             index=len(out),
             name=m.group("n").lstrip("%"),

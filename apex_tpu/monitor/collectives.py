@@ -68,7 +68,7 @@ def _iter_collective_rows(hlo_text: str):
             if op.startswith(prefix):
                 if op.endswith("-start"):
                     break  # counted at the matching -done
-                sm = _SCOPE_RE.search(line)
+                sm = _xplane.HLO_TEXT_SCOPE_RE.search(line)
                 scope = _xplane.strip_scope(sm.group(1)) if sm else ""
                 for dt, dims in _hlo._SHAPE_RE.findall(m.group("shape")):
                     if dt not in _hlo._DTYPE_BYTES:
@@ -81,8 +81,6 @@ def _iter_collective_rows(hlo_text: str):
                            elems * _hlo._DTYPE_BYTES[dt], scope)
                 break
 
-
-_SCOPE_RE = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
 
 #: hop classification of a collective's stripped scope: the
 #: hierarchical sync nests each hop under a ``bucketNN/ici`` or

@@ -8,6 +8,12 @@ semantics bit-for-bit at jnp precision. This mirrors the reference's
 uncompiled.
 
 ``APEX_TPU_FORCE_INTERPRET=1`` forces interpret mode everywhere (debugging).
+
+Every kernel is launched through :func:`pallas_call` here, under a name
+from :data:`KERNEL_NAMES`: the name is the kernel's in the Mosaic module
+and the ``jax.named_scope`` the call is bound under, which is how the device
+trace tells one kernel from another (``prof.xplane.own_scope``) whatever a
+user calls the flax module it runs in.
 """
 
 from __future__ import annotations
@@ -21,6 +27,40 @@ def use_interpret() -> bool:
     if os.environ.get("APEX_TPU_FORCE_INTERPRET") == "1":
         return True
     return jax.default_backend() != "tpu"
+
+
+#: every kernel of the library, one name for each ``pallas_call`` site
+#: (tests/test_kernel_names.py walks the sources against this table)
+KERNEL_NAMES = (
+    # attention.py: native (B, S, H) layout, then the packed (B*H, S, D)
+    "apex_attn_fwd", "apex_attn_bwd", "apex_attn_bwd_dq",
+    "apex_attn_bwd_dkv", "apex_attn_fwd_packed", "apex_attn_bwd_dq_packed",
+    "apex_attn_bwd_dkv_packed",
+    "apex_layer_norm_fwd", "apex_layer_norm_bwd",
+    "apex_xentropy_fwd", "apex_xentropy_bwd",
+    "apex_mlp_fwd",
+    "apex_bn_act_bwd_stats", "apex_bn_act_bwd_dx",
+    # flat-buffer row kernels through launch(): multi_tensor, optim_kernels
+    "apex_rows_scale", "apex_rows_axpby", "apex_rows_l2norm",
+    "apex_rows_maxnorm", "apex_rows_adam", "apex_rows_sgd",
+    "apex_rows_adagrad", "apex_rows_lamb_stage1", "apex_rows_lamb_stage2",
+    "apex_rows_novograd",
+)
+
+
+def pallas_call(kernel, *, name, **kwargs):
+    """``pl.pallas_call`` for a kernel of this library, interpreted off a
+    TPU. ``name`` becomes the kernel's name in the Mosaic module and the
+    HLO instruction's (``%apex_attn_fwd.3``), and ``pl.pallas_call`` binds
+    the call under a ``jax.named_scope`` of that name, which is what the
+    device trace carries (tests/test_kernel_names.py holds it to that).
+    Neither adds an op."""
+    from jax.experimental import pallas as pl
+
+    if name not in KERNEL_NAMES:
+        raise ValueError(f"{name!r} is not in ops._dispatch.KERNEL_NAMES")
+    return pl.pallas_call(kernel, name=name, interpret=use_interpret(),
+                          **kwargs)
 
 
 # Rows per grid step for flat-buffer elementwise kernels. A (512, 128) fp32
@@ -88,7 +128,7 @@ def _resolve_block_rows(rows, buf0, block_rows):
     return br
 
 
-def launch(kernel, inputs, outs, scalars=None, block_rows=None):
+def launch(kernel, inputs, outs, scalars=None, block_rows=None, *, name):
     """Shared pallas_call plumbing for flat-buffer elementwise kernels.
 
     The single launch convention every arena kernel uses (the analogue of
@@ -102,9 +142,8 @@ def launch(kernel, inputs, outs, scalars=None, block_rows=None):
 
     ``outs`` is a list of ("block", dtype) | ("scalar", dtype) entries.
     Block outputs come back as flat buffers, scalar outputs as (1, 1)
-    arrays, in order.
+    arrays, in order. ``name`` is the kernel's in :data:`KERNEL_NAMES`.
     """
-    import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -135,13 +174,13 @@ def launch(kernel, inputs, outs, scalars=None, block_rows=None):
         else:
             raise ValueError(f"unknown out kind {kind!r}")
 
-    results = pl.pallas_call(
+    results = pallas_call(
         kernel,
+        name=name,
         grid=(rows // br,),
         in_specs=in_specs,
         out_specs=tuple(out_specs),
         out_shape=tuple(out_shapes),
-        interpret=use_interpret(),
     )(*args)
     if not isinstance(results, (list, tuple)):
         results = (results,)

@@ -36,7 +36,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from apex_tpu.ops._dispatch import use_interpret
+from apex_tpu.ops._dispatch import pallas_call, use_interpret
 
 LANES = 128
 # Grid-step overhead on TPU dwarfs the per-tile MXU work at 128-blocks
@@ -327,7 +327,7 @@ def _flash_fwd(q3, k3, v3, bias_g, bidx, scale, causal, block_q, block_k,
 
     kernel = functools.partial(_fwd_kernel, scale, causal, sk, sq,
                                has_bias, dropout_rate, g)
-    o, lse = pl.pallas_call(
+    o, lse = pallas_call(
         lambda *refs: kernel(refs),
         grid=(bh // g, nq, nk),
         in_specs=in_specs,
@@ -346,7 +346,7 @@ def _flash_fwd(q3, k3, v3, bias_g, bidx, scale, causal, block_q, block_k,
             pltpu.VMEM((g, bq, LANES), jnp.float32),
             pltpu.VMEM((g, bq, dp), jnp.float32),
         ],
-        interpret=use_interpret(),
+        name="apex_attn_fwd_packed",
     )(*args)
     lse = lse[:, 0].reshape(bh, sqp)[:, :sq]
     return o[:, :sq, :d], lse
@@ -539,7 +539,7 @@ def _flash_bwd(q3, k3, v3, bias_g, bidx, o3, lse, do3, scale, causal,
     in_specs += [q_spec_q, lane_spec_q, lane_spec_q]
     args += [dop, lse_l, delta_l]
 
-    dq = pl.pallas_call(
+    dq = pallas_call(
         lambda *refs: functools.partial(
             _bwd_dq_kernel, scale, causal, sk, sq, has_bias,
             dropout_rate, g)(refs),
@@ -548,7 +548,7 @@ def _flash_bwd(q3, k3, v3, bias_g, bidx, o3, lse, do3, scale, causal,
         out_specs=q_spec_q,
         out_shape=jax.ShapeDtypeStruct((bh, sqp, dp), q3.dtype),
         scratch_shapes=[pltpu.VMEM((g, bq, dp), jnp.float32)],
-        interpret=use_interpret(),
+        name="apex_attn_bwd_dq_packed",
     )(*args)
 
     # dk/dv: grid loops q innermost
@@ -572,7 +572,7 @@ def _flash_bwd(q3, k3, v3, bias_g, bidx, o3, lse, do3, scale, causal,
     in_specs2 += [q_spec_k, lane_spec_k, lane_spec_k]
     args2 += [dop, lse_l, delta_l]
 
-    dk, dv = pl.pallas_call(
+    dk, dv = pallas_call(
         lambda *refs: functools.partial(
             _bwd_dkv_kernel, scale, causal, sk, sq, has_bias,
             dropout_rate, g)(refs),
@@ -581,7 +581,7 @@ def _flash_bwd(q3, k3, v3, bias_g, bidx, o3, lse, do3, scale, causal,
         out_specs=(k_spec_k, k_spec_k),
         out_shape=(jax.ShapeDtypeStruct((bh, skp, dp), k3.dtype),) * 2,
         scratch_shapes=[pltpu.VMEM((g, bk, dp), jnp.float32)] * 2,
-        interpret=use_interpret(),
+        name="apex_attn_bwd_dkv_packed",
     )(*args2)
 
     return dq[:, :sq, :d], dk[:, :sk, :d], dv[:, :sk, :d]
@@ -928,7 +928,7 @@ def _flash_fwd_nl(q2, k2, v2, nh, d, scale, causal, block_q, block_k,
                                causal_off is not None,
                                bias_g is not None, bias_per_head,
                                dbo is not None)
-    o, lse = pl.pallas_call(
+    o, lse = pallas_call(
         lambda *refs: kernel(refs),
         grid=(bh // g, nq, nk),
         in_specs=in_specs,
@@ -946,7 +946,7 @@ def _flash_fwd_nl(q2, k2, v2, nh, d, scale, causal, block_q, block_k,
             pltpu.VMEM((g, bq, LANES), jnp.float32),
             pltpu.VMEM((1, bq, gd), jnp.float32),
         ]),
-        interpret=use_interpret(),
+        name="apex_attn_fwd",
     )(*args)
     lse = _lse_reorder(lse[:, 0], bh, g, nq, bq)[:, :sq]
     return o[:, :sq, :], lse
@@ -1189,7 +1189,7 @@ def _flash_bwd_fused_nl(qp, kp, vp, dop, lse_l, delta_l, nh, d, g,
         in_specs += [q_spec, lane_spec, lane_spec]
         args += [dop, lse_l, delta_l]
 
-    dq, dk, dv = pl.pallas_call(
+    dq, dk, dv = pallas_call(
         lambda *refs: functools.partial(
             _bwd_fused_kernel_nl, scale, causal, sk, sq, dropout_rate,
             d, g, causal_off is not None, self_delta,
@@ -1202,7 +1202,7 @@ def _flash_bwd_fused_nl(qp, kp, vp, dop, lse_l, delta_l, nh, d, g,
             jax.ShapeDtypeStruct((b, skp, H), kp.dtype),
             jax.ShapeDtypeStruct((b, skp, H), kp.dtype),
         ),
-        interpret=use_interpret(),
+        name="apex_attn_bwd",
     )(*args)
     return dq[:, :sq, :], dk[:, :sk, :], dv[:, :sk, :]
 
@@ -1342,12 +1342,11 @@ def _flash_bwd_nl(q2, k2, v2, nh, d, lse, delta, do2, scale, causal,
     in_specs += [q_spec, lane_spec, lane_spec]
     args += [dop, lse_l, delta_l]
 
-    interp = use_interpret()
     extra = {}
-    if bwd_vmem is not None and not interp:
+    if bwd_vmem is not None and not use_interpret():
         extra["compiler_params"] = pltpu.CompilerParams(
             vmem_limit_bytes=bwd_vmem)
-    dq = pl.pallas_call(
+    dq = pallas_call(
         lambda *refs: functools.partial(
             _bwd_dq_kernel_nl, scale, causal, sk, sq, dropout_rate, d,
             g, causal_off is not None, bias_p is not None,
@@ -1357,7 +1356,7 @@ def _flash_bwd_nl(q2, k2, v2, nh, d, lse, delta, do2, scale, causal,
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((b, sqp, H), q2.dtype),
         scratch_shapes=[pltpu.VMEM((1, bq, gd), jnp.float32)],
-        interpret=interp,
+        name="apex_attn_bwd_dq",
         **extra,
     )(*args)
 
@@ -1382,7 +1381,7 @@ def _flash_bwd_nl(q2, k2, v2, nh, d, lse, delta, do2, scale, causal,
     in_specs2 += [q_spec_k, lane_spec_k, lane_spec_k]
     args2 += [dop, lse_l, delta_l]
 
-    dk, dv = pl.pallas_call(
+    dk, dv = pallas_call(
         lambda *refs: functools.partial(
             _bwd_dkv_kernel_nl, scale, causal, sk, sq, dropout_rate, d,
             g, causal_off is not None, bias_p is not None,
@@ -1392,7 +1391,7 @@ def _flash_bwd_nl(q2, k2, v2, nh, d, lse, delta, do2, scale, causal,
         out_specs=(k_spec_k, k_spec_k),
         out_shape=(jax.ShapeDtypeStruct((b, skp, H), k2.dtype),) * 2,
         scratch_shapes=[pltpu.VMEM((1, bk, gd), jnp.float32)] * 2,
-        interpret=interp,
+        name="apex_attn_bwd_dkv",
         **extra,
     )(*args2)
 

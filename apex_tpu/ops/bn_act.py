@@ -48,7 +48,7 @@ import flax.linen as nn
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from apex_tpu.ops._dispatch import use_interpret
+from apex_tpu.ops._dispatch import pallas_call
 
 __all__ = ["bn_act_train", "bn_add_act_train", "bn_act_reference",
            "FusedBNAct"]
@@ -230,7 +230,6 @@ def _bwd_pallas(cfg: _Cfg, x, scale, bias, mean, invstd, count, z, dz,
     blk = pl.BlockSpec((rb, c), lambda i: (i, 0), memory_space=pltpu.VMEM)
     prow = pl.BlockSpec((1, c), lambda i: (0, 0), memory_space=pltpu.VMEM)
     acc = pl.BlockSpec((8, c), lambda i: (0, 0), memory_space=pltpu.VMEM)
-    interpret = use_interpret()
 
     # pass 1: channel sums (+ dr for the residual join)
     in_specs = [blk, blk] + ([blk] if mode == "addrelu" else []) \
@@ -242,13 +241,13 @@ def _bwd_pallas(cfg: _Cfg, x, scale, bias, mean, invstd, count, z, dz,
     if mode == "addrelu":
         out_specs.append(blk)
         out_shapes.append(jax.ShapeDtypeStruct((m, c), r_dtype))
-    res = pl.pallas_call(
+    res = pallas_call(
         functools.partial(_sums_kernel, mode),
         grid=(m // rb,),
         in_specs=in_specs,
         out_specs=tuple(out_specs),
         out_shape=tuple(out_shapes),
-        interpret=interpret,
+        name="apex_bn_act_bwd_stats",
     )(*args)
     sums = res[0]
     dr2 = res[1] if mode == "addrelu" else None
@@ -265,14 +264,14 @@ def _bwd_pallas(cfg: _Cfg, x, scale, bias, mean, invstd, count, z, dz,
     # pass 2: dx. For the residual join g-source is dr (pre-masked), so
     # z is not re-read.
     g_src = dr2 if mode == "addrelu" else g2
-    dx2 = pl.pallas_call(
+    dx2 = pallas_call(
         functools.partial(_dx_kernel,
                           "relu" if mode == "relu" else "plain"),
         grid=(m // rb,),
         in_specs=[blk, blk] + [prow] * 6,
         out_specs=blk,
         out_shape=jax.ShapeDtypeStruct((m, c), x.dtype),
-        interpret=interpret,
+        name="apex_bn_act_bwd_dx",
     )(x2, g_src, *params, k1, k2)
 
     dx = dx2.reshape(x.shape)

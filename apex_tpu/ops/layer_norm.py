@@ -23,7 +23,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from apex_tpu.ops._dispatch import use_interpret
+from apex_tpu.ops._dispatch import pallas_call
 
 LANES = 128
 
@@ -95,13 +95,13 @@ def _ln_forward(x2, weight, bias, eps, block_rows=None):
         args += [_pad2(weight.reshape(1, h), 1, hp),
                  _pad2(bias.reshape(1, h), 1, hp)]
 
-    y = pl.pallas_call(
+    y = pallas_call(
         functools.partial(_ln_fwd_kernel, h, eps, affine),
         grid=(npad // r,),
         in_specs=in_specs,
         out_specs=row_spec,
         out_shape=jax.ShapeDtypeStruct((npad, hp), x2.dtype),
-        interpret=use_interpret(),
+        name="apex_layer_norm_fwd",
     )(*args)
     return y[:n, :h]
 
@@ -172,13 +172,13 @@ def _ln_backward(g2, x2, weight, eps, block_rows=None):
         out_shapes += [jax.ShapeDtypeStruct((nblocks * 8, hp),
                                             jnp.float32)] * 2
 
-    res = pl.pallas_call(
+    res = pallas_call(
         functools.partial(_ln_bwd_kernel, h, eps, affine),
         grid=(nblocks,),
         in_specs=in_specs,
         out_specs=tuple(out_specs) if affine else out_specs[0],
         out_shape=tuple(out_shapes) if affine else out_shapes[0],
-        interpret=use_interpret(),
+        name="apex_layer_norm_bwd",
     )(*args)
     if affine:
         dx, dw_part, db_part = res

@@ -30,7 +30,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from apex_tpu.ops._dispatch import use_interpret
+from apex_tpu.ops._dispatch import pallas_call
 
 LANES = 128
 _VMEM_WEIGHT_BUDGET = 8 << 20  # bytes of fp32 weights resident per step
@@ -130,14 +130,14 @@ def _fused_mlp_fwd_impl(x, weights, biases, activation, block_rows=None):
                                          lambda i: (0, 0),
                                          memory_space=pltpu.VMEM))
 
-    y = pl.pallas_call(
+    y = pallas_call(
         functools.partial(_mlp_kernel, len(weights), activation, use_bias),
         grid=(npad // r,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((r, pdims[-1]), lambda i: (i, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((npad, pdims[-1]), x.dtype),
-        interpret=use_interpret(),
+        name="apex_mlp_fwd",
     )(*args)
     return y[:n, :dims[-1]].reshape(*lead, dims[-1])
 

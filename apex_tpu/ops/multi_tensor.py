@@ -53,7 +53,7 @@ def multi_tensor_scale(buf, scale, *, out_dtype=None):
     out, flag = launch(
         _scale_kernel, [buf],
         outs=[("block", out_dtype), ("scalar", jnp.float32)],
-        scalars=[scale])
+        scalars=[scale], name="apex_rows_scale")
     return out, flag[0, 0] == 0.0
 
 
@@ -82,7 +82,7 @@ def multi_tensor_axpby(a, x, b, y, *, out_dtype=None):
     out, flag = launch(
         _axpby_kernel, [x, y],
         outs=[("block", out_dtype), ("scalar", jnp.float32)],
-        scalars=[a, b])
+        scalars=[a, b], name="apex_rows_axpby")
     return out, flag[0, 0] == 0.0
 
 
@@ -107,7 +107,8 @@ def multi_tensor_l2norm(buf):
     sequentially on-core, so the partial sums accumulate in a revisited
     (1,1) SMEM scalar. Arena padding is zero, so no masking is needed.
     """
-    acc = launch(_l2norm_kernel, [buf], outs=[("scalar", jnp.float32)])
+    acc = launch(_l2norm_kernel, [buf], outs=[("scalar", jnp.float32)],
+                 name="apex_rows_l2norm")
     return jnp.sqrt(acc[0, 0])
 
 
@@ -125,7 +126,8 @@ def _maxnorm_kernel(x_ref, acc_ref):
 def multi_tensor_maxnorm(buf):
     """Global max-abs (Linf) — `MaxNormFunctor`
     (`multi_tensor_l2norm_kernel.cu:113-160`)."""
-    acc = launch(_maxnorm_kernel, [buf], outs=[("scalar", jnp.float32)])
+    acc = launch(_maxnorm_kernel, [buf], outs=[("scalar", jnp.float32)],
+                 name="apex_rows_maxnorm")
     return acc[0, 0]
 
 

@@ -30,11 +30,11 @@ from jax.experimental.pallas import tpu as pltpu
 from apex_tpu.ops._dispatch import launch
 
 
-def _launch(kernel, inputs, out_dtypes, scalars):
+def _launch(name, kernel, inputs, out_dtypes, scalars):
     """Elementwise arena kernel via the shared launcher: all outputs are
     full block buffers."""
     return launch(kernel, inputs, outs=[("block", dt) for dt in out_dtypes],
-                  scalars=scalars)
+                  scalars=scalars, name=name)
 
 
 # --- Adam / AdamW (`multi_tensor_adam.cu:24-120`) ---------------------------
@@ -87,7 +87,8 @@ def adam_update(p, g, m, v, *, lr, beta1, beta2, eps, weight_decay, step,
         out_dtypes.append(jnp.dtype(param_copy_dtype))
     kernel = functools.partial(_adam_kernel, adam_w_mode,
                                param_copy_dtype is not None)
-    return _launch(kernel, [p, g, m, v], out_dtypes, scalars)
+    return _launch("apex_rows_adam", kernel, [p, g, m, v], out_dtypes,
+                   scalars)
 
 
 # --- SGD (`multi_tensor_sgd_kernel.cu:30-180`) ------------------------------
@@ -133,7 +134,7 @@ def sgd_update(p, g, m, *, lr, momentum=0.0, dampening=0.0, weight_decay=0.0,
         out_dtypes.append(jnp.dtype(param_copy_dtype))
     kernel = functools.partial(_sgd_kernel, nesterov, wd_after_momentum,
                                param_copy_dtype is not None)
-    return _launch(kernel, [p, g, m], out_dtypes, scalars)
+    return _launch("apex_rows_sgd", kernel, [p, g, m], out_dtypes, scalars)
 
 
 # --- Adagrad (`multi_tensor_adagrad.cu`) ------------------------------------
@@ -161,7 +162,8 @@ def adagrad_update(p, g, h, *, lr, eps=1e-10, weight_decay=0.0,
     scalars = jnp.stack([jnp.asarray(s, jnp.float32) for s in
                          (lr, eps, weight_decay, grad_scale)])
     kernel = functools.partial(_adagrad_kernel, adagrad_w_mode)
-    return _launch(kernel, [p, g, h], [p.dtype, h.dtype], scalars)
+    return _launch("apex_rows_adagrad", kernel, [p, g, h],
+                   [p.dtype, h.dtype], scalars)
 
 
 # --- LAMB, two-stage (`multi_tensor_lamb.cu:41,234`) ------------------------
@@ -210,7 +212,7 @@ def lamb_stage1(p, g, m, v, *, beta1, beta2, eps, weight_decay, step,
                          (beta1, beta2, eps, weight_decay, bc1, bc2,
                           clip_scale, b3)])
     kernel = functools.partial(_lamb_stage1_kernel, adam_w_mode)
-    return _launch(kernel, [p, g, m, v],
+    return _launch("apex_rows_lamb_stage1", kernel, [p, g, m, v],
                    [jnp.float32, m.dtype, v.dtype], scalars)
 
 
@@ -236,7 +238,8 @@ def lamb_stage2(p, u, ratio_per_pos, *, lr, param_copy_dtype=None):
         out_dtypes.append(jnp.dtype(param_copy_dtype))
     kernel = functools.partial(_lamb_stage2_kernel,
                                param_copy_dtype is not None)
-    return _launch(kernel, [p, u, ratio_per_pos], out_dtypes, scalars)
+    return _launch("apex_rows_lamb_stage2", kernel, [p, u, ratio_per_pos],
+                   out_dtypes, scalars)
 
 
 # --- NovoGrad (`multi_tensor_novograd.cu:24-130`) ---------------------------
@@ -283,5 +286,5 @@ def novograd_update(p, g, m, vnorm_per_pos, *, lr, beta1, beta2, eps,
     scalars = jnp.stack([jnp.asarray(s, jnp.float32) for s in
                          (lr, beta1, b3, eps, weight_decay)] + [bc1, bc2])
     kernel = functools.partial(_novograd_kernel, reg_inside_moment)
-    return _launch(kernel, [p, g, m, vnorm_per_pos],
+    return _launch("apex_rows_novograd", kernel, [p, g, m, vnorm_per_pos],
                    [p.dtype, m.dtype], scalars)

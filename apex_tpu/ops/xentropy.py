@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from apex_tpu.ops._dispatch import use_interpret
+from apex_tpu.ops._dispatch import pallas_call
 
 LANES = 128
 
@@ -122,13 +122,13 @@ def _fwd_call(x2, labels, smoothing, block_rows=None):
     row = pl.BlockSpec((r, vp), lambda i: (i, 0), memory_space=pltpu.VMEM)
     lane = pl.BlockSpec((r, LANES), lambda i: (i, 0),
                         memory_space=pltpu.VMEM)
-    loss, lse = pl.pallas_call(
+    loss, lse = pallas_call(
         functools.partial(_fwd_kernel, v, smoothing),
         grid=(npad // r,),
         in_specs=[row, lane],
         out_specs=(lane, lane),
         out_shape=(jax.ShapeDtypeStruct((npad, LANES), jnp.float32),) * 2,
-        interpret=use_interpret(),
+        name="apex_xentropy_fwd",
     )(xp, lab)
     return loss[:n, 0], lse[:n, 0]
 
@@ -153,13 +153,13 @@ def _bwd_call(x2, labels, lse, g, smoothing, block_rows=None):
     row = pl.BlockSpec((r, vp), lambda i: (i, 0), memory_space=pltpu.VMEM)
     lane = pl.BlockSpec((r, LANES), lambda i: (i, 0),
                         memory_space=pltpu.VMEM)
-    dx = pl.pallas_call(
+    dx = pallas_call(
         functools.partial(_bwd_kernel, v, smoothing),
         grid=(npad // r,),
         in_specs=[row, lane, lane, lane],
         out_specs=row,
         out_shape=jax.ShapeDtypeStruct((npad, vp), x2.dtype),
-        interpret=use_interpret(),
+        name="apex_xentropy_bwd",
     )(xp, lab, lsep, gp)
     return dx[:n, :v]
 

@@ -17,6 +17,16 @@ Two protocols in one object:
           (GradientTransformation-compatible, costs one extra subtract)
 
 ``apex_tpu.amp.Amp`` auto-detects the fused protocol.
+
+Every pass over the parameters is traced under a ``jax.named_scope`` of
+its own, ``optim/<optimizer>/<phase>`` (``optim/lamb/norms``,
+``optim/lamb/update``, ``optim/sgd/update``, ``optim/<optimizer>/arena``
+for the flatten and unflatten of the arena strategy): the device trace
+carries it (``prof.xplane.own_scope``), beneath amp's ``amp/update``. A
+fusion carries the scope of one of its ops, so a pass reads under the
+phase XLA named it after: LAMB's first sweep (moments, update direction
+and both norms in one fusion) under ``norms``, and an SGD update that XLA
+fuses into the weight-gradient fusion under the backward.
 """
 
 from __future__ import annotations
@@ -90,6 +100,13 @@ class FusedOptimizer:
     #: "auto" switches to the tree strategy at this many parameters
     TREE_THRESHOLD = 8_000_000
 
+    #: the optimizer's word in ``optim/<scope>/<phase>``
+    scope = ""
+
+    def _phase(self, phase: str):
+        """The named scope of one pass over the parameters."""
+        return jax.named_scope(f"optim/{self.scope}/{phase}")
+
     def __init__(self, lr: Scalar, strategy: str = "auto"):
         if strategy not in ("auto", "tree", "arena"):
             raise ValueError(f"unknown strategy {strategy!r}")
@@ -136,8 +153,9 @@ class FusedOptimizer:
         if self._use_tree(params):
             return self._tree_step(grads, state, params)
         spec = arena.plan(params)
-        p_bufs = arena.flatten(params, spec)
-        g_bufs = arena.flatten(grads, spec, cast=jnp.float32)
+        with self._phase("arena"):
+            p_bufs = arena.flatten(params, spec)
+            g_bufs = arena.flatten(grads, spec, cast=jnp.float32)
         count = state.count + 1
         lr = self.lr(count) if callable(self.lr) else self.lr
 
@@ -151,8 +169,9 @@ class FusedOptimizer:
             new_p[dt] = p_out
             for name in self.slot_names:
                 new_slots[name][dt] = s_out[name]
-        return (arena.unflatten(new_p, spec),
-                FusedOptState(count=count, slots=new_slots))
+        with self._phase("arena"):
+            new_params = arena.unflatten(new_p, spec)
+        return new_params, FusedOptState(count=count, slots=new_slots)
 
     def update(self, grads, state: FusedOptState, params):
         """optax GradientTransformation protocol (updates = new - old)."""
@@ -189,6 +208,7 @@ class FusedAdam(FusedOptimizer):
     """
 
     slot_names = ("m", "v")
+    scope = "adam"
 
     def __init__(self, lr: Scalar = 1e-3, betas=(0.9, 0.999), eps=1e-8,
                  weight_decay=0.0, adam_w_mode=True, bias_correction=True,
@@ -201,11 +221,13 @@ class FusedAdam(FusedOptimizer):
         self.bias_correction = bias_correction
 
     def _partition_step(self, spec, dt, p, g, slots, count, lr, ctx):
-        p2, m2, v2 = K.adam_update(
-            p, g, slots["m"], slots["v"], lr=lr, beta1=self.beta1,
-            beta2=self.beta2, eps=self.eps, weight_decay=self.weight_decay,
-            step=count, adam_w_mode=self.adam_w_mode,
-            bias_correction=self.bias_correction)
+        with self._phase("update"):
+            p2, m2, v2 = K.adam_update(
+                p, g, slots["m"], slots["v"], lr=lr, beta1=self.beta1,
+                beta2=self.beta2, eps=self.eps,
+                weight_decay=self.weight_decay, step=count,
+                adam_w_mode=self.adam_w_mode,
+                bias_correction=self.bias_correction)
         return p2, {"m": m2, "v": v2}
 
     def _tree_step(self, grads, state, params):
@@ -228,8 +250,9 @@ class FusedAdam(FusedOptimizer):
                 upd = upd + wd * p32
             return _LeafOut((p32 - lr * upd).astype(p.dtype), m2, v2)
 
-        out = jax.tree_util.tree_map(leaf, params, grads,
-                                     state.slots["m"], state.slots["v"])
+        with self._phase("update"):
+            out = jax.tree_util.tree_map(leaf, params, grads,
+                                         state.slots["m"], state.slots["v"])
         p2, m2, v2 = self._split(out, 3)
         return p2, FusedOptState(count=count, slots={"m": m2, "v": v2})
 
@@ -238,6 +261,7 @@ class FusedSGD(FusedOptimizer):
     """SGD with momentum (`apex/optimizers/fused_sgd.py:6-217`)."""
 
     slot_names = ("m",)
+    scope = "sgd"
 
     def __init__(self, lr: Scalar = 1e-3, momentum=0.0, dampening=0.0,
                  weight_decay=0.0, nesterov=False, wd_after_momentum=False,
@@ -254,11 +278,12 @@ class FusedSGD(FusedOptimizer):
 
     def _partition_step(self, spec, dt, p, g, slots, count, lr, ctx):
         first = (count == 1) if self.momentum > 0 else False
-        p2, m2 = K.sgd_update(
-            p, g, slots["m"], lr=lr, momentum=self.momentum,
-            dampening=self.dampening, weight_decay=self.weight_decay,
-            nesterov=self.nesterov, first_run=first,
-            wd_after_momentum=self.wd_after_momentum)
+        with self._phase("update"):
+            p2, m2 = K.sgd_update(
+                p, g, slots["m"], lr=lr, momentum=self.momentum,
+                dampening=self.dampening, weight_decay=self.weight_decay,
+                nesterov=self.nesterov, first_run=first,
+                wd_after_momentum=self.wd_after_momentum)
         return p2, {"m": m2}
 
     def _tree_step(self, grads, state, params):
@@ -279,8 +304,9 @@ class FusedSGD(FusedOptimizer):
                 upd = upd + wd * p32
             return _LeafOut((p32 - lr * upd).astype(p.dtype), m2)
 
-        out = jax.tree_util.tree_map(leaf, params, grads,
-                                     state.slots["m"])
+        with self._phase("update"):
+            out = jax.tree_util.tree_map(leaf, params, grads,
+                                         state.slots["m"])
         p2, m2 = self._split(out, 2)
         return p2, FusedOptState(count=count, slots={"m": m2})
 
@@ -289,6 +315,7 @@ class FusedAdagrad(FusedOptimizer):
     """Adagrad (`apex/optimizers/fused_adagrad.py:5-95`)."""
 
     slot_names = ("h",)
+    scope = "adagrad"
 
     def __init__(self, lr: Scalar = 1e-2, eps=1e-10, weight_decay=0.0,
                  adagrad_w_mode=False, strategy: str = "auto"):
@@ -298,10 +325,11 @@ class FusedAdagrad(FusedOptimizer):
         self.adagrad_w_mode = adagrad_w_mode
 
     def _partition_step(self, spec, dt, p, g, slots, count, lr, ctx):
-        p2, h2 = K.adagrad_update(
-            p, g, slots["h"], lr=lr, eps=self.eps,
-            weight_decay=self.weight_decay,
-            adagrad_w_mode=self.adagrad_w_mode)
+        with self._phase("update"):
+            p2, h2 = K.adagrad_update(
+                p, g, slots["h"], lr=lr, eps=self.eps,
+                weight_decay=self.weight_decay,
+                adagrad_w_mode=self.adagrad_w_mode)
         return p2, {"h": h2}
 
     def _tree_step(self, grads, state, params):
@@ -320,8 +348,9 @@ class FusedAdagrad(FusedOptimizer):
                 upd = upd + wd * p32
             return _LeafOut((p32 - lr * upd).astype(p.dtype), h2)
 
-        out = jax.tree_util.tree_map(leaf, params, grads,
-                                     state.slots["h"])
+        with self._phase("update"):
+            out = jax.tree_util.tree_map(leaf, params, grads,
+                                         state.slots["h"])
         p2, h2 = self._split(out, 2)
         return p2, FusedOptState(count=count, slots={"h": h2})
 
@@ -356,6 +385,7 @@ class FusedLAMB(FusedOptimizer):
     """
 
     slot_names = ("m", "v")
+    scope = "lamb"
 
     def __init__(self, lr: Scalar = 1e-3, betas=(0.9, 0.999), eps=1e-6,
                  weight_decay=0.01, adam_w_mode=True, bias_correction=True,
@@ -375,10 +405,13 @@ class FusedLAMB(FusedOptimizer):
         (`fused_lamb.py:120-136`)."""
         if not self.max_grad_norm:
             return jnp.float32(1.0)
-        sq = sum(jnp.square(MT.multi_tensor_l2norm(g)) for g in g_all.values())
-        gnorm = jnp.sqrt(sq)
-        return jnp.where(gnorm > self.max_grad_norm,
-                         self.max_grad_norm / gnorm, 1.0).astype(jnp.float32)
+        with self._phase("norms"):
+            sq = sum(jnp.square(MT.multi_tensor_l2norm(g))
+                     for g in g_all.values())
+            gnorm = jnp.sqrt(sq)
+            return jnp.where(
+                gnorm > self.max_grad_norm, self.max_grad_norm / gnorm,
+                1.0).astype(jnp.float32)
 
     def _step_context(self, spec, g_bufs):
         # global grad norm computed ONCE per step over all partitions
@@ -386,17 +419,21 @@ class FusedLAMB(FusedOptimizer):
 
     def _partition_step(self, spec, dt, p, g, slots, count, lr, ctx):
         clip = ctx
-        u, m2, v2 = K.lamb_stage1(
-            p, g, slots["m"], slots["v"], beta1=self.beta1, beta2=self.beta2,
-            eps=self.eps, weight_decay=self.weight_decay, step=count,
-            bias_correction=self.bias_correction,
-            adam_w_mode=self.adam_w_mode, clip_scale=clip)
+        with self._phase("update"):
+            u, m2, v2 = K.lamb_stage1(
+                p, g, slots["m"], slots["v"], beta1=self.beta1,
+                beta2=self.beta2, eps=self.eps,
+                weight_decay=self.weight_decay, step=count,
+                bias_correction=self.bias_correction,
+                adam_w_mode=self.adam_w_mode, clip_scale=clip)
 
         part = spec.partition(dt)
-        ratio_pos = lamb_trust_ratios(part, p, u,
-                                      use_nvlamb=self.use_nvlamb,
-                                      weight_decay=self.weight_decay)
-        p2 = K.lamb_stage2(p, u, ratio_pos, lr=lr)
+        with self._phase("norms"):
+            ratio_pos = lamb_trust_ratios(part, p, u,
+                                          use_nvlamb=self.use_nvlamb,
+                                          weight_decay=self.weight_decay)
+        with self._phase("update"):
+            p2 = K.lamb_stage2(p, u, ratio_pos, lr=lr)
         return p2, {"m": m2, "v": v2}
 
     def _tree_step(self, grads, state, params):
@@ -409,35 +446,39 @@ class FusedLAMB(FusedOptimizer):
 
         # global grad-norm clip factor (`fused_lamb.py:120-136`)
         if self.max_grad_norm:
-            sq = sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
-                     for g in jax.tree_util.tree_leaves(grads))
-            gnorm = jnp.sqrt(sq)
-            clip = jnp.where(gnorm > self.max_grad_norm,
-                             self.max_grad_norm / gnorm, 1.0)
+            with self._phase("norms"):
+                sq = sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
+                         for g in jax.tree_util.tree_leaves(grads))
+                gnorm = jnp.sqrt(sq)
+                clip = jnp.where(gnorm > self.max_grad_norm,
+                                 self.max_grad_norm / gnorm, 1.0)
         else:
             clip = jnp.float32(1.0)
         plain_identity = not self.use_nvlamb and self.weight_decay == 0.0
 
         def leaf(p, g, m, v):
-            p32 = p.astype(jnp.float32)
-            g32 = g.astype(jnp.float32) * clip
-            if not self.adam_w_mode:
-                g32 = g32 + wd * p32
-            m2 = b1 * m + (1.0 - b1) * g32
-            v2 = b2 * v + (1.0 - b2) * g32 * g32
-            u = (m2 / bc1) / (jnp.sqrt(v2 / bc2) + eps)
-            if self.adam_w_mode:
-                u = u + wd * p32
+            with self._phase("update"):
+                p32 = p.astype(jnp.float32)
+                g32 = g.astype(jnp.float32) * clip
+                if not self.adam_w_mode:
+                    g32 = g32 + wd * p32
+                m2 = b1 * m + (1.0 - b1) * g32
+                v2 = b2 * v + (1.0 - b2) * g32 * g32
+                u = (m2 / bc1) / (jnp.sqrt(v2 / bc2) + eps)
+                if self.adam_w_mode:
+                    u = u + wd * p32
             # per-tensor trust ratio — each leaf IS one tensor, so the
             # norms are plain reduces (no arena segments needed)
             if plain_identity:
                 ratio = jnp.float32(1.0)
             else:
-                pn = jnp.sqrt(jnp.sum(jnp.square(p32)))
-                un = jnp.sqrt(jnp.sum(jnp.square(u)))
-                ratio = jnp.where((pn > 0) & (un > 0), pn / un, 1.0)
-            return _LeafOut((p32 - lr * ratio * u).astype(p.dtype), m2,
-                            v2)
+                with self._phase("norms"):
+                    pn = jnp.sqrt(jnp.sum(jnp.square(p32)))
+                    un = jnp.sqrt(jnp.sum(jnp.square(u)))
+                    ratio = jnp.where((pn > 0) & (un > 0), pn / un, 1.0)
+            with self._phase("update"):
+                return _LeafOut(
+                    (p32 - lr * ratio * u).astype(p.dtype), m2, v2)
 
         out = jax.tree_util.tree_map(leaf, params, grads,
                                      state.slots["m"], state.slots["v"])
@@ -457,6 +498,7 @@ class FusedNovoGrad(FusedOptimizer):
     """
 
     slot_names = ("m",)
+    scope = "novograd"
 
     def __init__(self, lr: Scalar = 1e-3, betas=(0.9, 0.999), eps=1e-8,
                  weight_decay=0.0, bias_correction=True,
@@ -500,8 +542,9 @@ class FusedNovoGrad(FusedOptimizer):
         if self._use_tree(params):
             return self._tree_step(grads, state, params)
         spec = arena.plan(params)
-        p_bufs = arena.flatten(params, spec)
-        g_bufs = arena.flatten(grads, spec, cast=jnp.float32)
+        with self._phase("arena"):
+            p_bufs = arena.flatten(params, spec)
+            g_bufs = arena.flatten(grads, spec, cast=jnp.float32)
         count = state.count + 1
         lr = self.lr(count) if callable(self.lr) else self.lr
 
@@ -510,29 +553,32 @@ class FusedNovoGrad(FusedOptimizer):
         for part in spec.partitions:
             dt = part.dtype
             p, g = p_bufs[dt], g_bufs[dt]
-            norms = self._per_tensor_norm(g, part)
-            v_prev = state.slots["vnorm"][dt]
-            blended = self.beta2 * v_prev + (1.0 - self.beta2) * norms
-            if self.init_zero:
-                v_new = blended
-            else:
-                # init with first-step norm so the first blend is a no-op
-                # (`fused_novograd.py:163-174`)
-                v_new = jnp.where(count == 1, norms, blended)
-            vpos = MT.spread_per_tensor(v_new, part.offsets, part.padded,
-                                        len(p), fill=1.0)
-            p2, m2 = K.novograd_update(
-                p, g, state.slots["m"][dt], vpos, lr=lr, beta1=self.beta1,
-                beta2=self.beta2, eps=self.eps,
-                weight_decay=self.weight_decay, step=count,
-                grad_averaging=self.grad_averaging,
-                bias_correction=self.bias_correction,
-                reg_inside_moment=self.reg_inside_moment)
+            with self._phase("norms"):
+                norms = self._per_tensor_norm(g, part)
+                v_prev = state.slots["vnorm"][dt]
+                blended = self.beta2 * v_prev + (1.0 - self.beta2) * norms
+                if self.init_zero:
+                    v_new = blended
+                else:
+                    # init with first-step norm so the first blend is a
+                    # no-op (`fused_novograd.py:163-174`)
+                    v_new = jnp.where(count == 1, norms, blended)
+                vpos = MT.spread_per_tensor(
+                    v_new, part.offsets, part.padded, len(p), fill=1.0)
+            with self._phase("update"):
+                p2, m2 = K.novograd_update(
+                    p, g, state.slots["m"][dt], vpos, lr=lr,
+                    beta1=self.beta1, beta2=self.beta2, eps=self.eps,
+                    weight_decay=self.weight_decay, step=count,
+                    grad_averaging=self.grad_averaging,
+                    bias_correction=self.bias_correction,
+                    reg_inside_moment=self.reg_inside_moment)
             new_p[dt] = p2
             new_slots["m"][dt] = m2
             new_slots["vnorm"][dt] = v_new
-        return (arena.unflatten(new_p, spec),
-                FusedOptState(count=count, slots=new_slots))
+        with self._phase("arena"):
+            new_params = arena.unflatten(new_p, spec)
+        return new_params, FusedOptState(count=count, slots=new_slots)
 
     def _tree_step(self, grads, state, params):
         count = state.count + 1
@@ -544,24 +590,26 @@ class FusedNovoGrad(FusedOptimizer):
         b3 = (1.0 - b1) if self.grad_averaging else 1.0
 
         def leaf(p, g, m, vprev):
-            p32 = p.astype(jnp.float32)
-            g32 = g.astype(jnp.float32)
-            if self.norm_type == 2:
-                nrm = jnp.sqrt(jnp.sum(jnp.square(g32)))
-            else:
-                nrm = jnp.max(jnp.abs(g32))
-            blended = b2 * vprev + (1.0 - b2) * nrm
-            v_new = blended if self.init_zero else \
-                jnp.where(count == 1, nrm, blended)
-            denom = v_new / bc2 + eps
-            if self.reg_inside_moment:
-                gg = g32 / denom + wd * p32
-                m2 = b1 * m + b3 * gg
-                p2 = p32 - lr * (m2 / bc1)
-            else:
-                m2 = b1 * m + b3 * g32
-                p2 = p32 - lr * ((m2 / bc1) / denom + wd * p32)
-            return _LeafOut(p2.astype(p.dtype), m2, v_new)
+            with self._phase("norms"):
+                p32 = p.astype(jnp.float32)
+                g32 = g.astype(jnp.float32)
+                if self.norm_type == 2:
+                    nrm = jnp.sqrt(jnp.sum(jnp.square(g32)))
+                else:
+                    nrm = jnp.max(jnp.abs(g32))
+                blended = b2 * vprev + (1.0 - b2) * nrm
+                v_new = blended if self.init_zero else \
+                    jnp.where(count == 1, nrm, blended)
+            with self._phase("update"):
+                denom = v_new / bc2 + eps
+                if self.reg_inside_moment:
+                    gg = g32 / denom + wd * p32
+                    m2 = b1 * m + b3 * gg
+                    p2 = p32 - lr * (m2 / bc1)
+                else:
+                    m2 = b1 * m + b3 * g32
+                    p2 = p32 - lr * ((m2 / bc1) / denom + wd * p32)
+                return _LeafOut(p2.astype(p.dtype), m2, v_new)
 
         out = jax.tree_util.tree_map(leaf, params, grads,
                                      state.slots["m"],

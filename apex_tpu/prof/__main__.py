@@ -1,17 +1,26 @@
-"""CLI: parse a profiler logdir into a per-op device-time table.
+"""CLI: parse a profiler logdir into device-time tables.
 
 The command-line mirror of the reference's offline analyzers
 (`python -m apex.pyprof.parse` over nvprof SQLite →
 `apex/pyprof/parse/parse.py:1-30`, and the analyzed table of
 `python -m apex.pyprof.prof` → `apex/pyprof/prof/prof.py:1-256`). Here
 the artifact is a ``jax.profiler`` trace directory (written by
-``apex_tpu.prof.trace`` or any jax trace capture) and the analysis is
-per-HLO-op device timing plus category rollups.
+``apex_tpu.prof.trace`` or any jax trace capture, e.g.
+``benchmark/.out/<cell>/trace`` after a ``--trace 1`` run) and the
+analysis is per-HLO-op device timing, rolled up by the runtime's op
+category, by the program's named scopes (``amp/fwd`` forward and backward,
+``amp/update``, ``ddp/sync_gradients``) and by kernel name and optimizer
+phase (``apex_attn_fwd``, ``optim/lamb/norms``).
+
+Where the trace holds three or more runs of the step program, the tables
+cover its whole steps — from the start of the second run to the end of the
+last but one, the first and the last may be clipped by the profiler — and
+read in ms per step; otherwise they cover the whole trace.
 
 Usage::
 
-    python -m apex_tpu.prof /tmp/trace            # top-30 op table
-    python -m apex_tpu.prof /tmp/trace --top 100
+    python -m apex_tpu.prof /tmp/trace            # top-30 op table + rollups
+    python -m apex_tpu.prof /tmp/trace --top 100 --depth 3
     python -m apex_tpu.prof /tmp/trace --csv      # machine-readable
 """
 
@@ -21,6 +30,14 @@ import argparse
 import sys
 
 
+def _rollup(title, by, steps, total):
+    unit = "ms/step" if steps else "ms"
+    print(f"\n{title:<52} {unit:>10} {'%':>6}")
+    for key, us in by.items():
+        print(f"{key[:52]:<52} {us / 1e3 / (steps or 1):>10.3f} "
+              f"{100 * us / total:>5.1f}%")
+
+
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(
         prog="python -m apex_tpu.prof",
@@ -28,8 +45,11 @@ def main(argv=None) -> int:
     p.add_argument("logdir", help="trace directory (contains *.xplane.pb)")
     p.add_argument("--top", type=int, default=30,
                    help="rows in the op table (default 30)")
+    p.add_argument("--depth", type=int, default=2,
+                   help="scope path components in the by-scope table "
+                        "(default 2)")
     p.add_argument("--csv", action="store_true",
-                   help="emit name,category,count,total_us rows")
+                   help="emit name,category,count,total_us,scope rows")
     args = p.parse_args(argv)
 
     from apex_tpu.prof.xplane import parse_trace
@@ -39,17 +59,27 @@ def main(argv=None) -> int:
         print("no device ops found in trace (CPU-only run, or no "
               "*.xplane.pb under the logdir)", file=sys.stderr)
         return 1
+    runs, steps = tp.step_runs, 0
+    if len(runs) >= 3:
+        tp, steps = tp.window(runs[1][0], runs[-2][1]), len(runs) - 2
     if args.csv:
-        print("name,category,occurrences,total_us")
+        print("name,category,occurrences,total_us,scope")
         for r in tp.ops:
             print(f"{r.name},{r.category},{r.occurrences},"
-                  f"{r.total_us:.1f}")
-    else:
-        print(tp.table(top=args.top))
-        print()
-        for cat, us in sorted(tp.by_category().items(),
-                              key=lambda kv: -kv[1]):
-            print(f"{cat:<16} {us:12.0f}us")
+                  f"{r.total_us:.1f},{r.scope}")
+        return 0
+    total = tp.total_us or 1.0
+    if steps:
+        print(f"{tp.device}: {steps} whole steps of {len(runs)} traced "
+              f"runs, {tp.module_total_us / steps / 1e3:.3f} ms a step, "
+              f"{total / steps / 1e3:.3f} ms of it busy")
+    print(tp.table(top=args.top))
+    _rollup("category", tp.by_category(), steps, total)
+    _rollup(f"scope (depth {args.depth})",
+            tp.by_scope(depth=args.depth, phases=True), steps, total)
+    own = tp.by_own_scope()
+    if own:
+        _rollup("kernel / optimizer phase", own, steps, total)
     return 0
 
 

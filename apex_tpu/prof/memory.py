@@ -48,6 +48,7 @@ import numpy as np
 from apex_tpu.prof.hlo import _DTYPE_BYTES, _compile, cost_analysis_of
 # ONE scope-stripping rule for device-time attribution (xplane) and
 # byte attribution (here)
+from apex_tpu.prof.xplane import HLO_TEXT_SCOPE_RE
 from apex_tpu.prof.xplane import strip_scope as _strip_scope
 
 __all__ = [
@@ -72,8 +73,6 @@ _INSTR_RE = re.compile(
     r"^(?P<root>ROOT )?%?(?P<n>[^ ]+) = "
     r"(?P<shape>\((?:[^()]|\([^()]*\))*\)|[^ ]+) "
     r"(?P<op>[\w-]+)\(")
-
-_OP_NAME_RE = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
 
 # opcodes whose "result" is a view / control artifact, not a fresh
 # HBM allocation — excluded from the liveness walk
@@ -239,7 +238,7 @@ def parse_entry(hlo_text: str):
             continue
         name = m.group("n").lstrip("%")
         shape, op = m.group("shape"), m.group("op")
-        sm = _OP_NAME_RE.search(line)
+        sm = HLO_TEXT_SCOPE_RE.search(line)
         op_name = sm.group(1) if sm else ""
         if op == "parameter":
             pm = _PARAM_NUM_RE.search(line)

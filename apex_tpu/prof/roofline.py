@@ -53,7 +53,7 @@ import numpy as np
 
 from apex_tpu.prof.hlo import _DTYPE_BYTES, _conv_flops, _dot_flops
 from apex_tpu.prof.report import PEAK_FLOPS, PEAK_HBM_BW, lookup_peak
-from apex_tpu.prof.xplane import strip_scope
+from apex_tpu.prof.xplane import HLO_TEXT_SCOPE_RE, strip_scope
 
 __all__ = ["RooflineRow", "RooflineReport", "roofline_report",
            "classify_family", "FAMILIES", "BOUND_CLASSES"]
@@ -96,7 +96,6 @@ _FAMILY_PATTERNS: Tuple[Tuple[str, str], ...] = (
 )
 
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
-_OP_NAME_RE = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
 _CALLS_RE = re.compile(r"calls=%?([\w.\-]+)")
 _INSTR_RE = re.compile(
     r"^(?:ROOT )?%?(?P<n>[^ ]+) = "
@@ -253,7 +252,7 @@ def _module_costs(hlo_text: str) -> Dict[str, Dict[str, Any]]:
         called = _CALLS_RE.search(line)
         if called:
             flops += comp_flops.get(called.group(1), 0.0)
-        sm = _OP_NAME_RE.search(line)
+        sm = HLO_TEXT_SCOPE_RE.search(line)
         scope_raw = sm.group(1) if sm else ""
         mxu_cap = 1.0
         if (op == "custom-call"
@@ -615,17 +614,18 @@ def roofline_report(compiled=None, profile=None, *,
             profile_total += rec.total_us
             cost = costs.get(rec.name)
             if cost is None:
-                # analytic from the op's own metadata HLO (inline
-                # operand types — the committed-fixture path)
-                cost = _module_costs("ENTRY fallback {\n  "
-                                     + rec.hlo + "\n}") .get(rec.name)
+                # analytic from the op's own instruction text (inline
+                # operand types — the committed-fixture path), with the
+                # trace's scope put where a module's text carries it
+                cost = _module_costs(
+                    "ENTRY fallback {\n  " + rec.hlo
+                    + f', metadata={{op_name="{rec.scope}"}}\n}}'
+                ).get(rec.name)
             if cost is None:
                 cost = {"flops": 0.0, "bytes": 0.0, "opcode": rec.opcode,
-                        "shape": "", "scope": "", "scope_raw": "",
+                        "shape": "", "scope": strip_scope(rec.scope),
+                        "scope_raw": rec.scope,
                         "mxu_cap": 1.0, "hlo": rec.hlo[:400]}
-                m = _OP_NAME_RE.search(rec.hlo)
-                if m:
-                    cost["scope"] = strip_scope(m.group(1))
             seen.add(rec.name)
             rows.append(_mk(rec.name, cost, rec.occurrences,
                             rec.avg_us, rec.category))

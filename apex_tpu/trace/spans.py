@@ -21,7 +21,12 @@ read plus the named-scope enter (no ops added to the compiled program —
 asserted by the ``trace/no-extra-dispatch`` compile check). Spans inside
 a jitted function execute at *trace time* only; their host durations
 attribute compile/trace cost (useful on step 0), while their named
-scopes attribute device time on every step via xplane. Host-side spans
+scopes attribute device time on every step: the TPU's trace carries, for
+each op, the scope path it was compiled under (the ``tf_op`` stat of the
+event metadata), which :func:`apex_tpu.prof.parse_trace` reads into
+``OpRecord.scope`` and :meth:`TraceProfile.by_scope` sums (``amp/fwd``
+forward and backward, ``amp/update``, ``ddp/sync_gradients``, ...). A
+fusion carries the scope of one of its ops. Host-side spans
 around the dispatch measure wall clock per step — remember jax dispatch
 is async, so wrap the sync point (e.g. the host fetch) in its own span.
 """

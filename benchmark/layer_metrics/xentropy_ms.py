@@ -1,0 +1,13 @@
+"""Device time a step spends in the softmax cross-entropy kernels, forward and
+backward: the ops traced under a scope ``apex_xentropy_*``, chip 0, per step
+of the window."""
+
+UNIT = "ms"
+LAYER = "fused kernels"
+MOVES = "samples_per_s_per_chip"
+
+
+def read(trace, run_info):
+    import scope_reduce
+    return scope_reduce.ms_per_step(
+        trace, lambda r: scope_reduce.kernel(r).startswith("apex_xentropy_"))

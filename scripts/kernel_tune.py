@@ -161,7 +161,8 @@ def sweep_specs():
                 mt._scale_kernel, [b_],
                 outs=[("block", jnp_.float32),
                       ("scalar", jnp_.float32)],
-                scalars=[2.0], block_rows=block["block_rows"])
+                scalars=[2.0], block_rows=block["block_rows"],
+                name="apex_rows_scale")
             return out, flag
         return fn, (buf,)
 
@@ -350,7 +351,6 @@ def audit_tune_report(tmp, db):
     from apex_tpu.ops import autotune
 
     print("== tune_report joins worst_gaps off the BERT-layer fixture")
-    os.environ["APEX_TPU_XPLANE_PURE"] = "1"
     tp = xplane.parse_trace(os.path.join(_FIXTURES,
                                          "bert_layer.xplane.pb"))
     rep = roofline.roofline_report(profile=tp, device_kind="TPU v5 lite")

@@ -53,7 +53,6 @@ def audit_fixture_join(tmp: str) -> None:
     from apex_tpu.prof import roofline, xplane
 
     print("== roofline join on the committed BERT-layer fixture")
-    os.environ["APEX_TPU_XPLANE_PURE"] = "1"     # tf-free decode path
     tp = xplane.parse_trace(os.path.join(_FIXTURES,
                                          "bert_layer.xplane.pb"))
     rep = roofline.roofline_report(profile=tp,

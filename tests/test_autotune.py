@@ -225,7 +225,8 @@ class TestBitwiseNumerics:
             out, flag = _dispatch.launch(
                 mt._scale_kernel, [buf],
                 outs=[("block", jnp.float32), ("scalar", jnp.float32)],
-                scalars=[1.7], block_rows=block_rows)
+                scalars=[1.7], block_rows=block_rows,
+                name="apex_rows_scale")
             return np.asarray(out), bool(flag[0, 0] == 0.0)
 
         out_d, ok_d = scale(None)
@@ -289,7 +290,7 @@ class TestBlockRefusal:
                     mt._scale_kernel, [buf],
                     outs=[("block", jnp.float32),
                           ("scalar", jnp.float32)],
-                    scalars=[2.0])
+                    scalars=[2.0], name="apex_rows_scale")
         msgs = [str(w.message) for w in rec]
         assert any(fp in m and "falling back" in m
                    and f"BLOCK_ROWS={_dispatch.BLOCK_ROWS}" in m
@@ -304,7 +305,8 @@ class TestBlockRefusal:
             out, _ = _dispatch.launch(
                 mt._scale_kernel, [buf],
                 outs=[("block", jnp.float32), ("scalar", jnp.float32)],
-                scalars=[3.0], block_rows=384)
+                scalars=[3.0], block_rows=384,
+                name="apex_rows_scale")
         assert float(out[0]) == 3.0
 
     def test_as_rows_refusal_names_the_contract(self):

@@ -98,12 +98,27 @@ def test_op_estimates_finds_dot():
 
 
 def _build_xspace(tmp_path):
-    """Hand-build an XSpace proto shaped like a real TPU trace."""
+    """Hand-build an XSpace proto shaped like a real TPU trace, with
+    tensorflow's own proto classes: what the pure-python decoder reads
+    of it is held to the schema, field number by field number."""
     from tensorflow.tsl.profiler.protobuf import xplane_pb2
 
     xs = xplane_pb2.XSpace()
     plane = xs.planes.add()
     plane.name = "/device:TPU:0"
+    stat_ids = {}
+    for i, name in enumerate(
+            ("tf_op", "hlo_category", "flops", "bytes_accessed",
+             "memory_access_breakdown", "peak", "kind", "loop fusion"), 1):
+        plane.stat_metadata[i].id = i
+        plane.stat_metadata[i].name = name
+        stat_ids[name] = i
+
+    def stat(md, name, **value):
+        s = md.stats.add()
+        s.metadata_id = stat_ids[name]
+        for k, v in value.items():
+            setattr(s, k, v)
 
     md_mod = plane.event_metadata[1]
     md_mod.id = 1
@@ -113,6 +128,17 @@ def _build_xspace(tmp_path):
     md_fus.name = ("%fusion.3 = f32[128,128]{1,0:T(8,128)} "
                    "fusion(f32[128,128]{1,0} %p0), kind=kLoop, "
                    "calls=%fused_computation")
+    stat(md_fus, "tf_op",
+         str_value="jit(step)/transpose(jvp(amp/fwd))/Dense_0/add:")
+    stat(md_fus, "hlo_category", ref_value=stat_ids["loop fusion"])
+    stat(md_fus, "flops", int64_value=0)
+    stat(md_fus, "bytes_accessed", int64_value=131072)
+    # {read, space 1 (HBM), 65536 B} + {write, space 3 (on chip), 65536 B}
+    stat(md_fus, "memory_access_breakdown", bytes_value=(
+        b"\n\x08\x08\x01\x10\x01\x18\x80\x80\x04"
+        b"\n\x08\x08\x02\x10\x03\x18\x80\x80\x04"))
+    stat(md_fus, "peak", double_value=202.7)
+    stat(md_fus, "kind", uint64_value=7)
     md_conv = plane.event_metadata[3]
     md_conv.id = 3
     md_conv.name = ("%convolution.7 = f32[8,16,16,64]{3,2,1,0} "
@@ -129,12 +155,15 @@ def _build_xspace(tmp_path):
 
     ops = plane.lines.add()
     ops.name = "XLA Ops"
+    ops.timestamp_ns = 5
     for i in range(2):
         ev = ops.events.add()
         ev.metadata_id = 2
+        ev.offset_ps = i * 10**9
         ev.duration_ps = 100_000_000  # 100 us
         ev = ops.events.add()
         ev.metadata_id = 3
+        ev.offset_ps = i * 10**9 + 100_000_000
         ev.duration_ps = 300_000_000  # 300 us
 
     p = tmp_path / "host.xplane.pb"
@@ -155,12 +184,32 @@ def test_xplane_parser_synthetic(tmp_path):
     assert conv.category == "conv"
     assert conv.occurrences == 2
     assert conv.total_us == pytest.approx(600.0)
+    assert (conv.scope, conv.hlo_category, conv.flops, conv.hbm_bytes) == (
+        "", "", None, None)          # no stats: the opcode's category
     fus = tp.ops[1]
-    assert fus.category == "fusion.loop"
+    assert fus.category == fus.hlo_category == "loop fusion"   # a ref_value
     assert fus.avg_us == pytest.approx(100.0)
+    assert fus.scope == "jit(step)/transpose(jvp(amp/fwd))/Dense_0/add"
+    assert fus.phase == "bwd"
+    assert fus.flops is None and fus.bytes_accessed == 131072
+    assert fus.hbm_bytes == 65536
     cats = tp.by_category()
     assert cats["conv"] == pytest.approx(600.0)
     assert "conv" in tp.table()
+    # every kind of XStat value comes through by its stat's name
+    from apex_tpu.prof.xplane import decode_xspace
+    with open(path, "rb") as f:
+        plane = decode_xspace(f.read()).planes[0]
+    assert plane.event_metadata[2].stats["peak"] == 202.7
+    assert plane.event_metadata[2].stats["kind"] == 7
+    assert plane.lines[1].timestamp_ns == 5
+    # the runs of the step program, and a window cut on the device's clock
+    assert tp.step_runs == [(0.0, 500_000.0), (1_000_000.0, 1_500_000.0)]
+    cut = tp.window(1_000_000.0, 1_100_205.0)   # the ops' line starts 5 ns in
+    assert [(r.opcode, r.occurrences, r.total_us) for r in cut.ops] == [
+        ("fusion", 1, 100.0), ("convolution", 1, pytest.approx(0.2))]
+    assert cut.module_runs == 1 and cut.window_ns == (1_000_000.0,
+                                                      1_100_205.0)
 
 
 FIXTURE = os.path.join(os.path.dirname(__file__), "fixtures",
@@ -180,9 +229,9 @@ def _block_tf(monkeypatch):
 
 
 def test_xplane_parse_without_tensorflow(monkeypatch):
-    """With the tf proto import blocked, the pure-python wire-format
-    decoder parses the committed fixture — the tool justifying every
-    perf claim no longer needs tensorflow (VERDICT r5 weak 6)."""
+    """The reader has one decoder, the pure-python one: with tensorflow
+    unimportable it still parses, so a benchmark process that holds the
+    chip never loads tensorflow (10-15 s, and a second libtpu loader)."""
     _block_tf(monkeypatch)
     tp = prof.parse_trace(FIXTURE)
     assert tp.device == "/device:TPU:0"
@@ -201,13 +250,8 @@ def test_xplane_corrupt_file_actionable_error(tmp_path, monkeypatch):
 
 
 class TestXplaneFixture:
-    """Pin the committed on-chip-shaped fixture's per-op table (pure
-    decoder forced — no tensorflow on the decode path), in lockstep
+    """Pin the committed synthetic fixture's per-op table, in lockstep
     with scripts/make_xplane_fixture.py."""
-
-    @pytest.fixture(autouse=True)
-    def _pure(self, monkeypatch):
-        monkeypatch.setenv("APEX_TPU_XPLANE_PURE", "1")
 
     def test_per_op_table(self):
         tp = prof.parse_trace(FIXTURE)
@@ -217,9 +261,9 @@ class TestXplaneFixture:
         rows = [(r.name, r.opcode, r.category, r.occurrences,
                  round(r.total_us, 1)) for r in tp.ops]
         assert rows == [
-            ("fusion.31", "fusion", "fusion.output", 2, 184.5),
+            ("fusion.31", "fusion", "output fusion", 2, 184.5),
             ("convolution.7", "convolution", "conv", 2, 148.0),
-            ("fusion.88", "fusion", "fusion.input", 2, 100.0),
+            ("fusion.88", "fusion", "input fusion", 2, 100.0),
             ("all-reduce.3", "all-reduce", "collective", 1, 41.0),
             ("custom-call.9", "custom-call", "custom-call", 1, 31.0),
             ("copy.5", "copy", "copy", 1, 12.5),
@@ -238,20 +282,114 @@ class TestXplaneFixture:
         assert scopes["ddp/sync_gradients"] == pytest.approx(41.0)
         assert scopes["(unscoped)"] == pytest.approx(12.5)
         assert "conv" in tp.table()
+        # the wrappers a scope keeps tell the two sides of the step apart
+        split = tp.by_scope(depth=2, phases=True)
+        assert split["amp/fwd [fwd]"] == pytest.approx(363.5)
+        assert split["amp/fwd [bwd]"] == pytest.approx(100.0)
+        assert tp.ops[0].scope == "jit(step)/jvp(amp/fwd)/stage3/bn_relu"
 
-    def test_parity_with_tensorflow_decoder(self, monkeypatch):
-        """When tensorflow IS available its decoder must agree with the
-        pure one bit for bit (skip silently where it isn't)."""
-        pytest.importorskip("tensorflow.tsl.profiler.protobuf.xplane_pb2")
-        tp_pure = prof.parse_trace(FIXTURE)
-        monkeypatch.delenv("APEX_TPU_XPLANE_PURE")
-        tp_tf = prof.parse_trace(FIXTURE)
-        key = lambda tp: [(r.name, r.opcode, r.occurrences, r.total_us,
-                           r.hlo) for r in tp.ops]
-        assert key(tp_pure) == key(tp_tf)
-        assert (tp_pure.device, tp_pure.module_runs,
-                tp_pure.module_total_us) == \
-            (tp_tf.device, tp_tf.module_runs, tp_tf.module_total_us)
+
+V5E_CAPTURE = os.path.join(os.path.dirname(__file__), "fixtures",
+                           "v5e_bert_steps.xplane.pb")
+
+
+class TestV5eCapture:
+    """What a chip really writes: the fixture cut from a v5e capture of the
+    benchmark's BERT cell (tests/fixtures/README.md). The synthetic
+    fixtures above follow its layout, not the other way round."""
+
+    #: name -> (opcode, category, runs, flops, bytes_accessed, hbm_bytes,
+    #: scope): the compiler's counts for one run, byte for byte
+    ROWS = {
+        # the vocabulary GEMM's backward: FLOPs from the compiler, most of
+        # its bytes from HBM
+        "multiply_reduce_fusion.2": (
+            "fusion", "convolution fusion", 3, 512230490112, 641867776,
+            562581508, "jit(step)/transpose(jvp(amp/fwd))/dot_general"),
+        # LAMB's first sweep over a 4096x1024 f32 weight: p, m, v read and
+        # m, v written in HBM (5 x 16 MiB + two scalars), the bf16 gradient
+        # read from on-chip memory (operand marked S(1): 8 MiB more in
+        # bytes_accessed) -- memory space 1 is HBM
+        "multiply_reduce_fusion.63": (
+            "fusion", "loop fusion", 3, 79691776, 92274704, 83886088,
+            "jit(step)/amp/update/optim/lamb/norms/reduce_sum"),
+        # its second sweep: 4 x 16 MiB, all of it HBM
+        "multiply_subtract_fusion.12": (
+            "fusion", "loop fusion", 3, 33554432, 67108864, 67108864,
+            "jit(step)/amp/update/optim/lamb/update/sub"),
+        # a copy out of on-chip memory: bf16[8192,1024] written to HBM
+        "copy-done.653": (
+            "copy-done", "copy-done", 3, None, 16777240, 16777216, ""),
+        # Mosaic kernels: the instruction and the scope carry the kernel's
+        # name (ops/_dispatch.py), the compiler counts nothing inside
+        "apex_attn_fwd.47": (
+            "custom-call", "custom-call", 3, None, None, None,
+            "jit(step)/jvp(amp/fwd)/BertEncoder/TransformerLayer_23/"
+            "MultiheadAttention_0/SelfMultiheadAttn_0/apex_attn_fwd/"
+            "pallas_call"),
+        "apex_xentropy_bwd.1": (
+            "custom-call", "custom-call", 3, None, None, None,
+            "jit(step)/transpose(jvp(amp/fwd))/apex_xentropy_bwd/"
+            "pallas_call"),
+        # an async start: a tuple of tuples for a result shape
+        "slice-start.565": (
+            "async-start", "async-start", 3, None, 6291456, 3145728, ""),
+    }
+
+    @pytest.fixture(scope="class")
+    def tp(self):
+        return prof.parse_trace(V5E_CAPTURE)
+
+    def test_size_and_shape(self, tp):
+        assert os.path.getsize(V5E_CAPTURE) <= 150_000
+        assert tp.device == "/device:TPU:0"
+        assert tp.module_runs == 3 and len(tp.step_runs) == 3
+        assert all(r.occurrences == 3 for r in tp.ops)
+        assert not [r.name for r in tp.ops if r.opcode == "unknown"]
+
+    @pytest.mark.parametrize("name", sorted(ROWS))
+    def test_named_row(self, tp, name):
+        r = next(r for r in tp.ops if r.name == name)
+        assert (r.opcode, r.category, r.occurrences, r.flops,
+                r.bytes_accessed, r.hbm_bytes, r.scope) == self.ROWS[name]
+        assert r.hlo_category == r.category
+
+    def test_scopes_name_the_device_time(self, tp):
+        """Under 5% unscoped (the reader before PR 25 said 100%), and the
+        wrappers split forward from backward."""
+        scopes = tp.by_scope(depth=2, phases=True)
+        assert set(scopes) == {"amp/fwd [bwd]", "amp/fwd [fwd]",
+                               "amp/update", "(unscoped)"}
+        assert scopes["(unscoped)"] / tp.total_us < 0.05
+        assert scopes["amp/fwd [bwd]"] > scopes["amp/fwd [fwd]"] > \
+            scopes["amp/update"] > scopes["(unscoped)"]
+        assert tp.by_scope(depth=4)[
+            "amp/fwd/BertEncoder/TransformerLayer_23"] > 0.2 * tp.total_us
+        cats = tp.by_category()
+        assert list(cats)[:3] == ["convolution fusion", "custom-call",
+                                  "loop fusion"]
+        # every Mosaic call under a kernel's name, the optimizer's time
+        # under its two phases
+        own = tp.by_own_scope()
+        assert set(own) == {
+            "apex_attn_fwd", "apex_attn_bwd", "apex_layer_norm_fwd",
+            "apex_layer_norm_bwd", "apex_xentropy_fwd", "apex_xentropy_bwd",
+            "optim/lamb/norms", "optim/lamb/update"}
+        mosaic = sum(r.total_us for r in tp.ops
+                     if "tpu_custom_call" in r.hlo)
+        assert sum(v for k, v in own.items()
+                   if k.startswith("apex_")) == pytest.approx(mosaic)
+        assert own["optim/lamb/norms"] + own["optim/lamb/update"] == \
+            pytest.approx(scopes["amp/update"])
+
+    def test_one_whole_step(self, tp):
+        """The middle run, cut on the device's clock: every op once, a
+        third of the time."""
+        step = tp.window(*tp.step_runs[1])
+        assert step.module_runs == 1
+        assert all(r.occurrences == 1 for r in step.ops)
+        assert len(step.ops) == len(tp.ops)
+        assert step.total_us == pytest.approx(tp.total_us / 3, rel=2e-3)
 
 
 def test_trace_capture_roundtrip(tmp_path):
@@ -357,6 +495,12 @@ def test_opcode_categories_modern_traces():
         assert m, f"opcode regex missed: {text[:60]}"
         assert m.group("opcode") == want_opcode
         assert _categorize(m.group("opcode"), text) == want_cat
+    # the runtime's own category wins where the trace carries one, except
+    # over a collective, which monitor.collectives buckets by its opcode
+    assert _categorize("fusion", "kind=kLoop", "convolution fusion") == \
+        "convolution fusion"
+    assert _categorize("all-reduce-start", "", "all-reduce") == "collective"
+    assert _categorize("fusion", "", "all-gather fusion") == "collective"
 
 
 def test_by_scope_aggregates_named_scopes():
@@ -366,12 +510,10 @@ def test_by_scope_aggregates_named_scopes():
     metadata-less ops land under (unscoped)."""
     from apex_tpu.prof.xplane import OpRecord, TraceProfile
 
-    def rec(name, us, op_name=None):
+    def rec(name, us, op_name=""):
         hlo = f"%{name} = f32[8]{{0}} fusion(f32[8]{{0}} %p0)"
-        if op_name is not None:
-            hlo += f', metadata={{op_name="{op_name}"}}'
         return OpRecord(name=name, opcode="fusion", category="fusion",
-                        occurrences=1, total_us=us, hlo=hlo)
+                        occurrences=1, total_us=us, hlo=hlo, scope=op_name)
 
     tp = TraceProfile(path="", device="d", module_runs=1,
                       module_total_us=0.0, ops=[
@@ -379,14 +521,28 @@ def test_by_scope_aggregates_named_scopes():
         rec("f.2", 5.0, "jit(step)/transpose(jvp(step))/amp/fwd/dot"),
         rec("f.3", 2.0, "jit(step)/vmap(step)/amp/unscale/mul"),
         rec("f.4", 1.0, "jit(step)"),          # wrappers only
-        rec("f.5", 4.0),                       # no metadata at all
+        rec("f.5", 4.0),                       # no tf_op at all
+        rec("f.6", 3.0, "jit(step)/amp/update/optim/lamb/norms/reduce_sum"),
+        rec("f.7", 6.0, "jit(step)/jvp(amp/fwd)/Enc/Attn_0/apex_attn_fwd/"
+                        "pallas_call"),
+        rec("f.8", 7.0, "jit(loss)/transpose(jvp(apex_xentropy_bwd))/"
+                        "pallas_call"),
     ])
     got = tp.by_scope(depth=2)
-    assert got["amp/fwd"] == 15.0              # fwd + its transpose
+    assert got["amp/fwd"] == 21.0              # fwd + its transpose + f.7
     assert got["amp/unscale"] == 2.0
     assert got["(unscoped)"] == 5.0            # f.4 + f.5
     # depth=1 folds everything under the top-level scope
-    assert tp.by_scope(depth=1)["amp"] == 17.0
+    assert tp.by_scope(depth=1)["amp"] == 26.0
+    # kernel names and optimizer phases, wherever in the path they sit
+    assert tp.by_own_scope() == {"apex_xentropy_bwd": 7.0,
+                                 "apex_attn_fwd": 6.0,
+                                 "optim/lamb/norms": 3.0}
+    assert [r.phase for r in tp.ops] == ["", "bwd", "", "", "", "", "fwd",
+                                         "bwd"]
+    assert tp.total_us == 38.0
+    with pytest.raises(ValueError, match="parse_trace"):
+        tp.window(0.0, 1.0)                    # no events to cut from
 
 
 _REPO_ROOT = str(__import__("pathlib").Path(__file__).resolve().parents[1])
@@ -403,11 +559,15 @@ def test_cli_on_synthetic_trace(tmp_path):
         capture_output=True, text=True, cwd=_REPO_ROOT)
     assert r.returncode == 0, r.stderr
     assert "convolution" in r.stdout
+    # the rollups beside the op table: category, scope with the side of
+    # the step; two runs are too few to cut whole steps from, so in ms
+    assert "loop fusion" in r.stdout and "amp/fwd [bwd]" in r.stdout
+    assert "ms/step" not in r.stdout
     r2 = subprocess.run(
         [sys.executable, "-m", "apex_tpu.prof", str(tmp_path), "--csv"],
         capture_output=True, text=True, cwd=_REPO_ROOT)
     assert r2.returncode == 0
-    assert r2.stdout.startswith("name,category,occurrences,total_us")
+    assert r2.stdout.startswith("name,category,occurrences,total_us,scope")
 
 
 def test_cli_empty_dir(tmp_path):

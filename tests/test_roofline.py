@@ -44,10 +44,6 @@ class TestBertFixtureJoin:
     ledger through the tool (regenerate with
     scripts/make_xplane_fixture.py --bert)."""
 
-    @pytest.fixture(autouse=True)
-    def _pure(self, monkeypatch):
-        monkeypatch.setenv("APEX_TPU_XPLANE_PURE", "1")
-
     @pytest.fixture()
     def report(self):
         tp = prof.parse_trace(BERT_FIXTURE)
