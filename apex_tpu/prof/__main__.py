@@ -9,8 +9,9 @@ the artifact is a ``jax.profiler`` trace directory (written by
 ``benchmark/.out/<cell>/trace`` after a ``--trace 1`` run) and the
 analysis is per-HLO-op device timing, rolled up by the runtime's op
 category, by the program's named scopes (``amp/fwd`` forward and backward,
-``amp/update``, ``ddp/sync_gradients``) and by kernel name and optimizer
-phase (``apex_attn_fwd``, ``optim/lamb/norms``).
+``amp/update``, ``ddp/sync_gradients``), by kernel name and optimizer
+phase (``apex_attn_fwd``, ``optim/lamb/norms``) and, for a masked-LM step,
+by the head it took (``mlm/head_gathered``, ``mlm/head_full``).
 
 Where the trace holds three or more runs of the step program, the tables
 cover its whole steps — from the start of the second run to the end of the
@@ -80,6 +81,9 @@ def main(argv=None) -> int:
     own = tp.by_own_scope()
     if own:
         _rollup("kernel / optimizer phase", own, steps, total)
+    head = tp.by_head()
+    if head:
+        _rollup("MLM head, by the branch the steps took", head, steps, total)
     return 0
 
 

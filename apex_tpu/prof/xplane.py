@@ -75,6 +75,11 @@ HLO_TEXT_SCOPE_RE = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
 _OWN_SCOPE_RE = re.compile(
     r"(?:^|/|(?<!jit)\()(apex_\w+|optim/\w+/\w+)(?=[/)]|$)")
 
+# the two heads of ``models.mlm_loss`` (models/transformer.py): the scope is
+# round everything the branch runs, the xentropy kernels included, so the
+# device time under each says which branch the traced steps took
+_HEAD_SCOPE_RE = re.compile(r"(?:^|/|\()(mlm/head_\w+)(?=[/)]|$)")
+
 #: the memory space of ``memory_access_breakdown`` that is HBM
 HBM_MEMORY_SPACE = 1
 
@@ -277,6 +282,15 @@ class TraceProfile:
         """Device time per kernel name and optimizer phase, wherever in
         a user's module tree it sits (:func:`own_scope`)."""
         return self._sum_by(lambda r: own_scope(r.scope) or None)
+
+    def by_head(self) -> Dict[str, float]:
+        """Device time under each head of ``models.mlm_loss``:
+        ``mlm/head_gathered`` (the labelled rows, compacted) and
+        ``mlm/head_full`` (every row). A head no step took is absent."""
+        def key(r):
+            m = _HEAD_SCOPE_RE.search(r.scope)
+            return m.group(1) if m else None
+        return self._sum_by(key)
 
     def table(self, top: int = 20) -> str:
         total = self.total_us or 1.0
@@ -542,6 +556,23 @@ def _record(md: _Msg) -> OpRecord:
         hbm_bytes=_hbm_bytes(stats.get("memory_access_breakdown")))
 
 
+def _own_time(events) -> List[Tuple[int, int]]:
+    """``(metadata id, picoseconds)`` of each event, less what the events
+    inside it take. A ``conditional`` (or a ``while``) has an event of its
+    own on the ops line, round those of the branch it ran: counted whole,
+    the branch's time would be there twice, once with no scope at all."""
+    out, open_ = [], []                 # open_: [metadata id, end, own ps]
+    for mid, a, b in sorted(events, key=lambda e: (e[1], -e[2])):
+        while open_ and open_[-1][1] <= a:
+            done = open_.pop()
+            out.append((done[0], done[2]))
+        if open_:
+            open_[-1][2] -= min(b, open_[-1][1]) - a
+        open_.append([mid, b, b - a])
+    out.extend((mid, ps) for mid, _end, ps in open_)
+    return out
+
+
 def _aggregate(path: str, dev: _DeviceEvents,
                window_ns: Optional[Tuple[float, float]]) -> TraceProfile:
     if window_ns is None:
@@ -558,12 +589,12 @@ def _aggregate(path: str, dev: _DeviceEvents,
             yield mid, a, b
 
     agg: Dict[int, OpRecord] = {}
-    for mid, a, b in inside(dev.ops):
+    for mid, ps in _own_time(inside(dev.ops)):
         rec = agg.get(mid)
         if rec is None:
             rec = agg[mid] = _record(dev.metadata[mid])
         rec.occurrences += 1
-        rec.total_us += (b - a) / 1e6
+        rec.total_us += ps / 1e6
     modules = list(inside(dev.modules))
 
     by_module: Dict[int, float] = {}

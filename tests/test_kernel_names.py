@@ -132,7 +132,9 @@ def _ops(text):
       # d = 32 at the toy size: the packed (B*H, S, D) attention kernels
       "apex_attn_fwd_packed", "apex_attn_bwd_dq_packed",
       "apex_attn_bwd_dkv_packed", "apex_layer_norm_fwd",
-      "apex_layer_norm_bwd", "apex_xentropy_fwd", "apex_xentropy_bwd"]),
+      "apex_layer_norm_bwd", "apex_xentropy_fwd", "apex_xentropy_bwd",
+      # 2 x 128 rows at the toy size: a conditional between the two heads
+      "mlm/head_gathered", "mlm/head_full"]),
     ("resnet50", "img224_b256",
      ["optim/sgd/arena", "optim/sgd/update", "apex_rows_sgd",
       "apex_xentropy_fwd", "apex_xentropy_bwd"]),

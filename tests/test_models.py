@@ -1,5 +1,7 @@
 """Model family smoke + driver artifact tests (CPU mesh)."""
 
+import types
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -62,6 +64,210 @@ class TestTransformer:
         mask = jnp.asarray([[1, 1, 1, 1, 0, 0, 0, 0]])
         y = enc.apply(variables, toks, attn_mask=mask)
         assert y.shape == (1, 8, 32)
+
+
+# ---- the MLM head on the labelled rows only --------------------------------
+# B x S = 4 x 128 = 512 rows: the gathered head holds 128, one row block
+
+HEAD_B, HEAD_S, HEAD_V = 4, 128, 300
+HEAD_ROWS = HEAD_B * HEAD_S
+HEAD_CAP = 128
+
+
+def _label_case(case, rows=HEAD_ROWS, seq=HEAD_S, seed=7):
+    """Flat labels, -1 where a position is not labelled."""
+    rng = np.random.RandomState(seed)
+    labels = np.full(rows, -1, np.int32)
+    if case == "per_row_15pct":
+        picked = np.concatenate([
+            r * seq + rng.permutation(seq)[:round(0.15 * seq)]
+            for r in range(rows // seq)])
+    elif case == "one_row_all":         # the first sequence, whole: = cap
+        picked = np.arange(seq)
+    else:
+        count = {"none": 0, "one": 1, "cap": HEAD_CAP,
+                 "cap_plus_1": HEAD_CAP + 1, "every": rows}[case]
+        picked = rng.permutation(rows)[:count]
+    labels[picked] = rng.randint(0, HEAD_V, len(picked))
+    return labels
+
+
+@pytest.fixture(scope="module")
+def head_setup():
+    enc = models.BertEncoder(vocab_size=HEAD_V, hidden=32, layers=1,
+                             heads=2, max_len=HEAD_S)
+    tokens = jnp.asarray(np.random.RandomState(3).randint(
+        0, HEAD_V, (HEAD_B, HEAD_S)), jnp.int32)
+    params = enc.init(jax.random.PRNGKey(0), tokens)["params"]
+
+    def system(params, tokens, labels, smoothing):
+        return models.mlm_loss(enc, {"params": params}, tokens, labels,
+                               smoothing)
+
+    def plain(params, tokens, labels, smoothing):
+        """Every row's logits, then the pure-jnp cross-entropy."""
+        from apex_tpu import ops
+        hidden = enc.apply({"params": params}, tokens)
+        logits = hidden @ params["tok_emb"]["embedding"].T
+        losses = ops.softmax_cross_entropy_reference(logits, labels,
+                                                     smoothing)
+        return jnp.sum(losses) / jnp.maximum(jnp.sum(labels >= 0), 1)
+
+    def both(fn):       # one compile serves every label count
+        return jax.jit(jax.value_and_grad(fn), static_argnums=3)
+
+    return types.SimpleNamespace(
+        enc=enc, tokens=tokens, params=params, system=system, plain=plain,
+        system_grad=both(system), plain_grad=both(plain))
+
+
+def _assert_same(got, want):
+    (loss, grads), (ref_loss, ref_grads) = got, want
+    np.testing.assert_allclose(loss, ref_loss, rtol=1e-5, atol=1e-6)
+    flat, _ = jax.tree_util.tree_flatten_with_path(ref_grads)
+    assert len(flat) == len(jax.tree_util.tree_leaves(grads)) > 10
+    for (path, want_g), got_g in zip(flat, jax.tree_util.tree_leaves(grads)):
+        np.testing.assert_allclose(
+            got_g, want_g, rtol=1e-4, atol=1e-6,
+            err_msg=jax.tree_util.keystr(path))
+
+
+class TestMlmHead:
+    @pytest.mark.parametrize("smoothing", [0.0, 0.1])
+    @pytest.mark.parametrize("case", [
+        "none", "one", "per_row_15pct", "one_row_all", "cap", "cap_plus_1",
+        "every"])
+    def test_loss_and_gradients_equal_the_full_logits_reference(
+            self, head_setup, case, smoothing):
+        h = head_setup
+        labels = jnp.asarray(_label_case(case).reshape(HEAD_B, HEAD_S))
+        got = h.system_grad(h.params, h.tokens, labels, smoothing)
+        if case == "none":
+            assert float(got[0]) == 0.0
+            assert all(not np.any(np.asarray(g))
+                       for g in jax.tree_util.tree_leaves(got[1]))
+        _assert_same(got, h.plain_grad(h.params, h.tokens, labels, smoothing))
+
+    @pytest.mark.parametrize("case", ["cap_plus_1", "every"])
+    def test_over_capacity_is_the_old_head(self, head_setup, case):
+        """No label is dropped: a batch over the capacity gets what
+        ``mlm_loss`` computed before it had two heads, the loss to the bit
+        and the gradients to the order of a float32 sum."""
+        from apex_tpu import ops
+        h = head_setup
+
+        def old(params, tokens, labels):
+            hidden = h.enc.apply({"params": params}, tokens)
+            emb = params["tok_emb"]["embedding"]
+            logits = hidden @ emb.T.astype(hidden.dtype)
+            losses = ops.softmax_cross_entropy_loss(logits, labels, 0.0)
+            return jnp.sum(losses) / jnp.maximum(jnp.sum(labels >= 0), 1)
+
+        labels = jnp.asarray(_label_case(case).reshape(HEAD_B, HEAD_S))
+        loss, grads = h.system_grad(h.params, h.tokens, labels, 0.0)
+        old_loss, old_grads = jax.jit(jax.value_and_grad(old))(
+            h.params, h.tokens, labels)
+        assert float(loss) == float(old_loss)
+        for got, want in zip(jax.tree_util.tree_leaves(grads),
+                             jax.tree_util.tree_leaves(old_grads)):
+            np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-7)
+
+    def test_each_shard_takes_its_own_branch(self, head_setup, devices):
+        """Under shard_map one device's labels fit the capacity and the
+        other's do not: each gets its own batch's loss and gradients."""
+        from jax.sharding import Mesh, PartitionSpec as P
+        tokens, params, system, plain = (
+            head_setup.tokens, head_setup.params, head_setup.system,
+            head_setup.plain)
+        mesh = Mesh(np.array(devices[:2]), ("data",))
+        labels = jnp.asarray(np.concatenate([
+            _label_case("per_row_15pct"),                   # 76 <= 128
+            _label_case("cap_plus_1", seed=11)])            # 129 > 128
+            .reshape(2 * HEAD_B, HEAD_S))
+        tokens2 = jnp.concatenate([tokens, tokens[::-1]])
+
+        def local(params, tokens, labels):
+            loss, grads = jax.value_and_grad(system)(params, tokens,
+                                                     labels, 0.0)
+            return jax.tree_util.tree_map(lambda x: x[None], (loss, grads))
+
+        loss, grads = jax.jit(jax.shard_map(
+            local, mesh=mesh, in_specs=(P(), P("data"), P("data")),
+            out_specs=P("data"), check_vma=False))(params, tokens2, labels)
+        for shard in range(2):
+            rows = slice(shard * HEAD_B, (shard + 1) * HEAD_B)
+            got = jax.tree_util.tree_map(lambda x: x[shard], (loss, grads))
+            _assert_same(got, jax.value_and_grad(plain)(
+                params, tokens2[rows], labels[rows], 0.0))
+
+    def test_capacity_rule(self):
+        from apex_tpu.models.transformer import _head_capacity
+        # a quarter of the rows in whole 128-row blocks, never under one
+        assert _head_capacity(16 * 512) == 2048     # the benchmark's cell
+        assert _head_capacity(2 * 512) == 256       # its reference's rows
+        assert _head_capacity(HEAD_ROWS) == HEAD_CAP
+        assert _head_capacity(1000) == 128 and _head_capacity(16) == 128
+        assert _head_capacity(64 * 128) == 2048     # phase 1: 20 of 128
+
+    def test_lowered_head(self):
+        """What the compiled step rests on: one conditional a side of the
+        differentiation, a vocabulary GEMM of ``cap`` rows in the gathered
+        branch, nothing the size of the logits out of either conditional,
+        and both scopes in the lowered text."""
+        from apex_tpu.models.transformer import _mlm_head
+        hidden = jnp.ones((HEAD_ROWS, 32))
+        emb = jnp.ones((HEAD_V, 32))
+        labels = jnp.asarray(_label_case("per_row_15pct"))
+
+        def head(hidden, emb):
+            return _mlm_head(hidden, emb, labels, 0.0)
+
+        def conds(fn):
+            """The conditionals of ``fn``, not those inside one's branches
+            or inside an interpreted kernel."""
+            found = []
+
+            def walk(jaxpr):
+                for e in jaxpr.eqns:
+                    if e.primitive.name == "cond":
+                        found.append(e)
+                    elif e.primitive.name != "pallas_call":
+                        for sub in jax.core.jaxprs_in_params(e.params):
+                            walk(sub)
+            walk(jax.make_jaxpr(fn)(hidden, emb).jaxpr)
+            return found
+
+        def dots(jaxpr):
+            return [tuple(e.outvars[0].aval.shape) for e in jaxpr.eqns
+                    if e.primitive.name == "dot_general"]
+
+        fwd, = conds(head)
+        assert [v.aval.shape for v in fwd.outvars] == [()]
+        full, gathered = (b.jaxpr for b in fwd.params["branches"])
+        assert dots(gathered) == [(HEAD_CAP, HEAD_V)]
+        assert dots(full) == [(HEAD_ROWS, HEAD_V)]
+        # differentiated: the forward's, and one more for the backward
+        fwd2, bwd = conds(jax.grad(head, argnums=(0, 1)))
+        assert [v.aval.shape for v in fwd2.outvars] == [()]
+        assert [v.aval.shape for v in bwd.outvars] == [
+            (HEAD_ROWS, 32), (HEAD_V, 32)]
+        _, gathered_bwd = (b.jaxpr for b in bwd.params["branches"])
+        assert sorted(dots(gathered_bwd)) == [
+            (HEAD_CAP, 32), (HEAD_CAP, HEAD_V), (HEAD_V, 32)]
+        text = jax.jit(jax.value_and_grad(head)).lower(
+            hidden, emb).as_text(debug_info=True)
+        for scope in ("mlm/head_gathered", "mlm/head_full"):
+            for side in (f"_fun/{scope}/", f"_fun/jvp({scope})/",
+                         f"_fun/transpose(jvp({scope}))/"):
+                assert side in text, side
+
+    def test_few_rows_take_the_full_head_with_no_conditional(self):
+        from apex_tpu.models.transformer import _mlm_head
+        hidden, emb = jnp.ones((64, 32)), jnp.ones((HEAD_V, 32))
+        labels = jnp.zeros((64,), jnp.int32)
+        jaxpr = jax.make_jaxpr(
+            lambda h, e: _mlm_head(h, e, labels, 0.0))(hidden, emb)
+        assert "cond" not in {e.primitive.name for e in jaxpr.jaxpr.eqns}
 
 
 class TestDCGAN:

@@ -527,22 +527,67 @@ def test_by_scope_aggregates_named_scopes():
                         "pallas_call"),
         rec("f.8", 7.0, "jit(loss)/transpose(jvp(apex_xentropy_bwd))/"
                         "pallas_call"),
+        rec("f.9", 8.0, "jit(step)/jvp(amp/fwd)/cond/branch_1_fun/"
+                        "mlm/head_gathered/dot_general"),
+        rec("f.10", 9.0, "jit(step)/transpose(jvp(amp/fwd))/cond/"
+                         "branch_1_fun/transpose(jvp(mlm/head_gathered))/"
+                         "apex_xentropy_bwd/pallas_call"),
     ])
     got = tp.by_scope(depth=2)
-    assert got["amp/fwd"] == 21.0              # fwd + its transpose + f.7
+    assert got["amp/fwd"] == 38.0       # fwd + its transpose + f.7, f.9, f.10
     assert got["amp/unscale"] == 2.0
     assert got["(unscoped)"] == 5.0            # f.4 + f.5
     # depth=1 folds everything under the top-level scope
-    assert tp.by_scope(depth=1)["amp"] == 26.0
+    assert tp.by_scope(depth=1)["amp"] == 43.0
     # kernel names and optimizer phases, wherever in the path they sit
-    assert tp.by_own_scope() == {"apex_xentropy_bwd": 7.0,
+    assert tp.by_own_scope() == {"apex_xentropy_bwd": 16.0,
                                  "apex_attn_fwd": 6.0,
                                  "optim/lamb/norms": 3.0}
+    # the MLM head's branch holds its kernels too; the one not taken is absent
+    assert tp.by_head() == {"mlm/head_gathered": 17.0}
     assert [r.phase for r in tp.ops] == ["", "bwd", "", "", "", "", "fwd",
-                                         "bwd"]
-    assert tp.total_us == 38.0
+                                         "bwd", "fwd", "bwd"]
+    assert tp.total_us == 55.0
     with pytest.raises(ValueError, match="parse_trace"):
         tp.window(0.0, 1.0)                    # no events to cut from
+
+
+def test_a_conditional_counts_only_what_its_branch_leaves():
+    """A ``conditional`` has an event of its own on the ops line, round the
+    events of the branch it ran: each op keeps its time, the conditional
+    what is left, and the sum is the busy time (no time twice)."""
+    from apex_tpu.prof import xplane
+
+    def md(text, scope=""):
+        return xplane._Msg(name=text, display_name="",
+                           stats={"tf_op": scope} if scope else {})
+
+    gathered = "jit(step)/jvp(amp/fwd)/cond/branch_1_fun/mlm/head_gathered"
+    dev = xplane._DeviceEvents(
+        name="/device:TPU:0",
+        metadata={
+            1: md("%fusion.1 = f32[8]{0} fusion(%p)", "jit(step)/amp/update"),
+            2: md("%conditional = (f32[]) conditional(%pred, %a, %b), "
+                  "branch_computations={%region_0, %region_1}"),
+            3: md("%fusion.19 = bf16[2048,30522]{1,0} fusion(%h, %e)",
+                  gathered + "/dot_general"),
+            4: md("%apex_xentropy_fwd.5 = f32[2048,128]{1,0} custom-call(%x)",
+                  gathered + "/apex_xentropy_fwd/pallas_call"),
+        },
+        # ps: an op, then the conditional 100..1000 round two of its branch
+        ops=[(1, 0, 100), (2, 100, 1000), (3, 150, 800), (4, 800, 990)],
+        modules=[(9, 0, 1000)])
+    tp = xplane._aggregate("", dev, None)
+    got = {r.name: r.total_us * 1e6 for r in tp.ops}
+    assert got == pytest.approx({"fusion.1": 100, "conditional": 60,
+                                 "fusion.19": 650, "apex_xentropy_fwd.5": 190})
+    assert tp.total_us * 1e6 == pytest.approx(1000)
+    assert tp.by_head() == pytest.approx({"mlm/head_gathered": 840e-6})
+    # a window that cuts through the conditional keeps the partition
+    cut = xplane._aggregate("", dev, (0.5, 0.9))     # ns: 500..900 ps
+    assert cut.total_us * 1e6 == pytest.approx(400)
+    assert {r.name: r.total_us * 1e6 for r in cut.ops} == pytest.approx(
+        {"fusion.19": 300, "apex_xentropy_fwd.5": 100, "conditional": 0})
 
 
 _REPO_ROOT = str(__import__("pathlib").Path(__file__).resolve().parents[1])
