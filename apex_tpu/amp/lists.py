@@ -26,6 +26,8 @@ HALF_OPS = {
     "conv", "conv1d", "conv2d", "conv3d", "conv_transpose",
     "dense", "linear", "matmul", "einsum", "dot_general",
     "attention", "mlp", "rnn_cell", "lstm_cell", "gru_cell",
+    # ops/moe.py: the held experts' batched matmuls
+    "moe_experts",
 }
 
 # Precision-sensitive ops: keep fp32
@@ -37,6 +39,9 @@ FLOAT_OPS = {
     "exp", "expm1", "log", "log1p", "log2", "log10", "pow", "erf", "erfinv",
     "sum", "mean", "prod", "cumsum", "cumprod", "var", "std", "norm",
     "sigmoid_focal_loss", "renorm", "softplus", "gelu_exact",
+    # ops/delta_rule.py: the recurrence's state, decay and triangular solve;
+    # ops/moe.py: the router's scores, the choice and the weights
+    "gated_delta_rule", "moe_router",
 }
 
 # Multi-arg elementwise ops: promote to the widest floating dtype

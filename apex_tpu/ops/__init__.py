@@ -43,6 +43,11 @@ from apex_tpu.ops.attention import (
     mask_softmax_dropout,
 )
 from apex_tpu.ops.multihead_attn import SelfMultiheadAttn, EncdecMultiheadAttn
+from apex_tpu.ops.delta_rule import (
+    gated_delta_rule,
+    gated_delta_rule_reference,
+)
+from apex_tpu.ops import moe
 from apex_tpu.ops import autotune
 
 __all__ = [
@@ -57,4 +62,5 @@ __all__ = [
     "ConvBNAct", "conv_bn_act_train", "conv_bn_add_act_train",
     "flash_attention", "attention_reference", "mask_softmax_dropout",
     "SelfMultiheadAttn", "EncdecMultiheadAttn",
+    "gated_delta_rule", "gated_delta_rule_reference", "moe",
 ]

@@ -138,6 +138,16 @@ def _ops(text):
     ("resnet50", "img224_b256",
      ["optim/sgd/arena", "optim/sgd/update", "apex_rows_sgd",
       "apex_xentropy_fwd", "apex_xentropy_bwd"]),
+    ("kimi_linear", "lm_s8192_b1",
+     ["optim/adam/arena", "optim/adam/update", "apex_rows_adam",
+      "kda/proj", "kda/conv", "kda/gate", "kda/scan", "kda/out",
+      "mla/proj", "mla/attn", "mla/out", "lm/head",
+      # 192 rows, 2 of 16 experts a row: a held expert's capacity holds
+      # every row, so the overflow loop (moe/overflow) is not in this step
+      "moe/route", "moe/dispatch", "moe/experts", "moe/combine",
+      "moe/shared",
+      "apex_attn_fwd_packed", "apex_attn_bwd_dq_packed",
+      "apex_attn_bwd_dkv_packed", "apex_xentropy_fwd", "apex_xentropy_bwd"]),
 ])
 def test_scopes_reach_the_lowered_step_and_add_no_op(monkeypatch, config,
                                                      traffic, scopes):
@@ -155,6 +165,7 @@ def test_scopes_reach_the_lowered_step_and_add_no_op(monkeypatch, config,
     # jax.named_scope gone, amp's spans and the optimizer's phases go with
     # it; a kernel's scope is pallas_call's own and stays
     assert "/optim/lamb/" not in bare and "/optim/sgd/" not in bare
+    assert "/optim/adam/" not in bare and "/kda/scan/" not in bare
     assert "/amp/update/" not in bare
     assert _ops(scoped) == _ops(bare) and sum(_ops(scoped).values()) > 100
     # off a TPU the kernels are interpreted: no Mosaic call in either
