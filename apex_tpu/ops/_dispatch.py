@@ -40,6 +40,8 @@ KERNEL_NAMES = (
     "apex_xentropy_fwd", "apex_xentropy_bwd",
     "apex_mlp_fwd",
     "apex_bn_act_bwd_stats", "apex_bn_act_bwd_dx",
+    # delta_rule.py: a chunk's terms, state step and output, and their backward
+    "apex_kda_fwd", "apex_kda_bwd",
     # flat-buffer row kernels through launch(): multi_tensor, optim_kernels
     "apex_rows_scale", "apex_rows_axpby", "apex_rows_l2norm",
     "apex_rows_maxnorm", "apex_rows_adam", "apex_rows_sgd",

@@ -19,9 +19,17 @@ each chunk's start is carried from chunk to chunk. Three parts:
                for its backward;
 ``_output``    the outputs, for all chunks at once.
 
-The op has its own backward (``jax.custom_vjp``): it keeps its inputs and
-the chunk-start states, and the backward runs ``_prepare`` again, so no
-``(CHUNK, CHUNK)`` matrix lives from the forward to the backward.
+The op has its own backward (``jax.custom_vjp``). Head sizes of whole
+128-lane tiles (the published KDA and gated-DeltaNet sizes) take two Pallas
+kernels, ``apex_kda_fwd`` and ``apex_kda_bwd``, further down: all three
+parts a chunk at a time, chunks in order, the state in VMEM, so that the
+score matrices, the solve, the chunk's terms and every cotangent of them
+never reach HBM. From the forward to the backward they keep the inputs, the
+chunk-start states and ``(I + A)^-1`` (``C`` floats a token and head). Any
+other head size takes the three parts as ``jax.numpy``, ``HEAD_GROUP`` heads
+at a time, and keeps the inputs and the states; its backward runs
+``_prepare`` again. What the op sees of its inputs' shapes picks the path;
+no argument does.
 
 Decay. ``g <= 0`` is the log of the decay, and ``G`` its running sum from
 the chunk's start. ``exp(G_t - G_s)`` for ``s <= t`` is at most 1, but the
@@ -30,25 +38,30 @@ at ``g = -5`` a step ``exp(-G_s)`` passes float32 after 18 tokens. So the
 chunk is cut into sub-blocks of ``SUB``: between two sub-blocks the sum is
 split at the later one's start, ``exp(G_t - r) * exp(r - G_s)`` with both
 exponents <= 0, and inside a sub-block the ``(SUB, SUB, d_k)`` terms are
-summed as they are. Nothing that can overflow is formed; what underflows
-is a contribution that is zero in float32 anyway.
+summed as they are (the kernels split at the middle of every block of 2, 4,
+... ``CHUNK`` tokens instead, see there). Nothing that can overflow is
+formed; what underflows is a contribution that is zero in float32 anyway.
 
 Precision. State, decay and the solve are float32 whatever the inputs
 (``gated_delta_rule`` is a FLOAT op of ``amp/lists.py``); the matmuls take
 float32 operands at the backend's default precision, the ``(CHUNK,
-CHUNK)`` matrices that feed the solve at ``HIGHEST``.
+CHUNK)`` matrices that feed the solve, and the solve, at ``HIGHEST``; on
+both paths.
 """
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 CHUNK = 64
 SUB = 16
-#: heads that go through the op together: the backward's working set is
-#: ~110 MB a head at 8192 tokens, and the groups run one after another
+#: heads that go through the ``jax.numpy`` form together: its backward's
+#: working set is ~110 MB a head at 8192 tokens; the groups run in turn
 HEAD_GROUP = 8
 _HI = lax.Precision.HIGHEST
 
@@ -104,6 +117,351 @@ def _prepare(q, k, v, g, beta):
             jnp.exp(total[..., 0, :]))
 
 
+# ---- the Pallas kernels: a chunk of a few heads a grid step -----------------
+#
+# A pair ``s < t`` of a chunk differs in one highest bit ``L`` of its two
+# positions: ``t`` lies in the upper half and ``s`` in the lower half of one
+# block of ``2 L`` tokens. Level ``L`` takes ``G`` at that block's middle as
+# the point to split the sum at, for all its blocks at once: one exponent
+# ``n_L <= 0`` a token (the sum of ``g`` between the token and the middle),
+# and one masked ``(C, d) x (d, C)`` matmul. ``log2 C`` levels cover every
+# pair once, so nothing is summed on the vector unit and no ``(SUB, SUB, d)``
+# term is formed; ``SUB`` plays no part here. The exponents themselves come
+# from one matmul of a constant 0/1 matrix with ``g``: sums of ``g``, never
+# differences of ``G``. ``(I + A)^-1`` is built level by level beside them:
+# the inverse of a block of ``2 L`` from those of its halves (block forward
+# substitution, two matmuls a level), kept whole for the backward.
+#
+# The chunks of ``HEADS_A_STEP`` heads go through as one: their rows stacked,
+# ``(heads C, d)``, under the same masks, which keep the heads apart because
+# no level reaches across ``C``. A dependent chain of small matmuls waits on
+# the matrix unit's latency once for all of them, and a grid step's fixed
+# cost is shared.
+
+HEADS_A_STEP = 2
+
+
+def _levels(c):
+    return tuple(1 << i for i in range(c.bit_length() - 1))
+
+
+@functools.lru_cache(None)
+def _sum_matrix(c, heads):
+    """``(2 + log2 C) heads C, heads C`` of 0/1: stacked, the rows that sum
+    ``g`` into ``G`` (inclusive), into ``G_end - G`` and into each level's
+    exponent, each block-diagonal over the heads."""
+    t, s = np.arange(c)[:, None], np.arange(c)[None, :]
+    blocks = [s <= t, s > t]
+    for lv in _levels(c):
+        mid = t // (2 * lv) * (2 * lv) + lv - 1      # last token of the lower half
+        blocks.append(np.where(t > mid, (s > mid) & (s <= t),
+                               (s > t) & (s <= mid)))
+    return np.concatenate([np.kron(np.eye(heads), b) for b in blocks],
+                          0).astype(np.float32)
+
+
+def _dot(a, b, contract=((1,), (0,)), precision=_HI):
+    return lax.dot_general(a, b, (contract, ((), ())), precision=precision,
+                           preferred_element_type=jnp.float32)
+
+
+_mm = functools.partial(_dot, precision=None)   # the state and output products
+_NT = ((1,), (1,))                      # a @ b.T
+_TN = ((0,), (0,))                      # a.T @ b
+
+
+def _sums(matrix, x):
+    """``matrix @ x`` in float32 for a 0/1 ``matrix`` (exact in bfloat16):
+    the three bfloat16 pieces of ``x``, one pass each, where ``HIGHEST``
+    would make six. The passes name their precision: under a
+    ``jax.default_matmul_precision`` of ``highest`` a bfloat16 product with
+    none would ask Mosaic for float32 passes, which it refuses."""
+    out = 0.0
+    for _ in range(3):
+        piece = x.astype(jnp.bfloat16)
+        out = out + _dot(matrix, piece, precision=lax.Precision.DEFAULT)
+        x = x - piece.astype(jnp.float32)
+    return out
+
+
+def _chunk_forward(q, k, v, g, beta, sum_matrix, inverse=None):
+    """One chunk of a few heads, rows stacked: ``q, k, g`` ``(R, d_k)``, ``v``
+    ``(R, d_v)``, ``beta`` ``(R, 1)``, float32, ``R`` = heads x ``CHUNK``;
+    ``sum_matrix`` in bfloat16. Returns ``qg, kg, w, ut, aqk, from_start``
+    (``aqk`` ``(R, R)``, a head's block on the diagonal; ``decay`` is the last
+    row of a head's ``from_start``) and what the backward needs of the way
+    there. ``inverse``: ``(I + A)^-1`` where the caller has it already."""
+    r, dk = k.shape
+    sums = jnp.minimum(_sums(sum_matrix, g), 0.0)
+    part = lambda i: sums[i * r:(i + 1) * r]
+    from_start, to_end = jnp.exp(part(0)), jnp.exp(part(1))
+    row = lax.broadcasted_iota(jnp.int32, (r, 1), 0)
+    # q's rows, then k's, against the columns: the bits two tokens differ in
+    differ = (lax.broadcasted_iota(jnp.int32, (2 * r, r), 0)
+              ^ lax.broadcasted_iota(jnp.int32, (2 * r, r), 1)) & (r - 1)
+    eye = (differ[:r] == 0).astype(k.dtype)
+    scores = jnp.zeros((2 * r, r), k.dtype)
+    build = inverse is None
+    kept = []
+    for i, lv in enumerate(_levels(CHUNK)):
+        e = jnp.exp(part(2 + i))
+        upper = (row & lv) != 0
+        e_near, e_far = jnp.where(upper, e, 0.0), jnp.where(upper, 0.0, e)
+        near = jnp.concatenate([q * e_near, k * e_near], 0)
+        far = k * e_far
+        level = jnp.where(differ < 2 * lv, _dot(near, far, _NT), 0.0)
+        scores = scores + level
+        if build:           # (I + A)^-1 of blocks of 2 lv from those of lv
+            a = level[r:] * beta
+            inverse = (inverse - _dot(_dot(inverse, a), inverse) if i
+                       else eye - a)         # level 1: blocks of one are I
+        kept.append((e_near, e_far, near, far))
+    aqk = scores[:r] + eye * jnp.sum(q * k, -1, keepdims=True)
+    k_start = k * from_start
+    rhs = jnp.concatenate([k_start, v], 1) * beta
+    solved = _dot(inverse, rhs)
+    out = (q * from_start, k * to_end, solved[:, :dk], solved[:, dk:], aqk,
+           from_start)
+    return out, (to_end, k_start, scores[r:], inverse, solved, kept, differ,
+                 eye)
+
+
+def _chunk_backward(q, k, v, beta, sum_matrix_t, forward, cts):
+    """Cotangents of ``q, k, v, g, beta`` for those of ``qg, kg, w, ut, aqk``
+    and of ``from_start`` (``decay``'s, in a head's last row); ``forward``:
+    what ``_chunk_forward`` returned."""
+    d_qg, d_kg, d_w, d_ut, d_aqk, d_from_start = cts
+    r, dk = k.shape
+    (_, kg, _, _, _, from_start), (
+        to_end, k_start, skk, inverse, solved, kept, differ, eye) = forward
+    # the solve: solved = inverse @ rhs, inverse = (I + beta * skk)^-1
+    d_rhs = _dot(_dot(eye, inverse, _NT), jnp.concatenate([d_w, d_ut], 1))
+    d_a = -_dot(d_rhs, solved, _NT)
+    d_beta = (jnp.sum(d_rhs[:, :dk] * k_start, -1, keepdims=True)
+              + jnp.sum(d_rhs[:, dk:] * v, -1, keepdims=True)
+              + jnp.sum(d_a * skk, -1, keepdims=True))
+    d_rhs = d_rhs * beta
+    d_v = d_rhs[:, dk:]
+    on_diagonal = jnp.sum(d_aqk * eye, -1, keepdims=True)
+    d_q = d_qg * from_start + on_diagonal * k
+    d_k = d_rhs[:, :dk] * from_start + d_kg * to_end + on_diagonal * q
+    d_sums = [(d_qg * q + d_rhs[:, :dk] * k + d_from_start) * from_start,
+              d_kg * kg]
+    d_scores = jnp.concatenate([d_aqk, d_a * beta], 0)
+    # the transposed cotangents, q's beside k's: (R, 2 R)
+    d_scores_t = jnp.concatenate([_dot(eye, d_aqk, _NT),
+                                  _dot(eye, d_a * beta, _NT)], 1)
+    differ_t = jnp.concatenate([differ[:r], differ[:r]], 1)
+    for lv, (e_near, e_far, near, far) in zip(_levels(CHUNK), kept):
+        d_near = _dot(jnp.where(differ < 2 * lv, d_scores, 0.0), far)
+        d_far = _dot(jnp.where(differ_t < 2 * lv, d_scores_t, 0.0), near)
+        d_q = d_q + d_near[:r] * e_near
+        d_k = d_k + d_near[r:] * e_near + d_far * e_far
+        d_sums.append(d_near[:r] * near[:r] + d_near[r:] * near[r:]
+                      + d_far * far)
+    d_g = _sums(sum_matrix_t, jnp.concatenate(d_sums, 0))
+    return d_q, d_k, d_v, d_g, d_beta
+
+
+# The recurrence runs in the same kernels: the chunk axis is the grid's last,
+# taken in order (in reverse by the backward), and the ``(d_k, d_v)`` state
+# of each head (the backward's: its cotangent) is carried in a VMEM scratch.
+# So ``qg, kg, w, ut, aqk`` never reach HBM: the forward writes the output
+# in the model's layout, the chunk-start states and ``(I + A)^-1``, and the
+# backward reads those, the inputs and ``d out``. The state and output
+# products take float32 operands at the default precision, as ``_chunk_step``
+# and ``_output`` below do.
+
+def _stacked(ref, heads):
+    """A ``(C, heads d)`` block, the heads side by side, as ``(heads C, d)``
+    float32, one head's rows after another's."""
+    d = ref.shape[-1] // heads
+    return jnp.concatenate([ref[:, j * d:(j + 1) * d].astype(jnp.float32)
+                            for j in range(heads)], 0)
+
+
+def _columns(ref, first, heads):
+    """Columns ``first ... first + heads`` of a ``(C, H)`` block, stacked:
+    ``(heads C, 1)`` float32."""
+    block = ref[...].astype(jnp.float32)
+    lane = lax.broadcasted_iota(jnp.int32, block.shape, 1)
+    return jnp.concatenate([
+        jnp.sum(jnp.where(lane == first + j, block, 0.0), -1, keepdims=True)
+        for j in range(heads)], 0)
+
+
+def _diagonal_blocks(blocks):
+    """``(C, C)`` blocks on the diagonal of one ``(n C, n C)`` matrix."""
+    zero = jnp.zeros_like(blocks[0])
+    return jnp.concatenate([
+        jnp.concatenate([b if i == j else zero for j in range(len(blocks))], 1)
+        for i, b in enumerate(blocks)], 0)
+
+
+def _turned(x, eye):
+    """A ``(1, d)`` row as a ``(d, 1)`` column, or back: through the
+    diagonal of ``eye``, ``(d, d)``."""
+    return jnp.sum(eye * x, -1 if x.shape[0] == 1 else 0, keepdims=True)
+
+
+def _eye(d):
+    return (lax.broadcasted_iota(jnp.int32, (d, d), 0)
+            == lax.broadcasted_iota(jnp.int32, (d, d), 1)).astype(jnp.float32)
+
+
+def _fwd_kernel(heads, q_ref, k_ref, v_ref, g_ref, beta_ref, sums_ref,
+                out_ref, states_ref, inverse_ref, state):
+    from jax.experimental import pallas as pl
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        state[...] = jnp.zeros_like(state)
+
+    q, k, v, g = (_stacked(r, heads) for r in (q_ref, k_ref, v_ref, g_ref))
+    beta = _columns(beta_ref, pl.program_id(1) * heads, heads)
+    (qg, kg, w, ut, aqk, from_start), kept = _chunk_forward(
+        q, k, v, g, beta, sums_ref[...])
+    c, dv = CHUNK, v.shape[-1]
+    eye = _eye(k.shape[-1])
+    for j in range(heads):
+        rows = slice(j * c, (j + 1) * c)
+        start = state[j]
+        states_ref[j] = start
+        inverse_ref[j] = kept[3][rows, rows]
+        u = ut[rows] - _mm(w[rows], start)
+        out_ref[:, j * dv:(j + 1) * dv] = (
+            _mm(qg[rows], start) + _mm(aqk[rows, rows], u))
+        decay = _turned(from_start[(j + 1) * c - 1:(j + 1) * c], eye)
+        state[j] = decay * start + _mm(kg[rows], u, _TN)
+
+
+def _bwd_kernel(heads, q_ref, k_ref, v_ref, g_ref, beta_ref, sums_ref,
+                sums_t_ref, states_ref, inverse_ref, d_out_ref, dq_ref,
+                dk_ref, dv_ref, dg_ref, dbeta_ref, lam_ref):
+    from jax.experimental import pallas as pl
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        lam_ref[...] = jnp.zeros_like(lam_ref)
+
+    q, k, v, g = (_stacked(r, heads) for r in (q_ref, k_ref, v_ref, g_ref))
+    beta = _columns(beta_ref, pl.program_id(1) * heads, heads)
+    forward = _chunk_forward(
+        q, k, v, g, beta, sums_ref[...],
+        _diagonal_blocks([inverse_ref[j] for j in range(heads)]))
+    qg, kg, w, ut, aqk, from_start = forward[0]
+    c, dv = CHUNK, v.shape[-1]
+    eye = _eye(k.shape[-1])
+    last = lax.broadcasted_iota(jnp.int32, (c, 1), 0) == c - 1
+    cts = []
+    for j in range(heads):
+        # o = qg S + aqk u, u = ut - w S, S' = decay S + kg^T u: lam is S''s
+        rows = slice(j * c, (j + 1) * c)
+        start, lam = states_ref[j], lam_ref[j]
+        d_o = d_out_ref[:, j * dv:(j + 1) * dv].astype(jnp.float32)
+        block = aqk[rows, rows]
+        u = ut[rows] - _mm(w[rows], start)
+        d_u = _mm(block, d_o, _TN) + _mm(kg[rows], lam)
+        decay = from_start[(j + 1) * c - 1:(j + 1) * c]
+        d_decay = _turned(jnp.sum(start * lam, -1, keepdims=True), eye)
+        cts.append((_mm(d_o, start, _NT), _mm(u, lam, _NT),
+                    -_mm(d_u, start, _NT), d_u, _mm(d_o, u, _NT),
+                    jnp.where(last, d_decay, 0.0)))
+        lam_ref[j] = (_mm(qg[rows], d_o, _TN) + _turned(decay, eye) * lam
+                      - _mm(w[rows], d_u, _TN))
+    d_qg, d_kg, d_w, d_ut, d_aqk, d_from_start = zip(*cts)
+    *grads, d_beta = _chunk_backward(
+        q, k, v, beta, sums_t_ref[...], forward,
+        tuple(jnp.concatenate(x, 0) for x in (d_qg, d_kg, d_w, d_ut))
+        + (_diagonal_blocks(d_aqk), jnp.concatenate(d_from_start, 0)))
+    for ref, x in zip((dq_ref, dk_ref, dv_ref, dg_ref), grads):
+        d = x.shape[-1]
+        for j in range(heads):
+            ref[:, j * d:(j + 1) * d] = x[j * c:(j + 1) * c].astype(ref.dtype)
+    for j in range(heads):
+        dbeta_ref[j] = d_beta[j * c:(j + 1) * c]
+
+
+def _tiled(dk, dv):
+    """Whether the kernels take these head sizes: whole 128-lane tiles."""
+    return dk % 128 == 0 and dv % 128 == 0
+
+
+def _specs(q, v, reverse=False):
+    """Grid and block specs of both kernels: ``(batch, heads, chunk)``, the
+    chunks in order (``reverse``: last first); the model's ``(B, T, H d)``
+    layout for tokens, chunk-major ``(N, B, H, ., .)`` for the rest."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    b, t, h, dk = q.shape
+    dv, chunks, c = v.shape[-1], t // CHUNK, CHUNK
+    heads = HEADS_A_STEP if h % HEADS_A_STEP == 0 else 1
+    at = (lambda n: chunks - 1 - n) if reverse else (lambda n: n)
+    token = lambda d: pl.BlockSpec((None, c, heads * d),
+                                   lambda b, h, n: (b, at(n), h))
+    chunk = lambda r, d: pl.BlockSpec((None, None, heads, r, d),
+                                      lambda b, h, n: (at(n), b, h, 0, 0))
+    sums = _sum_matrix(c, heads)
+    return dict(
+        heads=heads, grid=(b, h // heads, chunks), token=token, chunk=chunk,
+        inputs=[token(dk), token(dk), token(dv), token(dk),
+                pl.BlockSpec((None, c, h), lambda b, h, n: (b, at(n), 0))],
+        per_chunk=lambda r, d: jax.ShapeDtypeStruct((chunks, b, h, r, d),
+                                                    jnp.float32),
+        sums=jnp.asarray(sums, jnp.bfloat16),
+        sums_t=jnp.asarray(sums.T, jnp.bfloat16),
+        whole=lambda x: pl.BlockSpec(x.shape, lambda b, h, n: (0, 0)),
+        state=pltpu.VMEM((heads, dk, dv), jnp.float32),
+        params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")))
+
+
+def _flat(x):
+    """``(B, T, H, d)`` -> ``(B, T, H d)``: the head is a lane offset."""
+    return x.reshape(*x.shape[:2], -1)
+
+
+def _forward_kernel(q, k, v, g, beta):
+    """The op by the forward kernel: the output ``(B, T, H, d_v)``, the
+    chunk-start states ``(N, B, H, d_k, d_v)`` and ``(I + A)^-1`` ``(N, B, H,
+    C, C)``, both for the backward kernel."""
+    from apex_tpu.ops._dispatch import pallas_call
+    sp = _specs(q, v)
+    (b, t, h, dk), dv, c = q.shape, v.shape[-1], CHUNK
+    out, states, inverse = pallas_call(
+        functools.partial(_fwd_kernel, sp["heads"]),
+        name="apex_kda_fwd", grid=sp["grid"],
+        in_specs=sp["inputs"] + [sp["whole"](sp["sums"])],
+        out_specs=[sp["token"](dv), sp["chunk"](dk, dv), sp["chunk"](c, c)],
+        out_shape=[jax.ShapeDtypeStruct((b, t, h * dv), jnp.float32),
+                   sp["per_chunk"](dk, dv), sp["per_chunk"](c, c)],
+        scratch_shapes=[sp["state"]], compiler_params=sp["params"],
+    )(*map(_flat, (q, k, v, g)), beta, sp["sums"])
+    return out.reshape(b, t, h, dv), states, inverse
+
+
+def _backward_kernel(q, k, v, g, beta, states, inverse, d_out):
+    """Cotangents of the op's inputs, in their layout and dtypes."""
+    from apex_tpu.ops._dispatch import pallas_call
+    sp = _specs(q, v, reverse=True)
+    (b, t, h, dk), dv, c = q.shape, v.shape[-1], CHUNK
+    *grads, d_beta = pallas_call(
+        functools.partial(_bwd_kernel, sp["heads"]),
+        name="apex_kda_bwd", grid=sp["grid"],
+        in_specs=sp["inputs"] + [
+            sp["whole"](sp["sums"]), sp["whole"](sp["sums_t"]),
+            sp["chunk"](dk, dv), sp["chunk"](c, c), sp["token"](dv)],
+        out_specs=sp["inputs"][:4] + [sp["chunk"](c, 1)],
+        out_shape=[jax.ShapeDtypeStruct(_flat(x).shape, x.dtype)
+                   for x in (q, k, v, g)] + [sp["per_chunk"](c, 1)],
+        scratch_shapes=[sp["state"]], compiler_params=sp["params"],
+    )(*map(_flat, (q, k, v, g)), beta, sp["sums"], sp["sums_t"], states,
+      inverse, _flat(d_out))
+    d_beta = jnp.transpose(d_beta[..., 0], (1, 0, 3, 2)).reshape(b, t, h)
+    return (*(d.reshape(x.shape) for d, x in zip(grads, (q, k, v, g))),
+            d_beta.astype(beta.dtype))
+
+
 def _chunk_step(state, w, ut, kg, decay):
     u = ut - w @ state
     return decay[..., None] * state + jnp.swapaxes(kg, -1, -2) @ u
@@ -151,14 +509,20 @@ def _prepared(q, k, v, g, beta):
 
 
 def _forward(q, k, v, g, beta):
+    """The output ``(B, T, H, d_v)`` and what the backward keeps beside the
+    inputs: the chunk-start states and, from the kernel, ``(I + A)^-1``."""
     from apex_tpu.amp.functional_patch import suspend
     with suspend():                     # float32 here whatever the policy
         with jax.named_scope("kda/scan"):
+            if _tiled(q.shape[-1], v.shape[-1]):
+                out, *kept = _forward_kernel(q, k, v, g, beta)
+                return out, tuple(kept)
             qg, kg, w, ut, aqk, decay = _prepared(q, k, v, g, beta)
             states = _propagate(w, ut, kg, decay)
             out = _output(qg, aqk, w, ut, states)
     n, b, h, c, dv = out.shape
-    return jnp.transpose(out, (1, 0, 3, 2, 4)).reshape(b, n * c, h, dv), states
+    out = jnp.transpose(out, (1, 0, 3, 2, 4)).reshape(b, n * c, h, dv)
+    return out, (states,)
 
 
 @jax.custom_vjp
@@ -167,14 +531,16 @@ def _scan(q, k, v, g, beta):
 
 
 def _scan_fwd(q, k, v, g, beta):
-    out, states = _forward(q, k, v, g, beta)
-    return out, (q, k, v, g, beta, states)
+    out, kept = _forward(q, k, v, g, beta)
+    return out, (q, k, v, g, beta, *kept)
 
 
 def _scan_bwd(res, d_out):
     from apex_tpu.amp.functional_patch import suspend
-    q, k, v, g, beta, states = res
+    q, k, v, g, beta, states, *inverse = res
     with suspend(), jax.named_scope("kda/scan"):
+        if inverse:
+            return _backward_kernel(q, k, v, g, beta, states, *inverse, d_out)
         (qg, kg, w, ut, aqk, decay), back = jax.vjp(_prepared, q, k, v, g, beta)
         _, out_back = jax.vjp(_output, qg, aqk, w, ut, states)
         d_qg, d_aqk, d_w, d_ut, d_states = out_back(_chunked(d_out))
@@ -202,7 +568,8 @@ def gated_delta_rule(q, k, v, g, beta):
             jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2))
             for x in (q, k, v, g, beta))
     h = q.shape[2]
-    if h > HEAD_GROUP and h % HEAD_GROUP == 0:
+    if (h > HEAD_GROUP and h % HEAD_GROUP == 0
+            and not _tiled(q.shape[-1], v.shape[-1])):
         grouped = lambda x: jnp.moveaxis(x.reshape(
             *x.shape[:2], h // HEAD_GROUP, HEAD_GROUP, *x.shape[3:]), 2, 0)
         out = lax.map(lambda xs: _scan(*xs),

@@ -48,6 +48,60 @@ def test_chunked_equals_recurrent(length, g_scale):
             jnp.max(jnp.abs(b))), name
 
 
+@pytest.mark.parametrize("g_scale", [1e-3, 0.1, 1.0, 5.0])
+@pytest.mark.parametrize("length", [64, 100, 192])
+def test_kernels_equal_chunked_and_recurrent(length, g_scale, monkeypatch):
+    """Head sizes of whole lane tiles take the Pallas kernels (interpreted
+    here): against the recurrence and against the ``jax.numpy`` chunked form
+    they replace, output and all five gradients."""
+    batch = 2 if length == 100 else 1       # the grid's batch axis, once
+    args = inputs(length, batch, length, 2, 128, 128, g_scale)
+    both = lambda fn: (fn(*args), jax.grad(weighted(fn),
+                                           argnums=range(5))(*args))
+    assert "/apex_kda_fwd/" in jax.jit(gated_delta_rule).lower(
+        *args).as_text(debug_info=True)
+    out, got = both(gated_delta_rule)
+    ref, want = both(gated_delta_rule_reference)
+    with monkeypatch.context() as m:     # the chunked form, by the tile test
+        m.setattr(delta_rule, "_tiled", lambda dk, dv: False)
+        mid, middle = both(gated_delta_rule)
+    assert out.shape == ref.shape == (batch, length, 2, 128)
+    assert bool(jnp.all(jnp.isfinite(out)))
+    assert float(jnp.max(jnp.abs(out - ref))) <= 2e-6
+    assert float(jnp.max(jnp.abs(out - mid))) <= 2e-6
+    for name, a, b, c in zip("q k v g beta".split(), got, want, middle):
+        assert bool(jnp.all(jnp.isfinite(a))), name
+        scale = float(jnp.max(jnp.abs(b)))
+        assert float(jnp.max(jnp.abs(a - b))) <= 5e-5 * scale, name
+        assert float(jnp.max(jnp.abs(a - c))) <= 5e-5 * scale, name
+
+
+def test_small_heads_take_the_chunked_form():
+    """``d_k = 16`` is no whole lane tile: no kernel in the lowered op."""
+    assert not delta_rule._tiled(16, 8) and not delta_rule._tiled(128, 64)
+    assert delta_rule._tiled(128, 256)
+    lowered = jax.jit(jax.grad(weighted(gated_delta_rule))).lower(
+        *inputs(0, 1, 64, 2, 16, 8, 0.1)).as_text(debug_info=True)
+    assert "apex_kda" not in lowered and "triangular_solve" in lowered
+
+
+def test_strong_decay_forms_nothing_unbounded_in_the_kernel():
+    """Everything the forward kernel writes (output, states, the inverse),
+    and every term it forms on the way (the levels' decayed operands, the
+    chunk's six terms), at g = -5 a step."""
+    q, k, v, g, beta = inputs(0, 1, 128, 2, 128, 128, 5.0)
+    for term in delta_rule._forward_kernel(q, k, v, g, beta):
+        assert bool(jnp.all(jnp.isfinite(term)))
+        assert float(jnp.max(jnp.abs(term))) < 1e3
+    chunk = lambda x: x[0, :64, 0]
+    terms = delta_rule._chunk_forward(
+        *map(chunk, (q, k, v, g)), beta[0, :64, :1],
+        jnp.asarray(delta_rule._sum_matrix(64, 1), jnp.bfloat16))
+    for term in jax.tree_util.tree_leaves(terms):
+        assert bool(jnp.all(jnp.isfinite(term)))
+        assert float(jnp.max(jnp.abs(term))) < 1e3
+
+
 def test_strong_decay_forms_nothing_unbounded():
     """At g = -5 a step exp(-G) is 1e139 at a chunk's end: every term the
     chunked form builds must still be finite, not only its result."""
@@ -70,12 +124,13 @@ def test_heads_go_through_in_groups(monkeypatch):
             jnp.max(jnp.abs(b)))
 
 
-def test_float32_inside_whatever_comes_in():
+@pytest.mark.parametrize("dim", [16, 128])
+def test_float32_inside_whatever_comes_in(dim):
     """Under O1 the op takes half inputs and a patched ``jnp.einsum``: state,
     decay and result stay float32, and the gradients come back in the
-    inputs' dtypes."""
+    inputs' dtypes; in the kernels (``dim`` 128) as in the chunked form."""
     policy = amp.Policy.from_opt_level("O1")
-    args = inputs(1, 1, 64, 2, 16, 16, 0.1, jnp.bfloat16)
+    args = inputs(1, 1, 64, 2, dim, dim, 0.1, jnp.bfloat16)
     with amp.auto_cast(policy):
         out = gated_delta_rule(*args)
         grads = jax.grad(weighted(gated_delta_rule), argnums=range(5))(*args)

@@ -138,6 +138,47 @@ def test_remat_changes_nothing(toy):
         assert float(jnp.max(jnp.abs(x - y))) <= 1e-6
 
 
+def test_kda_kernels_in_the_lowered_step(monkeypatch):
+    """The benchmark's toy has KDA heads of 16, which take the ``jax.numpy``
+    chunked form: at the published head size of 128 the scan is two Pallas
+    kernels. A two-layer decoder with such heads, every
+    block recomputed in the backward: lowered for the TPU its differentiated
+    loss holds the forward kernel twice and the backward kernel once a KDA
+    layer and no triangular solve, and its loss and gradients are those of
+    the same model on the chunked form."""
+    import re
+    from apex_tpu.ops import _dispatch, delta_rule
+    config = {**TOY, "num_hidden_layers": 2, "linear_attn_config": {
+        **TOY["linear_attn_config"], "head_dim": 128}}
+    model = models.kimi_linear_from_config(config, remat=True)
+    assert [k[0] for k in model.layer_kinds] == ["kda", "kda"]
+    tokens = jax.random.randint(jax.random.PRNGKey(2), (1, LENGTH), 0,
+                                config["vocab_size"])
+    params = model.init(jax.random.PRNGKey(3), tokens)["params"]
+    # a new function each time: ``jax.jit`` keeps a trace by its function
+    step = lambda: jax.jit(jax.value_and_grad(
+        lambda p: models.lm_loss(model, {"params": p}, tokens)[0]))
+    with monkeypatch.context() as m:
+        m.setattr(_dispatch, "use_interpret", lambda: False)
+        text = step().trace(params).lower(
+            lowering_platforms=("tpu",)).as_text(debug_info=True)
+    kernels = re.findall(r'kernel_name = "(\w+)"', text)
+    assert kernels.count("apex_kda_fwd") == 4
+    assert kernels.count("apex_kda_bwd") == 2
+    assert "triangular_solve" not in text
+    loss, grads = step()(params)
+    with monkeypatch.context() as m:
+        m.setattr(delta_rule, "_tiled", lambda dk, dv: False)
+        assert "triangular_solve" in step().lower(params).as_text(
+            debug_info=True)
+        ref_loss, ref_grads = step()(params)
+    assert float(abs(loss - ref_loss)) <= 1e-6 * float(ref_loss)
+    flat = jax.tree_util.tree_flatten_with_path(grads)[0]
+    for (path, got), want in zip(flat, jax.tree_util.tree_leaves(ref_grads)):
+        assert float(jnp.linalg.norm(got - want)) <= 2e-5 * max(
+            float(jnp.linalg.norm(want)), 1e-3), jax.tree_util.keystr(path)
+
+
 def test_o1_model_is_near_the_reference(toy):
     """Under ``auto_cast`` the matmuls run in bfloat16 (2**-8 relative a
     product) with float32 accumulation, state, decay, router and norms. At

@@ -502,3 +502,33 @@ def test_mla_attention_compiles_for_the_v5e_at_the_cell_s_shape(
     assert text.count("tpu_custom_call") >= 3      # forward, dq, dk/dv
     for kernel in ("apex_attn_fwd", "apex_attn_bwd_dq", "apex_attn_bwd_dkv"):
         assert f"({kernel})" in text or f"/{kernel}/" in text, kernel
+
+
+@pytest.mark.parametrize("dtype,precision", [
+    (jnp.float32, "default"), (jnp.bfloat16, "default"),
+    (jnp.float32, "highest")])
+def test_kda_kernels_compile_for_the_v5e_at_the_cell_s_shape(
+        v5e_chip, monkeypatch, dtype, precision):
+    """``kimi_linear.lm_s8192_b1``'s delta rule: 32 heads of 128, 8192 tokens,
+    forward and backward kernel through Mosaic, with float32 ``q, k, v`` as
+    the model hands them over and with half ones as a user under O1 may, and
+    under a default matmul precision of ``highest`` (Mosaic refuses float32
+    passes over bfloat16 operands: the chip said so first, PR 29).
+    What interpret mode cannot see: the blocks' tiling, the transposed and
+    the bfloat16 matmuls, lane offsets of 64 inside the stacked heads'
+    ``(128, 128)`` matrices, the scoped VMEM."""
+    from apex_tpu.ops import _dispatch, delta_rule
+    monkeypatch.setattr(_dispatch, "use_interpret", lambda: False)
+    shape = lambda *s, dt=jnp.float32: jax.ShapeDtypeStruct(
+        s, dt, sharding=v5e_chip)
+    x = shape(1, 8192, 32, 128, dt=dtype)
+    args = (x, x, x, shape(1, 8192, 32, 128), shape(1, 8192, 32))
+    loss = lambda *a: jnp.sum(delta_rule.gated_delta_rule(*a))
+    with jax.default_matmul_precision(precision):
+        compiled = jax.jit(jax.grad(loss, argnums=range(5))).lower(
+            *args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    for kernel in ("apex_kda_fwd", "apex_kda_bwd"):
+        assert f"({kernel})" in text or f"/{kernel}/" in text, kernel
+    assert "triangular-solve" not in text and "while" not in text
