@@ -138,51 +138,27 @@ class BottleneckBlock(nn.Module):
     bn_axis_name: Optional[str] = None
     dtype: Optional[Any] = None
     fused_bn: bool = True
-    #: distributed-dgrad conv+BN backward (ops/conv_bn.py experiment):
-    #: None = off, "join" = residual-join unit only, "all" = every unit
-    dx_distribute: Optional[str] = None
 
     @nn.compact
     def __call__(self, x, train: bool = True):
         conv = partial(nn.Conv, use_bias=False, dtype=self.dtype)
         bn = partial(_BN, axis_name=self.bn_axis_name, dtype=self.dtype,
                      fused=self.fused_bn)
-        from apex_tpu.ops.conv_bn import ConvBNAct
-        cba = partial(ConvBNAct, axis_name=self.bn_axis_name,
-                      dtype=self.dtype)
-        dist_all = self.dx_distribute == "all"
-        dist_join = self.dx_distribute in ("all", "join")
         residual = x
-        if dist_all:
-            y = cba(self.features, (1, 1), relu=True)(x, train=train)
-            y = cba(self.features, (3, 3), self.strides,
-                    relu=True)(y, train=train)
-        else:
-            y = conv(self.features, (1, 1))(x)
-            y = bn(self.features, relu=True)(y, train=train)
-            y = conv(self.features, (3, 3), self.strides)(y)
-            y = bn(self.features, relu=True)(y, train=train)
-        need_proj = residual.shape[-1] != self.features * 4 \
-            or self.strides != (1, 1)
-        # module creation order on the default path is load-bearing:
-        # flax auto-names (Conv_2 = final 1x1, Conv_3 = projection) are
-        # the checkpoint layout — only the experimental dist paths may
-        # reorder (their parameter trees are new anyway)
-        if not dist_join:
-            y = conv(self.features * 4, (1, 1))(y)
-        if need_proj:
-            if dist_all:
-                residual = cba(self.features * 4, (1, 1), self.strides,
-                               relu=False)(x, train=train)
-            else:
-                residual = conv(self.features * 4, (1, 1),
-                                self.strides)(x)
-                residual = bn(self.features * 4)(residual, train=train)
+        y = conv(self.features, (1, 1))(x)
+        y = bn(self.features, relu=True)(y, train=train)
+        y = conv(self.features, (3, 3), self.strides)(y)
+        y = bn(self.features, relu=True)(y, train=train)
+        # module creation order is load-bearing: flax's auto-names
+        # (Conv_2 = final 1x1, Conv_3 = projection) are the checkpoint
+        # layout
+        y = conv(self.features * 4, (1, 1))(y)
+        if residual.shape[-1] != self.features * 4 \
+                or self.strides != (1, 1):
+            residual = conv(self.features * 4, (1, 1), self.strides)(x)
+            residual = bn(self.features * 4)(residual, train=train)
         # zero-init the last BN scale: standard ResNet recipe (identity
         # residual at init); the residual add + relu fuse into this unit
-        if dist_join:
-            return cba(self.features * 4, (1, 1), relu=True,
-                       init_scale=0.0)(y, residual, train=train)
         return bn(self.features * 4, init_scale=0.0, relu=True)(
             y, residual, train=train)
 
@@ -227,9 +203,6 @@ class ResNet(nn.Module):
     #: minimal-residual fused BN(+add)(+relu) backward (see ops/bn_act.py);
     #: False = plain flax BatchNorm autodiff (the numeric oracle)
     fused_bn: bool = True
-    #: distributed-dgrad conv+BN experiment (ops/conv_bn.py): None | "join"
-    #: | "all" — changes the parameter tree of the affected units
-    dx_distribute: Optional[str] = None
 
     @nn.compact
     def __call__(self, x, train: bool = True):
@@ -243,20 +216,9 @@ class ResNet(nn.Module):
         for i, n_blocks in enumerate(self.stage_sizes):
             for j in range(n_blocks):
                 strides = (2, 2) if i > 0 and j == 0 else (1, 1)
-                kw = {}
-                if self.dx_distribute is not None:
-                    if self.dx_distribute not in ("join", "all"):
-                        raise ValueError(
-                            "dx_distribute must be None, 'join' or "
-                            f"'all', got {self.dx_distribute!r}")
-                    if self.block is not BottleneckBlock:
-                        raise ValueError(
-                            "dx_distribute is only implemented for "
-                            f"BottleneckBlock, got {self.block!r}")
-                    kw["dx_distribute"] = self.dx_distribute
                 y = self.block(self.width * 2 ** i, strides,
                                self.bn_axis_name, self.dtype,
-                               self.fused_bn, **kw)(y, train)
+                               self.fused_bn)(y, train)
         y = jnp.mean(y, axis=(1, 2))
         return nn.Dense(self.num_classes, dtype=self.dtype)(y)
 

@@ -32,11 +32,6 @@ from apex_tpu.ops.bn_act import (
     bn_act_train,
     bn_add_act_train,
 )
-from apex_tpu.ops.conv_bn import (
-    ConvBNAct,
-    conv_bn_act_train,
-    conv_bn_add_act_train,
-)
 from apex_tpu.ops.attention import (
     flash_attention,
     attention_reference,
@@ -59,7 +54,6 @@ __all__ = [
     "softmax_cross_entropy_loss", "softmax_cross_entropy_reference",
     "BatchNorm2d_NHWC", "bn_group_spec",
     "FusedBNAct", "bn_act_reference", "bn_act_train", "bn_add_act_train",
-    "ConvBNAct", "conv_bn_act_train", "conv_bn_add_act_train",
     "flash_attention", "attention_reference", "mask_softmax_dropout",
     "SelfMultiheadAttn", "EncdecMultiheadAttn",
     "gated_delta_rule", "gated_delta_rule_reference", "moe",

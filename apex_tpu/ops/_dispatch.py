@@ -39,7 +39,6 @@ KERNEL_NAMES = (
     "apex_layer_norm_fwd", "apex_layer_norm_bwd",
     "apex_xentropy_fwd", "apex_xentropy_bwd",
     "apex_mlp_fwd",
-    "apex_bn_act_bwd_stats", "apex_bn_act_bwd_dx",
     # delta_rule.py: a chunk's terms, state step and output, and their backward
     "apex_kda_fwd", "apex_kda_bwd",
     # flat-buffer row kernels through launch(): multi_tensor, optim_kernels

@@ -27,8 +27,7 @@ no mask tensor ever exists in HBM.
 from __future__ import annotations
 
 import functools
-import os
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -621,25 +620,9 @@ def _native_g(nh, d, dropout_rate, bq, bk, itemsize, *, bias_isz=0,
     16 MiB scoped budget (in-blocks, scratch, score tile, out-blocks;
     packing amortizes per-step DMA setup). Dropout adds a (bq, bk)
     keep-mask/hash temporary; a bias adds its double-buffered
-    (g|1, bq, bk) in-block. ``APEX_TPU_NATIVE_G`` overrides for perf
-    experiments."""
+    (g|1, bq, bk) in-block. Always one of g0·{4, 2, 1} that divides
+    ``nh``."""
     g0 = _native_g0(nh, d)
-    forced = os.environ.get("APEX_TPU_NATIVE_G")
-    if forced:
-        try:
-            g = int(forced)
-        except ValueError:
-            raise ValueError(
-                f"APEX_TPU_NATIVE_G={forced!r} is not an integer; it "
-                "must be a multiple of the lane-alignment group "
-                f"g0={g0} that divides nh={nh}") from None
-        if g > 0 and g % g0 == 0 and nh % g == 0:
-            return g
-        import warnings
-        warnings.warn(
-            f"APEX_TPU_NATIVE_G={g} ignored: must be a positive multiple of "
-            f"g0={g0} (lane alignment for d={d}) and divide nh={nh}; "
-            "using the VMEM-ledger choice instead", stacklevel=3)
     # full ledger of what the fwd kernel keeps in scoped VMEM: the
     # double-buffered q/k/v in-blocks, the m/l/acc scratch, the f32
     # score tile, the o and lse out-blocks (also double-buffered), and
@@ -1207,6 +1190,93 @@ def _flash_bwd_fused_nl(qp, kp, vp, dop, lse_l, delta_l, nh, d, g,
     return dq[:, :sq, :], dk[:, :sk, :], dv[:, :sk, :]
 
 
+class _BwdPlan(NamedTuple):
+    """What the native backward runs for one static shape."""
+    bq: int
+    bk: int
+    g: int                      # heads per grid step
+    vmem_limit: Optional[int]   # None: Mosaic's 16 MiB default
+    form: str                   # "fused" | "two_kernel" | "two_kernel_raised"
+
+
+def _bwd_plan(nh, d, sq, sk, bh, itemsize, block_q, block_k, *,
+              dropout_rate=0.0, bias_isz=0, bias_per_head=False,
+              delta_shifted=False) -> _BwdPlan:
+    """Tiles, head group, VMEM limit and kernel form of the native
+    backward, from static values alone (tests/test_attention.py
+    ``test_backward_plan`` states one shape on each side of each
+    decision). ``bias_isz`` is the bias element size, 0 without a bias.
+
+    Every head group here is one of g0·{4, 2, 1} dividing ``nh``
+    (:func:`_native_g`), so halving one that is above g0 lands on a
+    lane-aligned divisor of ``nh`` again: no step below re-checks it."""
+    block_q, block_k = _block_cap(block_q, block_k, bias_isz > 0,
+                                  dropout_rate)
+    bq = _choose_block(block_q, sq)
+    bk = _choose_block(block_k, sk, lane=True)
+    g0 = _native_g0(nh, d)
+
+    def heads(bq_, bk_):
+        return _native_g(nh, d, dropout_rate, bq_, bk_, itemsize,
+                         bias_isz=bias_isz, bias_per_head=bias_per_head)
+
+    def bias_buf(g_, bufs):
+        return ((g_ if bias_per_head else 1) * bq * bk * bias_isz * bufs
+                if bias_isz else 0)
+
+    g = heads(bq, bk)
+    vmem_limit = None
+    if (sq > bq or sk > bk) and bq * bk * 4 >= (1 << 22) and bh > g:
+        # multi-block two-kernel path with 1024²-class f32 score tiles:
+        # Mosaic multi-buffers the streamed blocks across head-group
+        # boundaries when more groups follow (measured: the identical
+        # kernel compiles at bh == g and OOMs at 19.6 MiB with 64
+        # groups). The 16 MiB scoped-VMEM ceiling is a compiler
+        # default, not the hardware's (v5e carries 128 MiB): raise the
+        # limit for these two kernels instead of shrinking the tile —
+        # the 1024-tile bwd measured 27% faster with serialized grads,
+        # and the raised path falls back to the 512 cap whenever its
+        # own bwd ledger — in/out blocks with cross-group
+        # triple-buffering, both lane arrays, accumulators, and the
+        # live f32 score temporaries — would exceed the raised limit.
+        gd = g * d
+        bwd_est = ((2 * bq + 2 * bk) * gd * itemsize * 3
+                   + 2 * g * bq * LANES * 4 * 3
+                   + 2 * bk * gd * itemsize * 2 + 2 * bk * gd * 4
+                   + 3 * bq * bk * 4
+                   + bias_buf(g, 3))
+        if bwd_est > 32 * 2 ** 20:
+            bq = _choose_block(min(block_q, 512), sq)
+            bk = _choose_block(min(block_k, 512), sk, lane=True)
+            g = min(heads(bq, bk), 2 * g0)
+        else:
+            vmem_limit = 32 * 2 ** 20  # est 24.1 MiB at the 1024² point
+    if sq <= bq and sk <= bk:
+        # single-block grids: one fused sweep computes dq/dk/dv from a
+        # single s/p evaluation. Its VMEM budget carries all seven
+        # blocks (here the padded lengths are bq and bk) + f32 score
+        # temporaries — shrink g until it fits, and fall back to the
+        # two-kernel split when even the minimum lane-aligned group
+        # does not (large-S fp32 shapes).
+
+        def fused_est(g_):
+            gd = g_ * d
+            lanes = (2 * g_ * bq * LANES * 4 * 2 if delta_shifted
+                     else bq * bk * 4)   # self-delta: one extra f32 tile
+            return ((2 * bq + 2 * bk) * gd * itemsize * 2
+                    + (bq + 2 * bk) * gd * itemsize * 2
+                    + bq * bk * 4 * 3 + lanes + bias_buf(g_, 2))
+
+        gf = g
+        while gf > g0 and fused_est(gf) > 13 * 2 ** 20:
+            gf //= 2
+        if fused_est(gf) <= 13 * 2 ** 20:
+            return _BwdPlan(bq, bk, gf, None, "fused")
+    return _BwdPlan(bq, bk, g, vmem_limit,
+                    "two_kernel" if vmem_limit is None
+                    else "two_kernel_raised")
+
+
 def _flash_bwd_nl(q2, k2, v2, nh, d, lse, delta, do2, scale, causal,
                   block_q, block_k, dropout_rate=0.0, seed=None,
                   causal_off=None, delta_shifted=False, bias_g=None,
@@ -1222,52 +1292,13 @@ def _flash_bwd_nl(q2, k2, v2, nh, d, lse, delta, do2, scale, causal,
     b, sq, H = q2.shape
     sk = k2.shape[1]
     bh = b * nh
-    block_q, block_k = _block_cap(block_q, block_k, bias_g is not None,
-                                  dropout_rate)
-    bq = _choose_block(block_q, sq)
-    bk = _choose_block(block_k, sk, lane=True)
-    bias_isz = bias_g.dtype.itemsize if bias_g is not None else 0
     bias_per_head = bias_mode in ("head", "full")
-    g = _native_g(nh, d, dropout_rate, bq, bk, q2.dtype.itemsize,
-                  bias_isz=bias_isz, bias_per_head=bias_per_head)
-    bwd_vmem = None
-    if (sq > bq or sk > bk) and bq * bk * 4 >= (1 << 22) and bh > g:
-        # multi-block two-kernel path with 1024²-class f32 score tiles:
-        # Mosaic multi-buffers the streamed blocks across head-group
-        # boundaries when more groups follow (measured: the identical
-        # kernel compiles at bh == g and OOMs at 19.6 MiB with 64
-        # groups). The 16 MiB scoped-VMEM ceiling is a compiler
-        # default, not the hardware's (v5e carries 128 MiB): raise the
-        # limit for these two kernels instead of shrinking the tile —
-        # the 1024-tile bwd measured 27% faster with serialized grads
-        # (APEX_TPU_BWD_512=1 restores the capped-tile behavior), and
-        # the raised path falls back to the cap whenever its own bwd
-        # ledger — in/out blocks with cross-group triple-buffering,
-        # both lane arrays, accumulators, and the live f32 score
-        # temporaries — would exceed the raised limit.
-        gd_ = g * d
-        isz = q2.dtype.itemsize
-        bwd_est = ((2 * bq + 2 * bk) * gd_ * isz * 3
-                   + 2 * g * bq * LANES * 4 * 3
-                   + 2 * bk * gd_ * isz * 2 + 2 * bk * gd_ * 4
-                   + 3 * bq * bk * 4
-                   + ((g if bias_per_head else 1) * bq * bk
-                      * bias_isz * 3 if bias_isz else 0))
-        if (os.environ.get("APEX_TPU_BWD_512") == "1"
-                or bwd_est > 32 * 2 ** 20):
-            bq = _choose_block(min(block_q, 512), sq)
-            bk = _choose_block(min(block_k, 512), sk, lane=True)
-            g = _native_g(nh, d, dropout_rate, bq, bk,
-                          q2.dtype.itemsize, bias_isz=bias_isz,
-                          bias_per_head=bias_per_head)
-            g0_ = _native_g0(nh, d)
-            while g > 2 * g0_ or (nh % g) or (g % g0_):
-                nxt = g // 2
-                if nxt < g0_ or nxt % g0_ or nh % nxt:
-                    nxt = g0_
-                g = nxt
-        else:
-            bwd_vmem = 32 * 2 ** 20  # est 24.1 MiB at the 1024² point
+    plan = _bwd_plan(
+        nh, d, sq, sk, bh, q2.dtype.itemsize, block_q, block_k,
+        dropout_rate=dropout_rate,
+        bias_isz=bias_g.dtype.itemsize if bias_g is not None else 0,
+        bias_per_head=bias_per_head, delta_shifted=delta_shifted)
+    bq, bk, g = plan.bq, plan.bk, plan.g
     sqp = -(-sq // bq) * bq
     skp = -(-sk // bk) * bk
     nq, nk = sqp // bq, skp // bk
@@ -1277,48 +1308,20 @@ def _flash_bwd_nl(q2, k2, v2, nh, d, lse, delta, do2, scale, causal,
     qp, kp, vp = pad_s(q2, sqp), pad_s(k2, skp), pad_s(v2, skp)
     dop = pad_s(do2, sqp)
 
-    if nq == 1 and nk == 1:
-        # single-block grids: one fused sweep computes dq/dk/dv from a
-        # single s/p evaluation. Its VMEM budget carries all seven
-        # blocks + f32 score temporaries — shrink g until it fits, and
-        # fall back to the two-kernel split when even the minimum
-        # lane-aligned group does not (large-S fp32 shapes).
-        isz = q2.dtype.itemsize
-
-        def fused_est(g_):
-            gd_ = g_ * d
-            lanes = (2 * g_ * bq * LANES * 4 * 2 if delta_shifted
-                     else bq * bk * 4)   # self-delta: one extra f32 tile
-            bias_buf = ((g_ if bias_per_head else 1) * bq * bk
-                        * bias_isz * 2 if bias_isz else 0)
-            return ((2 * sqp + 2 * skp) * gd_ * isz * 2
-                    + (sqp + 2 * skp) * gd_ * isz * 2
-                    + bq * bk * 4 * 3 + lanes + bias_buf)
-
-        g0 = _native_g0(nh, d)
-        gf = g
-        while gf > g0 and fused_est(gf) > 13 * 2 ** 20:
-            # halve while staying a lane-aligned group that divides the
-            # head count (an APEX_TPU_NATIVE_G override may start on a
-            # non-power-of-two multiple of g0)
-            nxt = gf // 2
-            if nxt % g0 or nh % nxt:
-                nxt = g0
-            gf = nxt
-        if fused_est(gf) <= 13 * 2 ** 20:
-            if delta_shifted:
-                lse_f = _lanes_nl(lse, bh, gf, 1, bq, sq)
-                delta_f = _lanes_nl(delta, bh, gf, 1, bq, sq)
-            else:
-                lse_f = delta_f = None
-            bias_p = (None if bias_g is None
-                      else _pad_bias_nl(bias_g, sqp, skp))
-            return _flash_bwd_fused_nl(qp, kp, vp, dop, lse_f, delta_f,
-                                       nh, d, gf, scale, causal, sq, sk,
-                                       sqp, skp, bq, bk, seed,
-                                       dropout_rate, causal_off,
-                                       bias_p=bias_p,
-                                       bias_mode=bias_mode, dbo=dbo)
+    if plan.form == "fused":
+        if delta_shifted:
+            lse_f = _lanes_nl(lse, bh, g, 1, bq, sq)
+            delta_f = _lanes_nl(delta, bh, g, 1, bq, sq)
+        else:
+            lse_f = delta_f = None
+        bias_p = (None if bias_g is None
+                  else _pad_bias_nl(bias_g, sqp, skp))
+        return _flash_bwd_fused_nl(qp, kp, vp, dop, lse_f, delta_f,
+                                   nh, d, g, scale, causal, sq, sk,
+                                   sqp, skp, bq, bk, seed,
+                                   dropout_rate, causal_off,
+                                   bias_p=bias_p,
+                                   bias_mode=bias_mode, dbo=dbo)
 
     gd = g * d
     lse_l = _lanes_nl(lse, bh, g, nq, bq, sq)
@@ -1343,9 +1346,9 @@ def _flash_bwd_nl(q2, k2, v2, nh, d, lse, delta, do2, scale, causal,
     args += [dop, lse_l, delta_l]
 
     extra = {}
-    if bwd_vmem is not None and not use_interpret():
+    if plan.vmem_limit is not None and not use_interpret():
         extra["compiler_params"] = pltpu.CompilerParams(
-            vmem_limit_bytes=bwd_vmem)
+            vmem_limit_bytes=plan.vmem_limit)
     dq = pallas_call(
         lambda *refs: functools.partial(
             _bwd_dq_kernel_nl, scale, causal, sk, sq, dropout_rate, d,

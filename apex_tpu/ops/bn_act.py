@@ -34,21 +34,19 @@ Gradient note: the ``(mean, var, count)`` outputs exist for running-stat
 EMA updates and are treated as ``stop_gradient`` — cotangents flowing
 into them are ignored, matching torch BN semantics where running stats
 are buffers.
+
+Tried and deleted (PR 30): a two-kernel Pallas backward and float8 x̂
+residuals. Both lost on the chip; the numbers are in PERF.md §6.
 """
 
 from __future__ import annotations
 
 import functools
-import os
 from typing import Any, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import flax.linen as nn
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
-
-from apex_tpu.ops._dispatch import pallas_call
 
 __all__ = ["bn_act_train", "bn_add_act_train", "bn_act_reference",
            "FusedBNAct"]
@@ -60,10 +58,6 @@ class _Cfg(NamedTuple):
     eps: float
     axis_name: Optional[str]
     groups: Optional[Tuple[Tuple[int, ...], ...]]
-    #: store the backward-only activation residual as float8_e4m3 x̂
-    #: instead of the full-precision conv output x (round-5 byte-floor
-    #: experiment; see PERF.md round-5 ResNet section)
-    fp8: bool = False
 
 
 def _normalize_groups(axis_index_groups):
@@ -123,22 +117,6 @@ def _apply(x32, r, scale, bias, mean, invstd, relu):
     return y
 
 
-def _xres_of(x, mean, invstd, cfg: _Cfg):
-    """The backward's activation residual: x itself, or — under
-    ``cfg.fp8`` — x̂ quantized to float8_e4m3. x̂ is zero-mean unit
-    variance per channel BY CONSTRUCTION, so e4m3's dynamic range
-    covers it with no per-channel scale factor; the backward consumes
-    x only through x̂ (both channel sums and the dx term), so nothing
-    else is lost. The expression duplicates _apply's interior on
-    purpose: it fuses into the same normalize pass (reads x once,
-    writes y and x̂₈), costing one fp8 write where the backward then
-    reads fp8 twice instead of the wide dtype twice."""
-    if not cfg.fp8:
-        return x
-    return ((x.astype(jnp.float32) - mean)
-            * invstd).astype(jnp.float8_e4m3fn)
-
-
 def _fwd_common(x, r, scale, bias, cfg: _Cfg):
     x32 = x.astype(jnp.float32)
     mean, var, count = _stats(x32, cfg)
@@ -147,165 +125,8 @@ def _fwd_common(x, r, scale, bias, cfg: _Cfg):
     return z, mean, var, count, invstd
 
 
-# --- Pallas backward kernels ------------------------------------------------
-#
-# Measured on the ResNet-50 bench: expressing this backward in jnp lets
-# XLA CSE the relu mask into a materialized pred[...] tensor (205 MB per
-# layer1-class unit) and build 15-19-operand mega-fusions — 86.8 GB/step
-# vs the 80.4 GB of plain autodiff. The two kernels below pin the
-# intended traffic exactly: a sums pass and a dx pass, each reading
-# (x, g-source) once, mask and x̂ recomputed in-register, nothing else
-# materialized. This is the role of the reference's hand-written
-# backward reductions (`csrc/welford.cu:259-903`,
-# `batch_norm_add_relu.cu` dgrad).
-
-def _bwd_row_block(m: int, c: int) -> int:
-    """Rows per grid step: ~1 MiB half-dtype buffers (the addrelu sums
-    kernel holds 4 of them double-buffered inside the 16 MiB scoped
-    VMEM), a multiple of 8 that divides m exactly (so no padding copy of
-    a 400 MB tensor is ever made). Returns 0 if no such divisor exists
-    (caller falls back to the jnp backward)."""
-    if m % 8:
-        return 0
-    target = max(8, min(4096, (1 << 20) // (2 * c) // 8 * 8))
-    r = min(target, m)
-    r -= r % 8
-    while r >= 8 and m % r:
-        r -= 8
-    return max(r, 0)
-
-
-def _sums_kernel(mode, x_ref, g_ref, *rest):
-    refs = list(rest)
-    z_ref = refs.pop(0) if mode == "addrelu" else None
-    scale_ref, bias_ref, mean_ref, invstd_ref, sums_ref = refs[:5]
-    dr_ref = refs[5] if mode == "addrelu" else None
-    i = pl.program_id(0)
-
-    x = x_ref[:].astype(jnp.float32)
-    g = g_ref[:].astype(jnp.float32)
-    xhat = (x - mean_ref[:]) * invstd_ref[:]
-    if mode == "relu":
-        g = jnp.where(xhat * scale_ref[:] + bias_ref[:] > 0, g, 0.0)
-    elif mode == "addrelu":
-        g = jnp.where(z_ref[:].astype(jnp.float32) > 0, g, 0.0)
-        dr_ref[:] = g.astype(dr_ref.dtype)
-
-    s_dy = jnp.sum(g, axis=0, keepdims=True)
-    s_dyx = jnp.sum(g * xhat, axis=0, keepdims=True)
-    rows = jax.lax.broadcasted_iota(jnp.int32, sums_ref.shape, 0)
-    upd = jnp.where(rows == 0, s_dy, jnp.where(rows == 1, s_dyx, 0.0))
-
-    @pl.when(i == 0)
-    def _():
-        sums_ref[:] = jnp.zeros_like(sums_ref)
-
-    sums_ref[:] = sums_ref[:] + upd
-
-
-def _dx_kernel(mode, x_ref, g_ref, scale_ref, bias_ref, mean_ref,
-               invstd_ref, k1_ref, k2_ref, dx_ref):
-    x = x_ref[:].astype(jnp.float32)
-    g = g_ref[:].astype(jnp.float32)
-    xhat = (x - mean_ref[:]) * invstd_ref[:]
-    if mode == "relu":
-        # recompute the mask; for "addrelu" g is the already-masked dr
-        g = jnp.where(xhat * scale_ref[:] + bias_ref[:] > 0, g, 0.0)
-    dx = (scale_ref[:] * invstd_ref[:]) * (g - k1_ref[:] - xhat * k2_ref[:])
-    dx_ref[:] = dx.astype(dx_ref.dtype)
-
-
-def _bwd_pallas(cfg: _Cfg, x, scale, bias, mean, invstd, count, z, dz,
-                has_residual: bool, r_dtype, rb: int):
-    c = x.shape[-1]
-    m = x.size // c
-    x2 = x.reshape(m, c)
-    g2 = dz.reshape(m, c)
-    mode = ("addrelu" if (cfg.relu and has_residual)
-            else "relu" if cfg.relu else "plain")
-
-    row = lambda v: v.astype(jnp.float32).reshape(1, c)
-    params = [row(scale), row(bias), row(mean), row(invstd)]
-
-    blk = pl.BlockSpec((rb, c), lambda i: (i, 0), memory_space=pltpu.VMEM)
-    prow = pl.BlockSpec((1, c), lambda i: (0, 0), memory_space=pltpu.VMEM)
-    acc = pl.BlockSpec((8, c), lambda i: (0, 0), memory_space=pltpu.VMEM)
-
-    # pass 1: channel sums (+ dr for the residual join)
-    in_specs = [blk, blk] + ([blk] if mode == "addrelu" else []) \
-        + [prow] * 4
-    args = [x2, g2] + ([z.reshape(m, c)] if mode == "addrelu" else []) \
-        + params
-    out_specs = [acc]
-    out_shapes = [jax.ShapeDtypeStruct((8, c), jnp.float32)]
-    if mode == "addrelu":
-        out_specs.append(blk)
-        out_shapes.append(jax.ShapeDtypeStruct((m, c), r_dtype))
-    res = pallas_call(
-        functools.partial(_sums_kernel, mode),
-        grid=(m // rb,),
-        in_specs=in_specs,
-        out_specs=tuple(out_specs),
-        out_shape=tuple(out_shapes),
-        name="apex_bn_act_bwd_stats",
-    )(*args)
-    sums = res[0]
-    dr2 = res[1] if mode == "addrelu" else None
-
-    sum_dy, sum_dy_xhat = sums[0], sums[1]
-    if cfg.axis_name is not None:
-        sum_dy, sum_dy_xhat = jax.lax.psum(
-            (sum_dy, sum_dy_xhat), cfg.axis_name,
-            axis_index_groups=cfg.groups)
-
-    k1 = (sum_dy / count).reshape(1, c)
-    k2 = (sum_dy_xhat / count).reshape(1, c)
-
-    # pass 2: dx. For the residual join g-source is dr (pre-masked), so
-    # z is not re-read.
-    g_src = dr2 if mode == "addrelu" else g2
-    dx2 = pallas_call(
-        functools.partial(_dx_kernel,
-                          "relu" if mode == "relu" else "plain"),
-        grid=(m // rb,),
-        in_specs=[blk, blk] + [prow] * 6,
-        out_specs=blk,
-        out_shape=jax.ShapeDtypeStruct((m, c), x.dtype),
-        name="apex_bn_act_bwd_dx",
-    )(x2, g_src, *params, k1, k2)
-
-    dx = dx2.reshape(x.shape)
-    dscale = sum_dy_xhat.astype(scale.dtype)
-    dbias = sum_dy.astype(bias.dtype)
-    if has_residual:
-        # no relu in the unit ⇒ dr is dz itself (identity add)
-        dr = (dr2.reshape(x.shape) if dr2 is not None
-              else dz.astype(r_dtype))
-        return dx, dr, dscale, dbias
-    return dx, dscale, dbias
-
-
-def _bwd_core(cfg: _Cfg, x, scale, bias, mean, invstd, count, z, dz,
-              has_residual: bool, r_dtype=None, dx_dtype=None):
-    """Dispatch: jnp two-pass backward (the product path — XLA fuses it
-    into exactly one reduce + one elementwise pass per unit). The Pallas
-    variant exists behind ``APEX_TPU_BN_PALLAS_BWD=1``: measured on the
-    bench it LOSES — XLA lays conv activations out as {3,0,2,1} (batch
-    inside spatial) and a pallas custom-call pins default layouts, so
-    every operand pays a 400 MB-class layout copy (see PERF.md round 3).
-    """
-    if os.environ.get("APEX_TPU_BN_PALLAS_BWD") == "1" and not cfg.fp8:
-        c = x.shape[-1]
-        rb = _bwd_row_block(x.size // c, c)
-        if rb >= 8:
-            return _bwd_pallas(cfg, x, scale, bias, mean, invstd, count,
-                               z, dz, has_residual, r_dtype, rb)
-    return _bwd_jnp(cfg, x, scale, bias, mean, invstd, count, z, dz,
-                    has_residual, r_dtype, dx_dtype)
-
-
-def _bwd_jnp(cfg: _Cfg, x, scale, bias, mean, invstd, count, z, dz,
-             has_residual: bool, r_dtype=None, dx_dtype=None):
+def _bwd(cfg: _Cfg, x, scale, bias, mean, invstd, count, z, dz,
+         has_residual: bool, r_dtype=None):
     """The two-pass minimal backward. Reads: (x, g-source) twice; writes
     dx[, dr]. x̂ is recomputed, never re-read.
 
@@ -323,9 +144,6 @@ def _bwd_jnp(cfg: _Cfg, x, scale, bias, mean, invstd, count, z, dz,
     scale32 = scale.astype(jnp.float32)
 
     def xhat_of(xv):
-        if cfg.fp8:
-            # the residual already IS x̂ (fp8); dequantize in-register
-            return xv.astype(jnp.float32)
         return (xv.astype(jnp.float32) - mean_b) * invstd_b
 
     dr = None
@@ -364,7 +182,7 @@ def _bwd_jnp(cfg: _Cfg, x, scale, bias, mean, invstd, count, z, dz,
     g2 = masked(g_src)
     xhat2 = xhat_of(x)
     dx = ((scale32 * invstd).reshape(cshape)
-          * (g2 - k1 - xhat2 * k2)).astype(dx_dtype or x.dtype)
+          * (g2 - k1 - xhat2 * k2)).astype(x.dtype)
     dscale = sum_dy_xhat.astype(scale.dtype)
     dbias = sum_dy.astype(bias.dtype)
     if has_residual:
@@ -390,19 +208,14 @@ def bn_act_train(x, scale, bias, cfg: _Cfg):
 
 def _bn_act_fwd(x, scale, bias, cfg):
     z, mean, var, count, invstd = _fwd_common(x, None, scale, bias, cfg)
-    xres = _xres_of(x, mean, invstd, cfg)
-    xtok = jnp.zeros((), x.dtype)       # dx dtype token
-    return (z, mean, var, count), (xres, xtok, scale, bias, mean,
-                                   invstd, count)
+    return (z, mean, var, count), (x, scale, bias, mean, invstd, count)
 
 
 def _bn_act_bwd(cfg, res, cts):
     dz = cts[0]  # stat cotangents dropped: stats are buffers
-    xres, xtok, scale, bias, mean, invstd, count = res
-    dx, dscale, dbias = _bwd_core(cfg, xres, scale, bias, mean, invstd,
-                                  count, None, dz, has_residual=False,
-                                  dx_dtype=xtok.dtype)
-    return dx, dscale, dbias
+    x, scale, bias, mean, invstd, count = res
+    return _bwd(cfg, x, scale, bias, mean, invstd, count, None, dz,
+                has_residual=False)
 
 
 bn_act_train.defvjp(_bn_act_fwd, _bn_act_bwd)
@@ -425,21 +238,15 @@ def _bn_add_act_fwd(x, r, scale, bias, cfg):
     # conv input) so saving it adds no HBM tensor
     zres = z if cfg.relu else None
     rtok = jnp.zeros((), r.dtype)  # dtype token (residual leaves: arrays)
-    xres = _xres_of(x, mean, invstd, cfg)
-    xtok = jnp.zeros((), x.dtype)
-    return (z, mean, var, count), (xres, xtok, scale, bias, mean,
-                                   invstd, count, zres, rtok)
+    return (z, mean, var, count), (x, scale, bias, mean, invstd, count,
+                                   zres, rtok)
 
 
 def _bn_add_act_bwd(cfg, res, cts):
     dz = cts[0]
-    xres, xtok, scale, bias, mean, invstd, count, z, rtok = res
-    dx, dr, dscale, dbias = _bwd_core(cfg, xres, scale, bias, mean,
-                                      invstd, count, z, dz,
-                                      has_residual=True,
-                                      r_dtype=rtok.dtype,
-                                      dx_dtype=xtok.dtype)
-    return dx, dr, dscale, dbias
+    x, scale, bias, mean, invstd, count, z, rtok = res
+    return _bwd(cfg, x, scale, bias, mean, invstd, count, z, dz,
+                has_residual=True, r_dtype=rtok.dtype)
 
 
 bn_add_act_train.defvjp(_bn_add_act_fwd, _bn_add_act_bwd)
@@ -447,10 +254,9 @@ bn_add_act_train.defvjp(_bn_add_act_fwd, _bn_add_act_bwd)
 
 def make_cfg(*, relu: bool, eps: float = 1e-5,
              axis_name: Optional[str] = None,
-             axis_index_groups=None, fp8: bool = False) -> _Cfg:
+             axis_index_groups=None) -> _Cfg:
     return _Cfg(relu=bool(relu), eps=float(eps), axis_name=axis_name,
-                groups=_normalize_groups(axis_index_groups),
-                fp8=bool(fp8))
+                groups=_normalize_groups(axis_index_groups))
 
 
 def bn_act_reference(x, scale, bias, *, residual=None, relu=True,
@@ -488,14 +294,6 @@ class FusedBNAct(nn.Module):
     axis_index_groups: Optional[Sequence[Sequence[int]]] = None
     init_scale: float = 1.0
     dtype: Optional[Any] = None
-    #: fp8 backward-only residuals (or env APEX_TPU_FP8_RESIDUALS=1 at
-    #: trace time); see _Cfg.fp8. Caveat (ADVICE r5): with ReLU the
-    #: backward re-derives the activation mask from the *quantized* x̂,
-    #: so activations within ~one e4m3 quantum of the y==0 boundary can
-    #: receive gradients through a flipped mask — an extra noise source
-    #: beyond the quantization noise itself. Fine for the opt-in
-    #: memory-bandwidth experiment; don't expect bitwise-stable masks.
-    fp8_residuals: bool = False
 
     @nn.compact
     def __call__(self, x, residual=None, train: bool = True):
@@ -520,11 +318,8 @@ class FusedBNAct(nn.Module):
             return y.astype(x.dtype)
 
         axis = None if self.is_initializing() else self.axis_name
-        fp8 = (self.fp8_residuals
-               or os.environ.get("APEX_TPU_FP8_RESIDUALS") == "1")
         cfg = make_cfg(relu=self.relu, eps=self.epsilon, axis_name=axis,
-                       axis_index_groups=self.axis_index_groups,
-                       fp8=fp8)
+                       axis_index_groups=self.axis_index_groups)
         if residual is None:
             z, mean, var, count = bn_act_train(x, scale, bias, cfg)
         else:

@@ -661,7 +661,7 @@ def _():
     _vs_interpret(step, p, g, m, vnorm)
 
 
-# --- fused BN unit (Pallas two-pass backward) --------------------------------
+# --- fused BN unit -----------------------------------------------------------
 
 @case("bn_act/relu-grads")
 def _():
@@ -711,59 +711,6 @@ def _():
     want = jax.grad(loss_ref, argnums=(0, 1, 2, 3))(x, r, s, b)
     for gg, ww in zip(got, want):
         _check("bn_act grad", gg, ww, 5e-2, rtol=2e-2)
-
-
-@case("bn_act/pallas-bwd-variant")
-def _():
-    """The opt-in Pallas two-pass backward (APEX_TPU_BN_PALLAS_BWD=1):
-    not the default path (it loses to XLA on layout copies — PERF.md),
-    but it must stay Mosaic-legal across the channel grid since it is
-    the shipped fallback-free kernel surface."""
-    from apex_tpu.ops.bn_act import (bn_act_reference, bn_act_train,
-                                     bn_add_act_train, make_cfg)
-    old = os.environ.get("APEX_TPU_BN_PALLAS_BWD")
-    os.environ["APEX_TPU_BN_PALLAS_BWD"] = "1"
-    try:
-        cfg = make_cfg(relu=True)
-        for c, with_res in ((64, False), (256, True), (2048, True)):
-            x = _rand((8, 7, 7, c), 0, jnp.bfloat16)
-            r = _rand((8, 7, 7, c), 1, jnp.bfloat16)
-            s = _rand((c,), 2) * 0.5 + 1.0
-            b = _rand((c,), 3) * 0.1
-            g = _rand((8, 7, 7, c), 4)
-
-            if with_res:
-                def loss(x, r, s, b):
-                    z, *_ = bn_add_act_train(x, r, s, b, cfg)
-                    return jnp.sum(z.astype(jnp.float32) * g)
-
-                def loss_ref(x, r, s, b):
-                    z, _, _ = bn_act_reference(x, s, b, residual=r,
-                                               relu=True)
-                    return jnp.sum(z.astype(jnp.float32) * g)
-
-                got = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3)))(
-                    x, r, s, b)
-                want = jax.grad(loss_ref, argnums=(0, 1, 2, 3))(
-                    x, r, s, b)
-            else:
-                def loss(x, s, b):
-                    z, *_ = bn_act_train(x, s, b, cfg)
-                    return jnp.sum(z.astype(jnp.float32) * g)
-
-                def loss_ref(x, s, b):
-                    z, _, _ = bn_act_reference(x, s, b, relu=True)
-                    return jnp.sum(z.astype(jnp.float32) * g)
-
-                got = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(x, s, b)
-                want = jax.grad(loss_ref, argnums=(0, 1, 2))(x, s, b)
-            for gg, ww in zip(got, want):
-                _check(f"bn_act pallas c={c}", gg, ww, 5e-2, rtol=2e-2)
-    finally:
-        if old is None:
-            del os.environ["APEX_TPU_BN_PALLAS_BWD"]
-        else:
-            os.environ["APEX_TPU_BN_PALLAS_BWD"] = old
 
 
 # --- monitor: zero-dispatch telemetry contract -------------------------------

@@ -82,7 +82,6 @@ _FAMILY_PATTERNS: Tuple[Tuple[str, str], ...] = (
     ("bn_act", "bn_act"),
     ("bn_bwd", "bn_act"),
     ("batchnorm", "bn_act"),
-    ("conv_bn", "bn_act"),
     ("xentropy", "xentropy"),
     ("cross_entropy", "xentropy"),
     ("softmax_xent", "xentropy"),

@@ -106,7 +106,7 @@ def main():
         # group key: the model block from op_name, else the opcode
         key = opcode
         m = re.search(r"(BottleneckBlock_\d+|stem\w*|Dense_\d+|_BN_\d+"
-                      r"|FusedSGD|ConvBNAct_\d+)", op_name)
+                      r"|FusedSGD)", op_name)
         blk = m.group(1) if m else (op_name.split("/")[1]
                                     if op_name.count("/") > 1 else opcode)
         fwd = "jvp" in op_name and "transpose" not in op_name

@@ -620,3 +620,41 @@ def test_lse_variant_bias_cotangent():
         db_ref = jax.jit(jax.grad(loss_ref))(bias)
     np.testing.assert_allclose(np.asarray(db), np.asarray(db_ref),
                                atol=2e-4)
+
+
+MIB = 2 ** 20
+
+
+# Expected plans are the parent's: read off what `_flash_bwd_nl` asked
+# `pallas_call` for before the choice moved into `_bwd_plan` (PR 30).
+@pytest.mark.parametrize("shape,want", [
+    # BERT-Large's: one block, fused sweep, eight heads a step
+    pytest.param(dict(batch=16, s=512, d=64, nh=16, itemsize=2),
+                 (512, 512, 8, None, "fused"), id="bert-single-block-fused"),
+    # Kimi's MLA with the tiles models/kimi_linear.py passes: a 1 MiB
+    # score tile is under the 4 MiB at which the limit is raised
+    pytest.param(dict(batch=1, s=8192, d=192, nh=32, itemsize=2,
+                      blocks=(1024, 256)),
+                 (1024, 256, 2, None, "two_kernel"), id="mla-not-raised"),
+    pytest.param(dict(batch=4, s=2048, d=64, nh=16, itemsize=2),
+                 (1024, 1024, 2, 32 * MIB, "two_kernel_raised"),
+                 id="1024sq-raised"),
+    # the raised path's own ledger passes 32 MiB at d = 192: back to
+    # 512 tiles under the default limit, at most 2 · g0 = 4 heads
+    pytest.param(dict(batch=1, s=2048, d=192, nh=32, itemsize=2),
+                 (512, 512, 4, None, "two_kernel"), id="over-32mib-to-512"),
+    # float32, one block of 1024: the fused sweep passes 13 MiB even at
+    # g0 = 1 and splits
+    pytest.param(dict(batch=1, s=1024, d=128, nh=16, itemsize=4),
+                 (1024, 1024, 1, None, "two_kernel"), id="f32-fused-splits"),
+    pytest.param(dict(batch=4, s=2048, d=64, nh=16, itemsize=2,
+                      dropout_rate=0.1),
+                 (512, 512, 8, None, "two_kernel"), id="dropout-capped"),
+])
+def test_backward_plan(shape, want):
+    kw = dict(shape)
+    batch, s, d, nh, itemsize = (
+        kw.pop(k) for k in ("batch", "s", "d", "nh", "itemsize"))
+    blocks = kw.pop("blocks", (A.DEFAULT_BLOCK_Q, A.DEFAULT_BLOCK_K))
+    plan = A._bwd_plan(nh, d, s, s, batch * nh, itemsize, *blocks, **kw)
+    assert tuple(plan) == want

@@ -96,6 +96,19 @@ def test_an_unknown_name_is_refused():
                               out_shape=())
 
 
+def test_ops_and_models_read_no_experiment_switch():
+    """The only ``APEX_TPU_*`` variables ``ops/`` and ``models/`` know are
+    the interpreter override and the autotuner's two: a kernel has one
+    path, chosen from the shapes it sees (PR 30 deleted six switches that
+    selected paths which had lost on the chip)."""
+    found = set()
+    for sub in ("ops", "models"):
+        for path in (ROOT / "apex_tpu" / sub).rglob("*.py"):
+            found |= set(re.findall(r"APEX_TPU_[A-Z0-9_]+", path.read_text()))
+    assert found == {"APEX_TPU_FORCE_INTERPRET", "APEX_TPU_AUTOTUNE",
+                     "APEX_TPU_AUTOTUNE_DB"}
+
+
 # ---- the benchmark's steps, lowered: the scopes are there and cost nothing --
 
 def _load(name, path):

@@ -38,6 +38,66 @@ class TestResNet:
         assert 25e6 < n < 26e6, n
 
 
+def _resnet_layout(stage_sizes, bottleneck, width=64, classes=1000):
+    """``path -> shape`` of the ResNet parameters as checkpoints hold them,
+    written out from the architecture: in a block the convolutions are
+    numbered main path first (``Conv_2`` is a bottleneck's final 1x1), the
+    projection last (``Conv_3``; a basic block's ``Conv_2``), and the
+    projection's BN sits before the unit that joins the residual."""
+    bn = "FusedBNAct_0"
+    out = {"stem_conv/kernel": (7, 7, 3, width),
+           f"_BN_0/{bn}/scale": (width,), f"_BN_0/{bn}/bias": (width,)}
+    cin, k = width, 0
+    for i, n_blocks in enumerate(stage_sizes):
+        f = width * 2 ** i
+        cout = 4 * f if bottleneck else f
+        for j in range(n_blocks):
+            name = f"{'BottleneckBlock' if bottleneck else 'BasicBlock'}_{k}"
+            proj = cin != cout or (i > 0 and j == 0)
+            convs = ([(1, 1, cin, f), (3, 3, f, f), (1, 1, f, cout)]
+                     if bottleneck else [(3, 3, cin, f), (3, 3, f, f)])
+            bns = [f] * (len(convs) - 1)
+            if proj:
+                convs.append((1, 1, cin, cout))
+                bns.append(cout)
+            bns.append(cout)
+            for c, shape in enumerate(convs):
+                out[f"{name}/Conv_{c}/kernel"] = shape
+            for c, ch in enumerate(bns):
+                out[f"{name}/_BN_{c}/{bn}/scale"] = (ch,)
+                out[f"{name}/_BN_{c}/{bn}/bias"] = (ch,)
+            cin, k = cout, k + 1
+    out["Dense_0/kernel"] = (cin, classes)
+    out["Dense_0/bias"] = (classes,)
+    return out
+
+
+@pytest.mark.parametrize("arch", ["ResNet18", "ResNet50", "ResNet101"])
+def test_resnet_param_tree_is_the_checkpoint_layout(arch):
+    from flax.traverse_util import flatten_dict
+    stage_sizes, bottleneck = {"ResNet18": ([2, 2, 2, 2], False),
+                               "ResNet50": ([3, 4, 6, 3], True),
+                               "ResNet101": ([3, 4, 23, 3], True)}[arch]
+    model = getattr(models, arch)()
+    variables = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 64, 64, 3)), train=True))
+    got = {k: v.shape
+           for k, v in flatten_dict(variables["params"], sep="/").items()}
+    want = _resnet_layout(stage_sizes, bottleneck)
+    assert got == want
+    if bottleneck:
+        # the first block of a stage projects: its final 1x1 and its
+        # projection, by the names a checkpoint knows them under
+        assert got["BottleneckBlock_3/Conv_2/kernel"] == (1, 1, 128, 512)
+        assert got["BottleneckBlock_3/Conv_3/kernel"] == (1, 1, 256, 512)
+        assert "BottleneckBlock_4/Conv_3/kernel" not in got
+    stats = flatten_dict(variables["batch_stats"], sep="/")
+    assert ({k.removesuffix("/mean") for k in stats if k.endswith("/mean")}
+            == {k.removesuffix("/scale") for k in got
+                if k.endswith("/scale")})
+
+
 class TestTransformer:
     def test_encoder_forward(self):
         enc = models.BertEncoder(vocab_size=100, hidden=64, layers=2,
