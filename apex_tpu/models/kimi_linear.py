@@ -42,6 +42,10 @@ from apex_tpu.ops import moe
 from apex_tpu.ops.delta_rule import gated_delta_rule
 
 _init = nn.initializers.normal(0.02)
+#: what a recomputed block keeps beside its input: whatever a forward kernel
+#: of ``ops`` wrote and its backward reads, so the rerun holds no kernel
+_KEEP_KERNEL_OUTPUTS = jax.checkpoint_policies.save_only_these_names(
+    *ops.KEPT_NAMES)
 #: (block_q, block_k) of MLA's attention. The kernels' VMEM ledger was
 #: fitted at a head size of 64: at 192 the v5e's compiler refuses their
 #: default 1024 x 1024 (17.7 MiB of the 16 MiB scoped VMEM in the forward)
@@ -265,7 +269,11 @@ class KimiLinear(nn.Module):
 
     ``layer_kinds``: a ``("kda" | "mla", "dense" | "moe")`` pair a layer.
     ``remat``: run each block's forward again in the backward instead of
-    keeping its activations.
+    keeping its activations. The rerun keeps a block's input and what the
+    forward kernels of ``ops`` wrote (``ops.KEPT_NAMES``: the delta rule's
+    output, chunk-start states and ``(I + A)^-1``; attention's ``o`` and
+    ``lse``), so it holds no kernel: projections, convolution, gates, norms
+    and the experts run again, a forward kernel runs once a step.
     """
     dims: KimiLinearDims
     layer_kinds: Sequence[Any]
@@ -276,7 +284,8 @@ class KimiLinear(nn.Module):
         d = self.dims
         x = nn.Embed(d.vocab_size, d.hidden, embedding_init=_init,
                      name="embed")(tokens).astype(jnp.float32)
-        block = nn.remat(Block) if self.remat else Block
+        block = (nn.remat(Block, policy=_KEEP_KERNEL_OUTPUTS)
+                 if self.remat else Block)
         loads = []
         for i, kinds in enumerate(self.layer_kinds):
             x, load = block(d, tuple(kinds), name=f"layers_{i}")(x)

@@ -44,6 +44,7 @@ from apex_tpu.ops.delta_rule import (
 )
 from apex_tpu.ops import moe
 from apex_tpu.ops import autotune
+from apex_tpu.ops._dispatch import KEPT_ATTN, KEPT_KDA, KEPT_NAMES
 
 __all__ = [
     "autotune",
@@ -57,4 +58,5 @@ __all__ = [
     "flash_attention", "attention_reference", "mask_softmax_dropout",
     "SelfMultiheadAttn", "EncdecMultiheadAttn",
     "gated_delta_rule", "gated_delta_rule_reference", "moe",
+    "KEPT_ATTN", "KEPT_KDA", "KEPT_NAMES",
 ]

@@ -49,6 +49,17 @@ KERNEL_NAMES = (
 )
 
 
+#: ``jax.ad_checkpoint.checkpoint_name`` tags on what a forward kernel wrote
+#: and the backward reads, set inside the ops' ``custom_vjp`` forward rules,
+#: one name a kernel family: attention's ``o`` and ``lse``; the delta rule's
+#: output, chunk-start states and ``(I + A)^-1``. A ``jax.checkpoint`` or
+#: ``nn.remat`` with ``policy=save_only_these_names(*KEPT_NAMES)`` keeps them,
+#: so that its rerun of the forward holds no kernel (docs/layers.md)
+KEPT_ATTN = "apex_attn_kept"
+KEPT_KDA = "apex_kda_kept"
+KEPT_NAMES = (KEPT_ATTN, KEPT_KDA)
+
+
 def pallas_call(kernel, *, name, **kwargs):
     """``pl.pallas_call`` for a kernel of this library, interpreted off a
     TPU. ``name`` becomes the kernel's name in the Mosaic module and the

@@ -14,7 +14,11 @@ sequence parallelism (apex_tpu.parallel.ring).
 Layout: (B, S, H, D) inputs, kernel works on (B·H, S, D). Forward saves
 (out, lse) residuals; backward recomputes probabilities blockwise (two
 kernels: dq over q-blocks, dk/dv over k-blocks), the standard
-recompute-over-store trade that wins on HBM bandwidth.
+recompute-over-store trade that wins on HBM bandwidth. The forward rule
+names ``o`` and ``lse`` ``ops.KEPT_ATTN`` (``checkpoint_name``; the identity
+outside a checkpoint): a ``jax.checkpoint`` or ``nn.remat`` round the op
+whose policy is ``save_only_these_names(*ops.KEPT_NAMES)`` keeps them, and
+its rerun of the forward holds no ``apex_attn_fwd``.
 
 Additive bias (the reference's additive-mask variants), causal masking,
 and softmax dropout all run inside the kernel. Dropout — fused in the
@@ -32,10 +36,11 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from apex_tpu.ops._dispatch import pallas_call, use_interpret
+from apex_tpu.ops._dispatch import KEPT_ATTN, pallas_call, use_interpret
 
 LANES = 128
 # Grid-step overhead on TPU dwarfs the per-tile MXU work at 128-blocks
@@ -1536,6 +1541,12 @@ def _tuned_qk(q, k, block_q, block_k, dropout_rate):
             int(blocks.get("block_k", block_k)))
 
 
+def _kept(o, lse):
+    """What the forward kernel wrote and the backward reads, under the name
+    a checkpoint policy keeps it by: the identity anywhere else."""
+    return checkpoint_name(o, KEPT_ATTN), checkpoint_name(lse, KEPT_ATTN)
+
+
 def _flash_attention_fwd_res(q, k, v, bias, dropout_seed, scale, causal,
                              block_q, block_k, dropout_rate,
                              causal_offset=None, dbo=None):
@@ -1581,7 +1592,7 @@ def _flash_attention_fwd_res(q, k, v, bias, dropout_seed, scale, causal,
                                 block_q, block_k, dropout_rate, seed,
                                 causal_off=off, bias_g=bias_nl,
                                 bias_mode=bias_mode, dbo=dbo)
-        o = o2.reshape(b, sq, h, d)
+        o, lse = _kept(o2.reshape(b, sq, h, d), lse)
         return o, (q, k, v, bias, dropout_seed, o, lse, causal_offset)
     eff_bias, eff_causal = bias, causal
     if off is not None:
@@ -1592,7 +1603,7 @@ def _flash_attention_fwd_res(q, k, v, bias, dropout_seed, scale, causal,
     bias_g, bidx = _bias_group(eff_bias, b, h, sq, k.shape[1])
     o3, lse = _flash_fwd(q3, k3, v3, bias_g, bidx, scale, eff_causal,
                          block_q, block_k, dropout_rate, seed)
-    o = jnp.swapaxes(o3.reshape(b, h, sq, d), 1, 2)
+    o, lse = _kept(jnp.swapaxes(o3.reshape(b, h, sq, d), 1, 2), lse)
     return o, (q, k, v, bias, dropout_seed, o, lse, causal_offset)
 
 

@@ -31,6 +31,14 @@ at a time, and keeps the inputs and the states; its backward runs
 ``_prepare`` again. What the op sees of its inputs' shapes picks the path;
 no argument does.
 
+Under recomputation. The forward rule names what it hands the backward
+beside the inputs (the output, the states and, from the kernel, ``(I +
+A)^-1``) ``ops.KEPT_KDA`` by ``jax.ad_checkpoint.checkpoint_name``: a
+``jax.checkpoint`` or ``nn.remat`` round the op with
+``policy=jax.checkpoint_policies.save_only_these_names(*ops.KEPT_NAMES)``
+keeps them, and its rerun of the forward then holds no ``apex_kda_fwd``.
+Outside a checkpoint the name is the identity and lowers to nothing.
+
 Decay. ``g <= 0`` is the log of the decay, and ``G`` its running sum from
 the chunk's start. ``exp(G_t - G_s)`` for ``s <= t`` is at most 1, but the
 factorisation ``exp(G_t) * exp(-G_s)`` that turns it into a matmul is not:
@@ -57,6 +65,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
+
+from apex_tpu.ops._dispatch import KEPT_KDA, pallas_call
 
 CHUNK = 64
 SUB = 16
@@ -425,7 +436,6 @@ def _forward_kernel(q, k, v, g, beta):
     """The op by the forward kernel: the output ``(B, T, H, d_v)``, the
     chunk-start states ``(N, B, H, d_k, d_v)`` and ``(I + A)^-1`` ``(N, B, H,
     C, C)``, both for the backward kernel."""
-    from apex_tpu.ops._dispatch import pallas_call
     sp = _specs(q, v)
     (b, t, h, dk), dv, c = q.shape, v.shape[-1], CHUNK
     out, states, inverse = pallas_call(
@@ -442,7 +452,6 @@ def _forward_kernel(q, k, v, g, beta):
 
 def _backward_kernel(q, k, v, g, beta, states, inverse, d_out):
     """Cotangents of the op's inputs, in their layout and dtypes."""
-    from apex_tpu.ops._dispatch import pallas_call
     sp = _specs(q, v, reverse=True)
     (b, t, h, dk), dv, c = q.shape, v.shape[-1], CHUNK
     *grads, d_beta = pallas_call(
@@ -532,6 +541,7 @@ def _scan(q, k, v, g, beta):
 
 def _scan_fwd(q, k, v, g, beta):
     out, kept = _forward(q, k, v, g, beta)
+    out, *kept = (checkpoint_name(x, KEPT_KDA) for x in (out, *kept))
     return out, (q, k, v, g, beta, *kept)
 
 
