@@ -42,6 +42,9 @@ FLOAT_OPS = {
     # ops/delta_rule.py: the recurrence's state, decay and triangular solve;
     # ops/moe.py: the router's scores, the choice and the weights
     "gated_delta_rule", "moe_router",
+    # models/qwen3_next.py: the rotation of q and k by position (angles up
+    # to the context length: bfloat16 holds 8 bits of them)
+    "rotary",
 }
 
 # Multi-arg elementwise ops: promote to the widest floating dtype

@@ -1,8 +1,11 @@
 """apex_tpu.models — the model families the reference's examples/configs
 exercise (BASELINE.json): ResNet (imagenet example), DCGAN (multi-loss amp
 example), BERT-style transformer (FusedLAMB config), RNN stacks
-(`apex.RNN`), and a Kimi-Linear decoder (delta-rule linear attention, latent
-attention, routed experts: one expert-parallel rank's share).
+(`apex.RNN`), and two decoders over one shell (`decoder.py`: pre-norm blocks,
+routed experts at one expert-parallel rank's share, next-token loss):
+Kimi-Linear (delta-rule linear attention with a decay a channel, latent
+attention) and Qwen3-Next (gated DeltaNet with a decay a head and shared key
+heads, gated grouped-query attention with partial rotary, softmax router).
 """
 
 from apex_tpu.models.resnet import (
@@ -14,9 +17,16 @@ from apex_tpu.models.transformer import (
     FusedLayerNormModule, mlm_loss,
 )
 from apex_tpu.models.dcgan import Generator, Discriminator
+from apex_tpu.models.decoder import (
+    Block, Decoder, ExpertFFN, RMSNorm, SwiGLU, lm_loss,
+)
 from apex_tpu.models.kimi_linear import (
     KimiLinear, KimiLinearDims, KimiDeltaAttention, LatentAttention,
-    ExpertFFN, RMSNorm, kimi_linear_from_config, lm_loss,
+    kimi_linear_from_config,
+)
+from apex_tpu.models.qwen3_next import (
+    Qwen3Next, Qwen3NextDims, GatedDeltaNet, GatedAttention,
+    partial_rotary, qwen3_next_from_config,
 )
 
 __all__ = [
@@ -25,6 +35,9 @@ __all__ = [
     "BertEncoder", "BertLarge", "TransformerLayer", "MultiheadAttention",
     "FusedLayerNormModule", "mlm_loss",
     "Generator", "Discriminator",
+    "Block", "Decoder", "ExpertFFN", "RMSNorm", "SwiGLU", "lm_loss",
     "KimiLinear", "KimiLinearDims", "KimiDeltaAttention", "LatentAttention",
-    "ExpertFFN", "RMSNorm", "kimi_linear_from_config", "lm_loss",
+    "kimi_linear_from_config",
+    "Qwen3Next", "Qwen3NextDims", "GatedDeltaNet", "GatedAttention",
+    "partial_rotary", "qwen3_next_from_config",
 ]

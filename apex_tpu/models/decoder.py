@@ -1,0 +1,229 @@
+"""What the decoders share: the pre-norm block shell over a float32 residual
+stream, RMSNorm, SwiGLU, the expert layer of one expert-parallel rank, the
+short convolution of the delta-rule layers, the untied head and the
+next-token loss.
+
+A decoder is :class:`Decoder` over a ``dims`` of its own (a frozen
+dataclass: ``models/kimi_linear.py``, ``models/qwen3_next.py``). The shell
+asks ``dims`` for what differs between them and holds no model's name:
+
+``dims.mixer(kind)``   the token mixer of a layer of that kind, a module
+                       named by its kind (``kda``, ``mla``, ``gdn``, ``gattn``);
+``dims.norm(name)``    the norm of the blocks and the final one;
+``dims.experts()``     the :class:`ExpertFFN` of an expert layer;
+``dims.hidden``, ``dims.vocab_size``, ``dims.dense_width``, ``dims.held``,
+``dims.top_k``, ``dims.n_routed``.
+
+Written for ``amp.auto_cast``: the projections are ``nn.Dense`` (half under
+O1); the router, the convolution and the norms are float32
+(``amp/lists.py``).
+
+**One expert-parallel rank's share.** ``held`` lists the ids of the experts
+this rank holds, and the expert weights are ``(len(held), ...)``. The layer
+routes every token over all ``n_routed`` experts, normalises the weights
+over all the chosen ones, and returns the shared expert plus the chosen
+experts *that are in* ``held``: what this rank adds to the all-reduced sum
+of a deployment (the shared expert is every rank's alike, counted once).
+With ``held = range(n_routed)`` it is the whole layer. No token is dropped
+for any routing (``ops/moe.py``).
+
+The loss returns, beside itself, what a monitor wants of the routing:
+``rows_routed_here``, ``expert_load`` and ``experts_over_capacity``, a row
+for each expert layer.
+
+The expert layer and the head run under ``jax.named_scope``s a device trace
+can be cut by: ``moe/{route,dispatch,experts,combine,shared,overflow}``,
+``lm/head``; each mixer names its own.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Optional, Sequence
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from apex_tpu import ops
+from apex_tpu.ops import moe
+
+_init = nn.initializers.normal(0.02)
+#: what a recomputed block keeps beside its input: whatever a forward kernel
+#: of ``ops`` wrote and its backward reads, so the rerun holds no kernel
+_KEEP_KERNEL_OUTPUTS = jax.checkpoint_policies.save_only_these_names(
+    *ops.KEPT_NAMES)
+
+
+def _dense(features, name):
+    return nn.Dense(features, use_bias=False, kernel_init=_init, name=name)
+
+
+class RMSNorm(nn.Module):
+    """``x / rms(x) * scale`` over the last axis, in float32.
+    ``zero_centred``: the learned ``scale`` starts at zero and the norm
+    multiplies by ``1 + scale``."""
+    eps: float = 1e-5
+    zero_centred: bool = False
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param(
+            "scale", nn.initializers.zeros if self.zero_centred
+            else nn.initializers.ones, (x.shape[-1],), jnp.float32)
+        if self.zero_centred:
+            scale = 1.0 + scale
+        x = x.astype(jnp.float32)
+        return x * jax.lax.rsqrt(
+            jnp.mean(jnp.square(x), -1, keepdims=True) + self.eps) * scale
+
+
+def _conv_init(key, shape, dtype=jnp.float32):
+    bound = shape[0] ** -0.5            # a depthwise Conv1d's default
+    return jax.random.uniform(key, shape, dtype, -bound, bound)
+
+
+def _short_conv(x, taps):
+    """Causal depthwise convolution over the ``len(taps)`` newest tokens,
+    then SiLU. ``x`` ``(B, T, C)``, ``taps`` ``(K, C)``, newest last."""
+    k, t = taps.shape[0], x.shape[1]
+    padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (k - 1, 0), (0, 0)))
+    return jax.nn.silu(sum(padded[:, j:j + t] * taps[j] for j in range(k)))
+
+
+def _l2_normalised(x):
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), -1, keepdims=True) + 1e-6)
+
+
+class SwiGLU(nn.Module):
+    hidden: int
+    width: int
+
+    @nn.compact
+    def __call__(self, x):
+        gate = _dense(self.width, "gate_proj")(x)
+        up = _dense(self.width, "up_proj")(x)
+        return _dense(self.hidden, "down_proj")(jax.nn.silu(gate) * up)
+
+
+class ExpertFFN(nn.Module):
+    """The shared expert plus this rank's share of the routed ones. Returns
+    ``(y, expert_load)``.
+
+    ``scoring``: ``"sigmoid"`` scores with a selection bias ``e_bias`` that
+    no gradient moves, or ``"softmax"`` over all the experts and no bias
+    (``ops.moe.route``). ``shared_gate``: the shared expert's output times
+    ``sigmoid(w_s^T x)``, one learned gate a token."""
+    hidden: int
+    width: int
+    n_routed: int
+    top_k: int
+    held: Sequence[int]
+    scale: float
+    scoring: str = "sigmoid"
+    shared_gate: bool = False
+    shared_width: Optional[int] = None      # default: ``width``
+
+    @nn.compact
+    def __call__(self, x):
+        n = len(self.held)
+        router = self.param("router", _init, (self.hidden, self.n_routed),
+                            jnp.float32)
+        # the selection bias balances load outside the gradient: no cotangent
+        bias = (self.param("e_bias", nn.initializers.zeros, (self.n_routed,),
+                           jnp.float32)
+                if self.scoring == "sigmoid" else None)
+        w_gate, w_up = (self.param(name, _init, (n, self.hidden, self.width),
+                                   jnp.float32)
+                        for name in ("experts_gate", "experts_up"))
+        w_down = self.param("experts_down", _init,
+                            (n, self.width, self.hidden), jnp.float32)
+        rows = x.reshape(-1, self.hidden)
+        chosen, weights = moe.route(rows, router, bias, self.top_k,
+                                    self.scale, self.scoring)
+        y = moe.held_experts(rows, weights, chosen, w_gate, w_up, w_down,
+                             tuple(self.held), self.n_routed)
+        with jax.named_scope("moe/shared"):
+            shared = SwiGLU(self.hidden, self.shared_width or self.width,
+                            name="shared")(x)
+            if self.shared_gate:
+                shared = shared * jax.nn.sigmoid(_dense(1, "shared_gate")(x))
+        return (shared.astype(jnp.float32) + y.reshape(x.shape),
+                moe.expert_load(chosen, self.held))
+
+
+class Block(nn.Module):
+    """``h = x + Mix(norm(x))``, ``y = h + FFN(norm(h))``, with ``Mix`` the
+    ``dims``' mixer of kind ``kinds[0]`` and ``FFN`` dense or experts, as
+    ``kinds[1]`` says. Returns ``(y, expert_load or None)``."""
+    dims: Any
+    kinds: Sequence[str]
+
+    @nn.compact
+    def __call__(self, x):
+        d = self.dims
+        x = x + d.mixer(self.kinds[0])(d.norm("attn_norm")(x))
+        normed = d.norm("ffn_norm")(x)
+        if self.kinds[1] == "dense":
+            return x + SwiGLU(d.hidden, d.dense_width, name="mlp")(normed), None
+        y, load = d.experts()(normed)
+        return x + y, load
+
+
+class Decoder(nn.Module):
+    """Token ids ``(B, T)`` to logits ``(B, T, V)`` and the expert layers'
+    loads ``(n_moe, len(held))``.
+
+    ``layer_kinds``: a ``(mixer kind, "dense" | "moe")`` pair a layer.
+    ``remat``: run each block's forward again in the backward instead of
+    keeping its activations. The rerun keeps a block's input and what the
+    forward kernels of ``ops`` wrote (``ops.KEPT_NAMES``: the delta rule's
+    output, chunk-start states and ``(I + A)^-1``; attention's ``o`` and
+    ``lse``), so it holds no kernel: projections, convolution, gates, norms
+    and the experts run again, a forward kernel runs once a step.
+    """
+    dims: Any
+    layer_kinds: Sequence[Any]
+    remat: bool = False
+
+    @nn.compact
+    def __call__(self, tokens):
+        d = self.dims
+        x = nn.Embed(d.vocab_size, d.hidden, embedding_init=_init,
+                     name="embed")(tokens).astype(jnp.float32)
+        block = (nn.remat(Block, policy=_KEEP_KERNEL_OUTPUTS)
+                 if self.remat else Block)
+        loads = []
+        for i, kinds in enumerate(self.layer_kinds):
+            x, load = block(d, tuple(kinds), name=f"layers_{i}")(x)
+            if load is not None:
+                loads.append(load)
+        x = d.norm("final_norm")(x)
+        head = self.param("lm_head", _init, (d.hidden, d.vocab_size),
+                          jnp.float32)
+        with jax.named_scope("lm/head"):
+            from apex_tpu.amp.policy import current_policy
+            dtype = current_policy().op_dtype("matmul", x.dtype)
+            logits = x.astype(dtype) @ head.astype(dtype)
+        return logits, (jnp.stack(loads) if loads else
+                        jnp.zeros((0, len(d.held)), jnp.int32))
+
+
+def lm_loss(model, variables, tokens):
+    """Mean cross-entropy of token ``t + 1`` at position ``t`` (the last
+    position of each sequence has no label), over the fused softmax-CE.
+    Returns ``(loss, {"rows_routed_here", "expert_load",
+    "experts_over_capacity"})``, one row for each expert layer; the last
+    counts the held experts whose rows passed their capacity and took a
+    dense turn over every row (``moe/overflow``) in this step."""
+    logits, load = model.apply(variables, tokens)
+    labels = jnp.concatenate(
+        [tokens[:, 1:], jnp.full_like(tokens[:, :1], -1)], 1)
+    with jax.named_scope("lm/head"):
+        total = jnp.sum(ops.softmax_cross_entropy_loss(logits, labels))
+    loss = total / max(labels.shape[0] * (labels.shape[1] - 1), 1)
+    d = model.dims
+    cap = moe.capacity(tokens.size, d.top_k, d.n_routed)
+    return loss, {"rows_routed_here": jnp.sum(load, -1), "expert_load": load,
+                  "experts_over_capacity": jnp.sum(load > cap, -1,
+                                                   dtype=jnp.int32)}

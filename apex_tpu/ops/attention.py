@@ -1408,19 +1408,24 @@ def _flash_bwd_nl(q2, k2, v2, nh, d, lse, delta, do2, scale, causal,
 
 # --- public op --------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
 def flash_attention(q, k, v, bias=None, scale=None, causal=False,
                     block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
                     dropout_rate=0.0, dropout_seed=None,
                     causal_offset=None):
     """Blockwise softmax attention.
 
-    q: (B, Sq, H, D); k/v: (B, Sk, H, D); bias: optional additive
+    q: (B, Sq, H, D); k/v: (B, Sk, H, D), or (B, Sk, H_kv, D) with H_kv a
+    divisor of H (grouped-query attention: q head ``h`` reads k/v head
+    ``h // (H / H_kv)``); bias: optional additive
     (B|1, H|1, Sq|1, Sk|1) — the additive-mask variants of the reference
     (`self_multihead_attn_func.py` additive mask path). Returns
     (B, Sq, H, D). ``bias`` is differentiable (learned relative-position
     biases work); its gradient path materializes O(S²) scores, computed
     only when actually requested (see ``_bias_grad``).
+
+    Shared k/v heads reach the kernels repeated a group (in HBM, outside
+    the ``custom_vjp``: the repeat's own transpose sums ``dk`` and ``dv``
+    over a group); the kernels see one k/v head a q head, as before.
 
     ``dropout_rate > 0`` applies *softmax* dropout (on the normalized
     probabilities) inside the kernel — the fused Philox dropout of the
@@ -1437,6 +1442,15 @@ def flash_attention(q, k, v, bias=None, scale=None, causal=False,
     O(S²) additive bias; geometries that fall back to the bias path
     build the mask from the offset internally.
     """
+    if k.shape[2] != q.shape[2]:
+        k, v = (jnp.repeat(x, q.shape[2] // x.shape[2], 2) for x in (k, v))
+    return _flash_attention(q, k, v, bias, scale, causal, block_q, block_k,
+                            dropout_rate, dropout_seed, causal_offset)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash_attention(q, k, v, bias, scale, causal, block_q, block_k,
+                     dropout_rate, dropout_seed, causal_offset):
     o, _ = _flash_attention_fwd_res(q, k, v, bias, dropout_seed, scale,
                                     causal, block_q, block_k,
                                     dropout_rate, causal_offset)
@@ -1720,7 +1734,7 @@ def _bias_grad(q, k, v, bias, o, lse, do, scale, causal, *,
     return ds.astype(bias.dtype)
 
 
-flash_attention.defvjp(_fa_fwd, _fa_bwd)
+_flash_attention.defvjp(_fa_fwd, _fa_bwd)
 
 
 def attention_reference(q, k, v, bias=None, scale=None, causal=False):
@@ -1735,6 +1749,8 @@ def attention_reference(q, k, v, bias=None, scale=None, causal=False):
 def _attention_reference(q, k, v, bias, scale, causal):
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / np.sqrt(d)
+    if k.shape[2] != q.shape[2]:    # grouped-query: a k/v head a group
+        k, v = (jnp.repeat(x, q.shape[2] // x.shape[2], 2) for x in (k, v))
     s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     if bias is not None:

@@ -427,6 +427,91 @@ def _():
                atol=5e-2)
 
 
+def _rel(label, got, want, tol):
+    got, want = (np.asarray(x, np.float32) for x in (got, want))
+    err = float(np.max(np.abs(got - want)) / max(np.max(np.abs(want)), 1e-30))
+    assert np.all(np.isfinite(got)) and err <= tol, f"{label}: {err:.3e}"
+
+
+def _gqa_cell_case(t=8192, prefix=1024):
+    """Qwen3-Next's gated attention at the cell's shape (16 q heads on 2
+    k/v heads of 256, causal, bfloat16, the op's own tiles), forward and
+    the three gradients, against the dense oracle on a prefix: under a
+    causal mask the first rows see nothing of the rest."""
+    from apex_tpu.ops.attention import attention_reference, flash_attention
+    q = _rand((1, t, 16, 256), 0, jnp.bfloat16, 0.5)
+    k = _rand((1, t, 2, 256), 1, jnp.bfloat16, 0.5)
+    v = _rand((1, t, 2, 256), 2, jnp.bfloat16, 0.5)
+    w = _rand((1, prefix, 16, 256), 3, jnp.float32, 0.5)
+    loss = lambda fn: lambda q, k, v: jnp.sum(
+        fn(q, k, v, None, 1 / 16, True)[:, :prefix].astype(jnp.float32) * w)
+    out, grads = jax.jit(lambda *a: (
+        flash_attention(*a, None, 1 / 16, True),
+        jax.grad(loss(flash_attention), argnums=(0, 1, 2))(*a)))(q, k, v)
+    assert out.shape == q.shape and grads[1].shape == k.shape
+    head = tuple(x[:, :prefix].astype(jnp.float32) for x in (q, k, v))
+    _rel("gqa fwd", out[:, :prefix], attention_reference(
+        *head, None, 1 / 16, True), 3e-2)
+    want = jax.grad(loss(attention_reference), argnums=(0, 1, 2))(*head)
+    for name, g, r in zip("qkv", grads, want):
+        _rel(f"gqa d{name}", g[:, :prefix], r, 6e-2)
+        assert not np.any(np.asarray(g[:, prefix:], np.float32)), name
+
+
+@case("attention/gqa-d256-cell")
+def _():
+    _gqa_cell_case()
+
+
+# --- gated delta rule --------------------------------------------------------
+
+def _delta_rule_cell_case(t=8192, prefix=512, precision=None):
+    """Qwen3-Next's DeltaNet at the cell's shape (16 key heads serving 32
+    value heads of 128, one decay a head, float32 as the model hands them
+    over): the two kernels, fed the decay broadcast and the key heads
+    repeated, against the recurrence, one step a token: the output at every
+    token, all five gradients on a prefix (the recurrence's backward keeps a
+    state a token)."""
+    from apex_tpu.ops.delta_rule import (gated_delta_rule,
+                                         gated_delta_rule_reference)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    q = unit(_rand((1, t, 16, 128), 0)) * 128 ** -0.5
+    k = unit(_rand((1, t, 16, 128), 1))
+    v = _rand((1, t, 32, 128), 2)
+    g = -jnp.abs(_rand((1, t, 32), 3, scale=0.5)) - 1e-3
+    beta = jax.nn.sigmoid(_rand((1, t, 32), 4))
+    args = (q, k, v, g, beta)
+    loss = lambda fn: lambda *a: jnp.sum(
+        fn(*a)[:, :prefix] * jnp.cos(jnp.arange(128.0)))
+    with (jax.default_matmul_precision(precision) if precision
+          else contextlib.nullcontext()):
+        out, grads = jax.jit(lambda *a: (
+            gated_delta_rule(*a),
+            jax.grad(loss(gated_delta_rule), argnums=range(5))(*a)))(*args)
+    with jax.default_matmul_precision("highest"):
+        ref = jax.jit(gated_delta_rule_reference)(*args)
+        want = jax.jit(jax.grad(loss(gated_delta_rule_reference),
+                                argnums=range(5)))(
+            *(x[:, :prefix] for x in args))
+    assert out.shape == v.shape and out.dtype == jnp.float32
+    _rel("delta rule fwd", out, ref, 2e-2)
+    for name, x, a, b in zip("q k v g beta".split(), args, grads, want):
+        assert a.shape == x.shape, name
+        _rel(f"delta rule d{name}", a[:, :prefix], b, 5e-2)
+
+
+@case("delta_rule/scalar-decay-shared-keys-cell")
+def _():
+    _delta_rule_cell_case()
+
+
+@case("delta_rule/scalar-decay-shared-keys-cell-highest")
+def _():
+    # a suite or a user under ``highest``: Mosaic refuses float32 passes
+    # over bfloat16 operands, and the kernels' sums name their precision
+    _delta_rule_cell_case(precision="highest")
+
+
 # --- layer norm --------------------------------------------------------------
 
 def _ln_case(n, h, dtype=jnp.float32, atol=1e-4):

@@ -1,4 +1,5 @@
-"""Gated delta rule with a decay for each key channel, in chunks.
+"""Gated delta rule with a decay for each key channel (or one a head), in
+chunks.
 
 The recurrence of Kimi Delta Attention (arXiv:2510.26692), per head, with
 a state ``S`` of ``(d_k, d_v)`` that starts at zero::
@@ -565,19 +566,32 @@ _scan.defvjp(_scan_fwd, _scan_bwd)
 def gated_delta_rule(q, k, v, g, beta):
     """``o_t = S_t^T q_t`` of the recurrence above, for every token.
 
-    ``q, k, g``: ``(B, T, H, d_k)``; ``v``: ``(B, T, H, d_v)``; ``beta``:
-    ``(B, T, H)``. ``g`` is the log of the decay (``<= 0``), a value for
-    each key channel. The caller normalises and scales ``q`` and ``k``.
-    Returns ``(B, T, H, d_v)`` in float32. Any length: a sequence is padded
-    to whole chunks with tokens that leave the state as it is.
+    ``q, k``: ``(B, T, H_k, d_k)``; ``v``: ``(B, T, H, d_v)``; ``beta``:
+    ``(B, T, H)``. ``g`` is the log of the decay (``<= 0``): ``(B, T, H,
+    d_k)``, a value for each key channel (KDA), or ``(B, T, H)``, one value a
+    head (gated DeltaNet: ``Diag(exp(g_t))`` a multiple of the identity).
+    ``H_k`` divides ``H``: key head ``j`` serves the value heads ``j H / H_k
+    ... (j + 1) H / H_k - 1``. The caller normalises and scales ``q`` and
+    ``k``. Returns ``(B, T, H, d_v)`` in float32. Any length: a sequence is
+    padded to whole chunks with tokens that leave the state as it is.
+
+    What the op sees of the shapes picks the form, no argument does. A
+    decay a head and shared key heads reach the per-channel form (and its
+    kernels) as what they are short for: ``g`` broadcast over the key
+    channels, ``q`` and ``k`` repeated a group. Exact, and the cotangents
+    come back summed by the broadcast's own transpose.
     """
+    h = v.shape[2]
+    if q.shape[2] != h:
+        q, k = (jnp.repeat(x, h // x.shape[2], 2) for x in (q, k))
+    if g.ndim == 3:
+        g = jnp.broadcast_to(g[..., None], (*g.shape, k.shape[-1]))
     t = q.shape[1]
     pad = -t % CHUNK
     if pad:
         q, k, v, g, beta = (
             jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2))
             for x in (q, k, v, g, beta))
-    h = q.shape[2]
     if (h > HEAD_GROUP and h % HEAD_GROUP == 0
             and not _tiled(q.shape[-1], v.shape[-1])):
         grouped = lambda x: jnp.moveaxis(x.reshape(
@@ -592,17 +606,22 @@ def gated_delta_rule(q, k, v, g, beta):
 
 def gated_delta_rule_reference(q, k, v, g, beta):
     """The recurrence as written, one ``lax.scan`` step a token: the oracle
-    of the tests."""
+    of the tests. ``g`` a key channel or a head, ``q`` and ``k`` with the
+    value heads or with a divisor of them, as the op takes them."""
     f32 = lambda x: jnp.swapaxes(x.astype(jnp.float32), 0, 1)
+    b, _, h, dv = v.shape
+    dk = q.shape[-1]
+    # value head i reads key head i // group
+    key_head = jnp.arange(h) // (h // q.shape[2])
 
     def step(state, xs):
         q, k, v, g, beta = xs
-        state = state * jnp.exp(g)[..., None]
+        q, k = q[:, key_head], k[:, key_head]
+        state = state * jnp.exp(g).reshape(b, h, -1, 1)
         u = (v - jnp.einsum("bhkv,bhk->bhv", state, k)) * beta[..., None]
         state = state + k[..., None] * u[..., None, :]
         return state, jnp.einsum("bhkv,bhk->bhv", state, q)
 
-    b, _, h, dk = q.shape
-    zero = jnp.zeros((b, h, dk, v.shape[-1]), jnp.float32)
+    zero = jnp.zeros((b, h, dk, dv), jnp.float32)
     out = lax.scan(step, zero, tuple(map(f32, (q, k, v, g, beta))))[1]
     return jnp.swapaxes(out, 0, 1)

@@ -37,17 +37,21 @@ import jax.numpy as jnp
 CAPACITY_FACTOR = 8.0
 
 
-def route(x, router, bias, top_k, scale):
-    """Sigmoid scores over every expert, the ``top_k`` of ``score + bias``
-    chosen, weights ``scale * score / sum of the chosen scores``. ``x``
-    ``(T, D)``, ``router`` ``(D, E)``; float32 throughout (``moe_router``
-    is a FLOAT op). Returns ``chosen (T, k)`` int32, ``weights (T, k)``."""
+def route(x, router, bias, top_k, scale, scoring="sigmoid"):
+    """Scores over every expert (``scoring``: ``"sigmoid"``, each expert's
+    own, or ``"softmax"``, over all of them), the ``top_k`` of ``score +
+    bias`` chosen (``bias`` None: of the score), weights ``scale * score /
+    sum of the chosen scores``. ``x`` ``(T, D)``, ``router`` ``(D, E)``;
+    float32 throughout (``moe_router`` is a FLOAT op). Returns ``chosen
+    (T, k)`` int32, ``weights (T, k)``."""
+    score = {"sigmoid": jax.nn.sigmoid, "softmax": jax.nn.softmax}[scoring]
     with jax.named_scope("moe/route"):
         logits = jax.lax.dot_general(
             x.astype(jnp.float32), router.astype(jnp.float32),
             (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        scores = jax.nn.sigmoid(logits)
+        scores = score(logits)
         _, chosen = jax.lax.top_k(
+            scores if bias is None else
             scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), top_k)
         picked = jnp.take_along_axis(scores, chosen, -1)
         weights = scale * picked / (jnp.sum(picked, -1, keepdims=True) + 1e-20)

@@ -113,6 +113,68 @@ class TestFlashAttention:
                                    atol=2e-5)
 
 
+def grouped_oracle(q, k, v, causal):
+    """Dense softmax attention with q head ``i`` reading k/v head ``i //
+    (H / H_kv)``, the mapping written out a head."""
+    h, hkv, d = q.shape[2], k.shape[2], q.shape[3]
+    out = []
+    for i in range(h):
+        j = i // (h // hkv)
+        s_ = jnp.einsum("bqd,bkd->bqk", q[:, :, i], k[:, :, j]) / np.sqrt(d)
+        if causal:
+            s_ = jnp.where(np.tril(np.ones(s_.shape[-2:], bool)), s_, -1e30)
+        out.append(jnp.einsum("bqk,bkd->bqd", jax.nn.softmax(s_, -1),
+                              v[:, :, j]))
+    return jnp.stack(out, 2)
+
+
+class TestGroupedQueryAndHead256:
+    """k and v with fewer heads than q (a divisor), and the head size of
+    256 that Qwen3-Next's attention has: forward and all three gradients,
+    ``dk`` and ``dv`` summed over a group and in k's own shape."""
+
+    @pytest.mark.parametrize("causal", [False, True])
+    @pytest.mark.parametrize("h,hkv,d,s,blocks", [
+        (4, 2, 32, 96, None), (8, 1, 64, 200, None),
+        (16, 2, 256, 160, None),        # the cell's heads, one block
+        (8, 1, 256, 300, (128, 128)),   # several blocks: the two-kernel form
+        (2, 2, 256, 200, None),         # head 256, every head its own k/v
+    ])
+    def test_matches_a_dense_oracle(self, h, hkv, d, s, blocks, causal):
+        rng = np.random.RandomState(8)
+        q = jnp.asarray(rng.randn(2, s, h, d).astype(np.float32))
+        k, v = (jnp.asarray(rng.randn(2, s, hkv, d).astype(np.float32))
+                for _ in range(2))
+        kw = dict(zip(("block_q", "block_k"), blocks)) if blocks else {}
+        ours = lambda q, k, v: A.flash_attention(q, k, v, causal=causal, **kw)
+        want = grouped_oracle(q, k, v, causal)
+        np.testing.assert_allclose(np.asarray(ours(q, k, v)),
+                                   np.asarray(want), atol=3e-5)
+        np.testing.assert_allclose(
+            np.asarray(A.attention_reference(q, k, v, causal=causal)),
+            np.asarray(want), atol=3e-5)
+        loss = lambda fn: lambda *a: jnp.sum(jnp.sin(fn(*a)))
+        got = jax.grad(loss(ours), argnums=(0, 1, 2))(q, k, v)
+        ref = jax.grad(loss(lambda q, k, v: grouped_oracle(q, k, v, causal)),
+                       argnums=(0, 1, 2))(q, k, v)
+        for a, e, x, name in zip(got, ref, (q, k, v), "qkv"):
+            assert a.shape == x.shape, name
+            np.testing.assert_allclose(np.asarray(a), np.asarray(e),
+                                       atol=2e-4, err_msg=f"d{name}")
+
+    def test_bf16_grouped(self):
+        rng = np.random.RandomState(9)
+        q = jnp.asarray(rng.randn(1, 128, 16, 256), jnp.bfloat16)
+        k, v = (jnp.asarray(rng.randn(1, 128, 2, 256), jnp.bfloat16)
+                for _ in range(2))
+        got = A.flash_attention(q, k, v, None, 1 / 16, True)
+        assert got.dtype == jnp.bfloat16 and got.shape == q.shape
+        want = grouped_oracle(*(x.astype(jnp.float32) for x in (q, k, v)),
+                              True)
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want), atol=3e-2)
+
+
 class TestMHAModules:
     @pytest.mark.parametrize("norm_add", [False, True])
     def test_self_attn_fast_vs_default(self, norm_add):
@@ -650,6 +712,12 @@ MIB = 2 ** 20
     pytest.param(dict(batch=4, s=2048, d=64, nh=16, itemsize=2,
                       dropout_rate=0.1),
                  (512, 512, 8, None, "two_kernel"), id="dropout-capped"),
+    # Qwen3-Next's gated attention: at d = 256 a head is two whole lane
+    # tiles (g0 = 1), and the default 1024 x 1024 tiles that the compiler
+    # refused at d = 192 (g0 = 2) fit the raised limit with one head a step
+    pytest.param(dict(batch=1, s=8192, d=256, nh=16, itemsize=2),
+                 (1024, 1024, 1, 32 * MIB, "two_kernel_raised"),
+                 id="qwen3-next-d256-raised"),
 ])
 def test_backward_plan(shape, want):
     kw = dict(shape)

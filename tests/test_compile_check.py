@@ -48,3 +48,17 @@ def test_failure_is_reported(tmp_path, monkeypatch):
     data = json.loads(out.read_text())
     assert data["n_failed"] == 1
     assert "intentional" in data["results"][0]["error"]
+
+
+def test_the_qwen3_next_cell_cases_run_at_a_small_size():
+    """``attention/gqa-d256-cell`` and ``delta_rule/scalar-decay-shared-
+    keys-cell`` (and its ``highest`` twin) hold the cell's heads; on the chip
+    they run at its 8192 tokens, here interpreted at a few chunks."""
+    names = [n for n, _ in cc.CASES]
+    for name in ("attention/gqa-d256-cell",
+                 "delta_rule/scalar-decay-shared-keys-cell",
+                 "delta_rule/scalar-decay-shared-keys-cell-highest"):
+        assert name in names
+    cc._gqa_cell_case(t=256, prefix=128)
+    cc._delta_rule_cell_case(t=192, prefix=64)
+    cc._delta_rule_cell_case(t=128, prefix=64, precision="highest")

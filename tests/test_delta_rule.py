@@ -2,7 +2,8 @@
 recurrence it stands for, one step a token: outputs and every gradient, at
 lengths that are and are not whole chunks, from a decay that hardly decays
 to one (``g = -5`` a step) whose ``exp(-sum g)`` passes float32 inside a
-chunk."""
+chunk; with a decay for each key channel (KDA) and with one a head and key
+heads shared by pairs of value heads (gated DeltaNet)."""
 
 import jax
 import jax.numpy as jnp
@@ -139,3 +140,65 @@ def test_float32_inside_whatever_comes_in(dim):
     ref = gated_delta_rule_reference(*args)
     assert float(jnp.max(jnp.abs(out - ref))) <= 2e-6   # no half inside
     assert amp.lists.classify("gated_delta_rule") == "float"
+
+
+def shared(args, scalar=True, ratio=2):
+    """The inputs with one decay a head (its first channel's) and a key head
+    for every ``ratio`` value heads (every ``ratio``-th of them)."""
+    q, k, v, g, beta = args
+    return (q[:, :, ::ratio], k[:, :, ::ratio], v,
+            g[..., 0] if scalar else g, beta)
+
+
+@pytest.mark.parametrize("g_scale", [0.1, 5.0])
+@pytest.mark.parametrize("length", [64, 100])
+@pytest.mark.parametrize("dim", [16, 128])
+def test_scalar_decay_and_shared_key_heads_equal_recurrent(dim, length,
+                                                           g_scale):
+    """``g`` of ``(B, T, H)`` and 2 value heads a key head: the chunked form
+    (``dim`` 16) and the kernels (128, interpreted) against the recurrence,
+    output and all five gradients, in the shapes they came in."""
+    args = shared(inputs(length, 1, length, 4, dim, dim, g_scale))
+    assert args[0].shape[2] == 2 and args[3].shape == (1, length, 4)
+    if dim == 128:
+        assert "/apex_kda_fwd/" in jax.jit(gated_delta_rule).lower(
+            *args).as_text(debug_info=True)
+    out, ref = gated_delta_rule(*args), gated_delta_rule_reference(*args)
+    assert out.shape == ref.shape == (1, length, 4, dim)
+    assert bool(jnp.all(jnp.isfinite(out)))
+    assert float(jnp.max(jnp.abs(out - ref))) <= 2e-6
+    got = jax.grad(weighted(gated_delta_rule), argnums=range(5))(*args)
+    want = jax.grad(weighted(gated_delta_rule_reference),
+                    argnums=range(5))(*args)
+    for name, x, a, b in zip("q k v g beta".split(), args, got, want):
+        assert a.shape == x.shape, name
+        assert bool(jnp.all(jnp.isfinite(a))), name
+        assert float(jnp.max(jnp.abs(a - b))) <= 5e-5 * float(
+            jnp.max(jnp.abs(b))), name
+
+
+@pytest.mark.parametrize("dim", [16, 128])
+def test_scalar_path_equals_per_channel_path_fed_a_broadcast(dim):
+    """A decay a head is the per-channel form's with every channel alike,
+    shared key heads are that form's repeated: to the bit in the output, and
+    the cotangents are the broadcast's summed."""
+    full = inputs(5, 1, 100, 4, dim, dim, 1.0)
+    q, k, v, g, beta = shared(full)
+    wide = (jnp.repeat(q, 2, 2), jnp.repeat(k, 2, 2), v,
+            jnp.broadcast_to(g[..., None], v.shape[:3] + (dim,)), beta)
+    assert bool(jnp.all(gated_delta_rule(q, k, v, g, beta)
+                        == gated_delta_rule(*wide)))
+    got = jax.grad(weighted(gated_delta_rule), argnums=range(5))(
+        q, k, v, g, beta)
+    want = jax.grad(weighted(gated_delta_rule), argnums=range(5))(*wide)
+    pairs = lambda x: x.reshape(*x.shape[:2], 2, 2, -1).sum(3)
+    for a, b in zip(got, (pairs(want[0]), pairs(want[1]), want[2],
+                          want[3].sum(-1), want[4])):
+        assert float(jnp.max(jnp.abs(a - b))) <= 1e-6 * max(
+            1.0, float(jnp.max(jnp.abs(b))))
+    # each alone: shared heads with a decay a channel, a decay a head with
+    # every head its own keys
+    for args in (shared(full, scalar=False), shared(full, ratio=1)):
+        assert float(jnp.max(jnp.abs(
+            gated_delta_rule(*args)
+            - gated_delta_rule_reference(*args)))) <= 2e-6

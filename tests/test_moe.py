@@ -82,13 +82,17 @@ def test_exact_for_any_routing(kind):
             1.0, float(jnp.max(jnp.abs(b))))
 
 
-def test_the_shares_add_up():
+@pytest.mark.parametrize("scoring", ["sigmoid", "softmax"])
+def test_the_shares_add_up(scoring):
     """16 experts in 4 shares of 4: the four ranks' parts, summed, are the
-    uncut layer's routed part (a shared expert would be counted once)."""
+    uncut layer's routed part (a shared expert would be counted once), for
+    sigmoid scores with a selection bias and for a softmax over all 16."""
     E = 16
     x, w_gate, w_up, w_down = weights(1, E)
     router = jax.random.normal(jax.random.PRNGKey(2), (D, E)) * 0.3
-    chosen, w = moe.route(x, router, jnp.zeros(E), K, 2.446)
+    chosen, w = (moe.route(x, router, jnp.zeros(E), K, 2.446)
+                 if scoring == "sigmoid" else
+                 moe.route(x, router, None, 4, 1.0, "softmax"))
     whole = plain(x, w, chosen, w_gate, w_up, w_down, tuple(range(E)))
     parts = []
     for rank in range(4):
@@ -102,6 +106,55 @@ def test_the_shares_add_up():
     all_held = moe.held_experts(x, w, chosen, w_gate, w_up, w_down,
                                 tuple(range(E)), E)
     assert float(jnp.max(jnp.abs(all_held - whole))) <= 1e-5
+
+
+def test_the_shares_of_a_layer_with_a_gated_shared_expert_add_up():
+    """``models.ExpertFFN`` as Qwen3-Next builds it (softmax over 16, 4
+    chosen, a gate on the shared expert): four shares of 4, each with the
+    same router and shared expert, minus the shared expert counted three
+    times too often, are the uncut layer."""
+    from apex_tpu import models
+    E, k = 16, 4
+    layer = lambda held: models.ExpertFFN(D, F, E, k, held, 1.0, "softmax",
+                                          True)
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 40, D))
+    p = layer(tuple(range(E))).init(jax.random.PRNGKey(1), x)["params"]
+    p = {**p, "router": p["router"] * 30}      # scores that tell experts apart
+    assert "e_bias" not in p and p["shared_gate"]["kernel"].shape == (D, 1)
+    whole, load = layer(tuple(range(E))).apply({"params": p}, x)
+    assert int(load.sum()) == 2 * 40 * k
+    shared_only = models.SwiGLU(D, F).apply(
+        {"params": p["shared"]}, x) * jax.nn.sigmoid(
+            x @ p["shared_gate"]["kernel"])
+    assert float(jnp.max(jnp.abs(shared_only))) > 1e-3
+    total = 0.0
+    for rank in range(4):
+        held = tuple(range(4 * rank, 4 * rank + 4))
+        mine = {**p, **{n: p[n][4 * rank:4 * rank + 4] for n in (
+            "experts_gate", "experts_up", "experts_down")}}
+        part, load = layer(held).apply({"params": mine}, x)
+        assert load.shape == (4,)
+        total = total + part
+    assert float(jnp.max(jnp.abs(total - 3 * shared_only - whole))) <= 1e-5
+
+
+def test_softmax_route_normalises_over_the_chosen():
+    x, *_ = weights(3, 1)
+    router = jax.random.normal(jax.random.PRNGKey(4), (D, E))
+    policy = amp.Policy.from_opt_level("O1")
+    with amp.auto_cast(policy):
+        chosen, w = moe.route(x.astype(jnp.bfloat16), router, None, 10, 1.0,
+                              "softmax")
+    assert chosen.shape == w.shape == (T, 10)
+    assert chosen.dtype == jnp.int32 and w.dtype == jnp.float32
+    probs = jax.nn.softmax(
+        x.astype(jnp.bfloat16).astype(jnp.float32) @ router, -1)
+    top, ids = jax.lax.top_k(probs, 10)
+    assert bool(jnp.all(ids == chosen))
+    assert float(jnp.max(jnp.abs(w - top / top.sum(-1, keepdims=True)))) <= 1e-6
+    assert float(jnp.max(jnp.abs(w.sum(-1) - 1.0))) <= 1e-6
+    with pytest.raises(KeyError):
+        moe.route(x, router, None, 2, 1.0, "tanh")
 
 
 def test_route_scores_every_expert_in_float32():

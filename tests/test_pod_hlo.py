@@ -532,3 +532,63 @@ def test_kda_kernels_compile_for_the_v5e_at_the_cell_s_shape(
     for kernel in ("apex_kda_fwd", "apex_kda_bwd"):
         assert f"({kernel})" in text or f"/{kernel}/" in text, kernel
     assert "triangular-solve" not in text and "while" not in text
+
+
+def test_gated_attention_compiles_for_the_v5e_at_the_cell_s_shape(
+        v5e_chip, monkeypatch):
+    """``qwen3_next.lm_s8192_b1_v19k``'s attention: 16 q heads on 2 k/v heads
+    of 256, causal, 8192 tokens, forward and both backward kernels through
+    Mosaic at the op's own tiles. At a head of two whole lane tiles a head
+    goes through alone, and the default 1024 x 1024 tiles that the compiler
+    refuses at d = 192 fit (the backward under its raised VMEM limit);
+    1024 x 512 and 512 x 512 do not (16.08 and 17.74 MiB of 16, PR 32)."""
+    from apex_tpu import ops
+    from apex_tpu.ops import _dispatch, attention
+    for mod in (_dispatch, attention):
+        monkeypatch.setattr(mod, "use_interpret", lambda: False)
+    q = jax.ShapeDtypeStruct((1, 8192, 16, 256), jnp.bfloat16,
+                             sharding=v5e_chip)
+    kv = jax.ShapeDtypeStruct((1, 8192, 2, 256), jnp.bfloat16,
+                              sharding=v5e_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(ops.flash_attention(
+            q, k, v, None, 1 / 16, True).astype(jnp.float32))
+
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            q, kv, kv).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3      # forward, dq, dk/dv
+    for kernel in ("apex_attn_fwd", "apex_attn_bwd_dq", "apex_attn_bwd_dkv"):
+        assert f"({kernel})" in text or f"/{kernel}/" in text, kernel
+    dk, dv = compiled.out_info[1:]
+    assert dk.shape == dv.shape == (1, 8192, 2, 256)
+
+
+@pytest.mark.parametrize("dtype,precision", [
+    (jnp.float32, "default"), (jnp.bfloat16, "default"),
+    (jnp.float32, "highest")])
+def test_kda_kernels_take_a_scalar_decay_and_shared_key_heads_for_the_v5e(
+        v5e_chip, monkeypatch, dtype, precision):
+    """``qwen3_next.lm_s8192_b1_v19k``'s delta rule: 16 key heads serving 32
+    value heads of 128, one decay a head, 8192 tokens: the two kernels of
+    the Kimi cell, fed the decay broadcast and the key heads repeated, and
+    the cotangents summed back into the shapes that came in."""
+    from apex_tpu.ops import _dispatch, delta_rule
+    monkeypatch.setattr(_dispatch, "use_interpret", lambda: False)
+    shape = lambda *s, dt=jnp.float32: jax.ShapeDtypeStruct(
+        s, dt, sharding=v5e_chip)
+    qk = shape(1, 8192, 16, 128, dt=dtype)
+    args = (qk, qk, shape(1, 8192, 32, 128, dt=dtype),
+            shape(1, 8192, 32), shape(1, 8192, 32))
+    loss = lambda *a: jnp.sum(delta_rule.gated_delta_rule(*a))
+    with jax.default_matmul_precision(precision):
+        compiled = jax.jit(jax.grad(loss, argnums=range(5))).lower(
+            *args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    for kernel in ("apex_kda_fwd", "apex_kda_bwd"):
+        assert f"({kernel})" in text or f"/{kernel}/" in text, kernel
+    assert "triangular-solve" not in text and "while" not in text
+    assert [o.shape for o in compiled.out_info] == [a.shape for a in args]
