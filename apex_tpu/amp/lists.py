@@ -40,8 +40,9 @@ FLOAT_OPS = {
     "sum", "mean", "prod", "cumsum", "cumprod", "var", "std", "norm",
     "sigmoid_focal_loss", "renorm", "softplus", "gelu_exact",
     # ops/delta_rule.py: the recurrence's state, decay and triangular solve;
-    # ops/moe.py: the router's scores, the choice and the weights
-    "gated_delta_rule", "moe_router",
+    # ops/moe.py: the router's scores, the choice and the weights;
+    # ops/short_conv.py: the delta-rule layers' convolution, SiLU and l2-norm
+    "gated_delta_rule", "moe_router", "short_conv",
     # models/qwen3_next.py: the rotation of q and k by position (angles up
     # to the context length: bfloat16 holds 8 bits of them)
     "rotary",

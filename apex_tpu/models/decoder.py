@@ -82,19 +82,6 @@ def _conv_init(key, shape, dtype=jnp.float32):
     return jax.random.uniform(key, shape, dtype, -bound, bound)
 
 
-def _short_conv(x, taps):
-    """Causal depthwise convolution over the ``len(taps)`` newest tokens,
-    then SiLU. ``x`` ``(B, T, C)``, ``taps`` ``(K, C)``, newest last."""
-    k, t = taps.shape[0], x.shape[1]
-    padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (k - 1, 0), (0, 0)))
-    return jax.nn.silu(sum(padded[:, j:j + t] * taps[j] for j in range(k)))
-
-
-def _l2_normalised(x):
-    x = x.astype(jnp.float32)
-    return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), -1, keepdims=True) + 1e-6)
-
-
 class SwiGLU(nn.Module):
     hidden: int
     width: int

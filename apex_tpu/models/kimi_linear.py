@@ -29,9 +29,9 @@ import jax.numpy as jnp
 
 from apex_tpu import ops
 from apex_tpu.models.decoder import (
-    Decoder, ExpertFFN, RMSNorm, _conv_init, _dense, _init, _l2_normalised,
-    _short_conv, lm_loss)
+    Decoder, ExpertFFN, RMSNorm, _conv_init, _dense, _init, lm_loss)
 from apex_tpu.ops.delta_rule import gated_delta_rule
+from apex_tpu.ops.short_conv import short_conv
 
 #: (block_q, block_k) of MLA's attention. The kernels' VMEM ledger was
 #: fitted at a head size of 64: at 192 the v5e's compiler refuses their
@@ -67,12 +67,14 @@ class KimiDeltaAttention(nn.Module):
         with jax.named_scope("kda/proj"):
             qkv = [_dense(h * d, n)(x) for n in ("q_proj", "k_proj", "v_proj")]
         with jax.named_scope("kda/conv"):
+            # heads side by side, (B, T, H d), as the scan reads them:
+            # q's heads l2-normalised and scaled, k's normalised, v's plain
             q, k, v = (
-                heads(_short_conv(y, self.param(n, _conv_init,
-                                                (self.conv_size, h * d))))
-                for y, n in zip(qkv, ("q_conv", "k_conv", "v_conv")))
-            q = _l2_normalised(q) * d ** -0.5
-            k = _l2_normalised(k)
+                short_conv(y, self.param(n, _conv_init,
+                                         (self.conv_size, h * d)), norm, d)
+                for y, n, norm in zip(
+                    qkv, ("q_conv", "k_conv", "v_conv"),
+                    (((0, h * d, d ** -0.5),), ((0, h * d, 1.0),), ())))
         with jax.named_scope("kda/gate"):
             # the decay, a value for each key channel, in float32
             f_up = self.param("f_b", _init, (d, h * d), jnp.float32)
@@ -80,11 +82,11 @@ class KimiDeltaAttention(nn.Module):
             dt_bias = self.param("dt_bias", _dt_bias_init, (h * d,),
                                  jnp.float32)
             low = _dense(d, "f_a")(x).astype(jnp.float32)
-            g = -jnp.exp(a_log)[:, None] * heads(
-                jax.nn.softplus(low @ f_up + dt_bias))
+            g = -jnp.repeat(jnp.exp(a_log), d) * jax.nn.softplus(
+                low @ f_up + dt_bias)
             beta = jax.nn.sigmoid(_dense(h, "b_proj")(x).astype(jnp.float32))
             gate = _dense(h * d, "g_b")(_dense(d, "g_a")(x))
-        o = gated_delta_rule(q, k, v, g, beta)
+        o = gated_delta_rule(q, k, v, g, beta, head_dim=d)
         with jax.named_scope("kda/out"):
             o = RMSNorm(self.eps, name="o_norm")(o) * jax.nn.sigmoid(
                 heads(gate).astype(jnp.float32))

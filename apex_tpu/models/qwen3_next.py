@@ -32,9 +32,9 @@ import jax.numpy as jnp
 
 from apex_tpu import ops
 from apex_tpu.models.decoder import (
-    Decoder, ExpertFFN, RMSNorm, _conv_init, _dense, _l2_normalised,
-    _short_conv)
+    Decoder, ExpertFFN, RMSNorm, _conv_init, _dense)
 from apex_tpu.ops.delta_rule import gated_delta_rule
+from apex_tpu.ops.short_conv import short_conv
 
 
 def _a_log_init(key, shape, dtype=jnp.float32):
@@ -86,14 +86,16 @@ class GatedDeltaNet(nn.Module):
             qkvz = _dense(2 * n_qk + 2 * n_v, "qkvz_proj")(x)
             ba = _dense(2 * hv, "ba_proj")(x).astype(jnp.float32)
         with jax.named_scope("gdn/conv"):
-            # one convolution over q, k and v together
-            qkv = _short_conv(
-                qkvz[..., :2 * n_qk + n_v],
-                self.param("conv", _conv_init,
-                           (self.conv_size, 2 * n_qk + n_v)))
-            q = _l2_normalised(qkv[..., :n_qk].reshape(b, t, hk, dk)) * dk ** -0.5
-            k = _l2_normalised(qkv[..., n_qk:2 * n_qk].reshape(b, t, hk, dk))
-            v = qkv[..., 2 * n_qk:].reshape(b, t, hv, dv)
+            # one convolution over q, k and v together (the projection's
+            # leading channels: z's are not read), heads side by side as
+            # the scan reads them: q's heads l2-normalised and scaled, k's
+            # normalised, v's plain
+            qkv = short_conv(
+                qkvz, self.param("conv", _conv_init,
+                                 (self.conv_size, 2 * n_qk + n_v)),
+                ((0, n_qk, dk ** -0.5), (n_qk, 2 * n_qk, 1.0)), dk)
+            q, k, v = (qkv[..., :n_qk], qkv[..., n_qk:2 * n_qk],
+                       qkv[..., 2 * n_qk:])
         with jax.named_scope("gdn/gate"):
             # the decay, one value a value head, in float32
             a_log = self.param("A_log", _a_log_init, (hv,), jnp.float32)
@@ -102,7 +104,7 @@ class GatedDeltaNet(nn.Module):
             beta = jax.nn.sigmoid(ba[..., :hv])
             g = -jnp.exp(a_log) * jax.nn.softplus(ba[..., hv:] + dt_bias)
         with jax.named_scope("gdn/scan"):
-            o = gated_delta_rule(q, k, v, g, beta)
+            o = gated_delta_rule(q, k, v, g, beta, head_dim=dk)
         with jax.named_scope("gdn/out"):
             z = qkvz[..., 2 * n_qk + n_v:].reshape(b, t, hv, dv)
             o = RMSNorm(self.eps, name="o_norm")(o) * jax.nn.silu(

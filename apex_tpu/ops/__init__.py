@@ -43,6 +43,7 @@ from apex_tpu.ops.delta_rule import (
     gated_delta_rule_reference,
 )
 from apex_tpu.ops import moe
+from apex_tpu.ops import short_conv     # the module: short_conv.short_conv
 from apex_tpu.ops import autotune
 from apex_tpu.ops._dispatch import KEPT_ATTN, KEPT_KDA, KEPT_NAMES
 
@@ -57,6 +58,6 @@ __all__ = [
     "FusedBNAct", "bn_act_reference", "bn_act_train", "bn_add_act_train",
     "flash_attention", "attention_reference", "mask_softmax_dropout",
     "SelfMultiheadAttn", "EncdecMultiheadAttn",
-    "gated_delta_rule", "gated_delta_rule_reference", "moe",
+    "gated_delta_rule", "gated_delta_rule_reference", "moe", "short_conv",
     "KEPT_ATTN", "KEPT_KDA", "KEPT_NAMES",
 ]

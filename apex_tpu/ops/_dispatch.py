@@ -18,6 +18,7 @@ user calls the flax module it runs in.
 
 from __future__ import annotations
 
+import functools
 import os
 
 import jax
@@ -41,6 +42,8 @@ KERNEL_NAMES = (
     "apex_mlp_fwd",
     # delta_rule.py: a chunk's terms, state step and output, and their backward
     "apex_kda_fwd", "apex_kda_bwd",
+    # short_conv.py: convolution + SiLU + l2-norm in the scan's layout
+    "apex_short_conv_fwd", "apex_short_conv_bwd",
     # flat-buffer row kernels through launch(): multi_tensor, optim_kernels
     "apex_rows_scale", "apex_rows_axpby", "apex_rows_l2norm",
     "apex_rows_maxnorm", "apex_rows_adam", "apex_rows_sgd",
@@ -73,6 +76,55 @@ def pallas_call(kernel, *, name, **kwargs):
         raise ValueError(f"{name!r} is not in ops._dispatch.KERNEL_NAMES")
     return pl.pallas_call(kernel, name=name, interpret=use_interpret(),
                           **kwargs)
+
+
+def jit_launcher(fn=None, *, static_argnums=()):
+    """``jax.jit`` for a function that launches a kernel, so that the calls
+    of one shape share one trace and one lowered function (XLA inlines each
+    under its call site's own scope): a model's step holds a kernel once a
+    layer and again in a block's rerun, and tracing it anew each time is
+    seconds of every run's set-up. The trace is keyed on
+    :func:`use_interpret` as well, which ``pallas_call`` reads while it is
+    traced."""
+    if fn is None:
+        return functools.partial(jit_launcher, static_argnums=static_argnums)
+
+    def keyed(interpret, *args):
+        return fn(*args)
+    keyed.__name__ = keyed.__qualname__ = fn.__name__   # jit(<name>) scopes
+    jitted = jax.jit(keyed,
+                     static_argnums=(0, *(i + 1 for i in static_argnums)))
+    return functools.wraps(fn)(lambda *args: jitted(use_interpret(), *args))
+
+
+def kernel_calls(lowered_text):
+    """How often each kernel runs in a lowered step: ``lowered_text`` is
+    ``jax.jit(f).lower(...).as_text(debug_info=True)`` of a program lowered
+    for the TPU, and the result maps a name of :data:`KERNEL_NAMES` to its
+    calls from ``@main`` on. A kernel launched through a jitted wrapper
+    (``ops/delta_rule.py``, ``ops/short_conv.py``) is in the text once, in a
+    private function that every call site of that shape calls
+    (:func:`jit_launcher`): the calls are counted, not the functions."""
+    import collections
+    import re
+
+    own, calls, name = {}, {}, None
+    for line in lowered_text.splitlines():
+        started = re.match(r"\s*func\.func \w+ @([\w.$-]+)\(", line)
+        if started:
+            name = started.group(1)
+            own[name], calls[name] = collections.Counter(), []
+            continue
+        if name is None:
+            continue
+        own[name].update(re.findall(r'kernel_name = "(\w+)"', line))
+        calls[name] += re.findall(r"\bcall @([\w.$-]+)\(", line)
+
+    @functools.lru_cache(None)
+    def total(fn):
+        return sum((total(c) for c in calls.get(fn, ())),
+                   own.get(fn, collections.Counter()))
+    return total("main")
 
 
 # Rows per grid step for flat-buffer elementwise kernels. A (512, 128) fp32

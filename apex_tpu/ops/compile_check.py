@@ -512,6 +512,58 @@ def _():
     _delta_rule_cell_case(precision="highest")
 
 
+# --- short convolution -------------------------------------------------------
+
+def _short_conv_cell_case(wide, channels, norm, precision=None, t=8192):
+    """The delta-rule layers' convolution at a cell's shape (8192 tokens,
+    bfloat16 in as the projection hands it over under O1, the cell's
+    channels and normalised ranges): the two kernels against the
+    ``jax.numpy`` form, output and both gradients."""
+    from apex_tpu.ops.short_conv import short_conv, short_conv_reference
+    x = _rand((1, t, wide), 0, jnp.bfloat16)
+    taps = _rand((4, channels), 1, scale=0.3)
+    w = _rand((1, t, channels), 2)
+    both = lambda fn: jax.jit(lambda x, taps: (
+        fn(x, taps, norm, 128), jax.grad(lambda x, taps: jnp.sum(
+            fn(x, taps, norm, 128) * w), argnums=(0, 1))(x, taps)))(x, taps)
+    with (jax.default_matmul_precision(precision) if precision
+          else contextlib.nullcontext()):
+        out, (d_x, d_taps) = both(short_conv)
+    ref, (r_x, r_taps) = both(short_conv_reference)
+    assert out.shape == (1, t, channels) and out.dtype == jnp.float32
+    assert d_x.shape == x.shape and d_x.dtype == x.dtype
+    _rel("short conv fwd", out, ref, 1e-5)
+    # d x leaves as bfloat16 on both sides: a last bit of it, at most
+    _rel("short conv dx", d_x, r_x, 2 ** -7)
+    _rel("short conv dtaps", d_taps, r_taps, 1e-4)
+
+
+_KIMI_CONV = (4096, 4096, ((0, 4096, 128 ** -0.5),))
+# one convolution over q, k, v of a projection that holds z too
+_QWEN_CONV = (12288, 8192, ((0, 2048, 128 ** -0.5), (2048, 4096, 1.0)))
+
+
+@case("short_conv/kimi-cell")
+def _():
+    _short_conv_cell_case(*_KIMI_CONV)
+    _short_conv_cell_case(4096, 4096, ())       # v: no head normalised
+
+
+@case("short_conv/qwen-cell")
+def _():
+    _short_conv_cell_case(*_QWEN_CONV)
+
+
+@case("short_conv/kimi-cell-highest")
+def _():
+    _short_conv_cell_case(*_KIMI_CONV, precision="highest")
+
+
+@case("short_conv/qwen-cell-highest")
+def _():
+    _short_conv_cell_case(*_QWEN_CONV, precision="highest")
+
+
 # --- layer norm --------------------------------------------------------------
 
 def _ln_case(n, h, dtype=jnp.float32, atol=1e-4):

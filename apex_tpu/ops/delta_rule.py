@@ -68,7 +68,7 @@ import numpy as np
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
-from apex_tpu.ops._dispatch import KEPT_KDA, pallas_call
+from apex_tpu.ops._dispatch import KEPT_KDA, jit_launcher, pallas_call
 
 CHUNK = 64
 SUB = 16
@@ -399,14 +399,14 @@ def _tiled(dk, dv):
     return dk % 128 == 0 and dv % 128 == 0
 
 
-def _specs(q, v, reverse=False):
+def _specs(q, v, beta, reverse=False):
     """Grid and block specs of both kernels: ``(batch, heads, chunk)``, the
     chunks in order (``reverse``: last first); the model's ``(B, T, H d)``
     layout for tokens, chunk-major ``(N, B, H, ., .)`` for the rest."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    b, t, h, dk = q.shape
-    dv, chunks, c = v.shape[-1], t // CHUNK, CHUNK
+    b, t, h = beta.shape
+    dk, dv, chunks, c = q.shape[-1] // h, v.shape[-1] // h, t // CHUNK, CHUNK
     heads = HEADS_A_STEP if h % HEADS_A_STEP == 0 else 1
     at = (lambda n: chunks - 1 - n) if reverse else (lambda n: n)
     token = lambda d: pl.BlockSpec((None, c, heads * d),
@@ -415,7 +415,8 @@ def _specs(q, v, reverse=False):
                                       lambda b, h, n: (at(n), b, h, 0, 0))
     sums = _sum_matrix(c, heads)
     return dict(
-        heads=heads, grid=(b, h // heads, chunks), token=token, chunk=chunk,
+        heads=heads, dk=dk, dv=dv, grid=(b, h // heads, chunks), token=token,
+        chunk=chunk,
         inputs=[token(dk), token(dk), token(dv), token(dk),
                 pl.BlockSpec((None, c, h), lambda b, h, n: (b, at(n), 0))],
         per_chunk=lambda r, d: jax.ShapeDtypeStruct((chunks, b, h, r, d),
@@ -428,17 +429,15 @@ def _specs(q, v, reverse=False):
             dimension_semantics=("parallel", "parallel", "arbitrary")))
 
 
-def _flat(x):
-    """``(B, T, H, d)`` -> ``(B, T, H d)``: the head is a lane offset."""
-    return x.reshape(*x.shape[:2], -1)
+# Both launchers are jitted: a model's layers of one shape share one trace.
 
-
+@jit_launcher
 def _forward_kernel(q, k, v, g, beta):
-    """The op by the forward kernel: the output ``(B, T, H, d_v)``, the
-    chunk-start states ``(N, B, H, d_k, d_v)`` and ``(I + A)^-1`` ``(N, B, H,
-    C, C)``, both for the backward kernel."""
-    sp = _specs(q, v)
-    (b, t, h, dk), dv, c = q.shape, v.shape[-1], CHUNK
+    """The op by the forward kernel, ``q, k, v, g`` as ``(B, T, H d)``: the
+    output ``(B, T, H, d_v)``, the chunk-start states ``(N, B, H, d_k, d_v)``
+    and ``(I + A)^-1`` ``(N, B, H, C, C)``, both for the backward kernel."""
+    sp = _specs(q, v, beta)
+    (b, t, h), dk, dv, c = beta.shape, sp["dk"], sp["dv"], CHUNK
     out, states, inverse = pallas_call(
         functools.partial(_fwd_kernel, sp["heads"]),
         name="apex_kda_fwd", grid=sp["grid"],
@@ -447,14 +446,15 @@ def _forward_kernel(q, k, v, g, beta):
         out_shape=[jax.ShapeDtypeStruct((b, t, h * dv), jnp.float32),
                    sp["per_chunk"](dk, dv), sp["per_chunk"](c, c)],
         scratch_shapes=[sp["state"]], compiler_params=sp["params"],
-    )(*map(_flat, (q, k, v, g)), beta, sp["sums"])
+    )(q, k, v, g, beta, sp["sums"])
     return out.reshape(b, t, h, dv), states, inverse
 
 
+@jit_launcher
 def _backward_kernel(q, k, v, g, beta, states, inverse, d_out):
     """Cotangents of the op's inputs, in their layout and dtypes."""
-    sp = _specs(q, v, reverse=True)
-    (b, t, h, dk), dv, c = q.shape, v.shape[-1], CHUNK
+    sp = _specs(q, v, beta, reverse=True)
+    (b, t, h), dk, dv, c = beta.shape, sp["dk"], sp["dv"], CHUNK
     *grads, d_beta = pallas_call(
         functools.partial(_bwd_kernel, sp["heads"]),
         name="apex_kda_bwd", grid=sp["grid"],
@@ -462,14 +462,13 @@ def _backward_kernel(q, k, v, g, beta, states, inverse, d_out):
             sp["whole"](sp["sums"]), sp["whole"](sp["sums_t"]),
             sp["chunk"](dk, dv), sp["chunk"](c, c), sp["token"](dv)],
         out_specs=sp["inputs"][:4] + [sp["chunk"](c, 1)],
-        out_shape=[jax.ShapeDtypeStruct(_flat(x).shape, x.dtype)
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
                    for x in (q, k, v, g)] + [sp["per_chunk"](c, 1)],
         scratch_shapes=[sp["state"]], compiler_params=sp["params"],
-    )(*map(_flat, (q, k, v, g)), beta, sp["sums"], sp["sums_t"], states,
-      inverse, _flat(d_out))
+    )(q, k, v, g, beta, sp["sums"], sp["sums_t"], states, inverse,
+      d_out.reshape(b, t, h * dv))
     d_beta = jnp.transpose(d_beta[..., 0], (1, 0, 3, 2)).reshape(b, t, h)
-    return (*(d.reshape(x.shape) for d, x in zip(grads, (q, k, v, g))),
-            d_beta.astype(beta.dtype))
+    return (*grads, d_beta.astype(beta.dtype))
 
 
 def _chunk_step(state, w, ut, kg, decay):
@@ -524,7 +523,7 @@ def _forward(q, k, v, g, beta):
     from apex_tpu.amp.functional_patch import suspend
     with suspend():                     # float32 here whatever the policy
         with jax.named_scope("kda/scan"):
-            if _tiled(q.shape[-1], v.shape[-1]):
+            if q.ndim == 3:             # (B, T, H d): the kernels' layout
                 out, *kept = _forward_kernel(q, k, v, g, beta)
                 return out, tuple(kept)
             qg, kg, w, ut, aqk, decay = _prepared(q, k, v, g, beta)
@@ -563,7 +562,7 @@ def _scan_bwd(res, d_out):
 _scan.defvjp(_scan_fwd, _scan_bwd)
 
 
-def gated_delta_rule(q, k, v, g, beta):
+def gated_delta_rule(q, k, v, g, beta, head_dim=None):
     """``o_t = S_t^T q_t`` of the recurrence above, for every token.
 
     ``q, k``: ``(B, T, H_k, d_k)``; ``v``: ``(B, T, H, d_v)``; ``beta``:
@@ -575,25 +574,50 @@ def gated_delta_rule(q, k, v, g, beta):
     ``k``. Returns ``(B, T, H, d_v)`` in float32. Any length: a sequence is
     padded to whole chunks with tokens that leave the state as it is.
 
+    Or the heads side by side, as a projection writes them and the kernels
+    read them: ``q, k`` ``(B, T, H_k d_k)`` with ``head_dim = d_k`` stated,
+    ``v`` ``(B, T, H d_v)``, ``g`` ``(B, T, H d_k)`` or ``(B, T, H)``. For
+    head sizes the kernels take, operands in this layout reach them as they
+    are and their cotangents come back in it (``(B, T, H, d)`` is another
+    arrangement of the TPU's ``(8, 128)`` tiles: a copy each way). The
+    output is ``(B, T, H, d_v)`` either way.
+
     What the op sees of the shapes picks the form, no argument does. A
     decay a head and shared key heads reach the per-channel form (and its
     kernels) as what they are short for: ``g`` broadcast over the key
     channels, ``q`` and ``k`` repeated a group. Exact, and the cotangents
     come back summed by the broadcast's own transpose.
     """
-    h = v.shape[2]
-    if q.shape[2] != h:
-        q, k = (jnp.repeat(x, h // x.shape[2], 2) for x in (q, k))
-    if g.ndim == 3:
-        g = jnp.broadcast_to(g[..., None], (*g.shape, k.shape[-1]))
-    t = q.shape[1]
+    (b, t, h), flat = beta.shape, q.ndim == 3
+    dk = head_dim if flat else q.shape[-1]
+    dv = v.shape[-1] // h if flat else v.shape[-1]
+    one_a_head = g.ndim == 3 and g.shape[-1] == h
+    group = h * dk // (q.size // (b * t))    # value heads a key head
+    if _tiled(dk, dv):
+        # the kernels' layout, (B, T, H d). A head is a lane range there:
+        # the repeat and the broadcast are written as ranges side by side,
+        # because (B, T, H, group, d) is another arrangement of the tiles
+        q, k, v, g = (x.reshape(b, t, -1) for x in (q, k, v, g))
+        if group > 1:
+            q, k = (jnp.concatenate(
+                [x[..., i:i + dk] for i in range(0, x.shape[-1], dk)
+                 for _ in range(group)], -1) for x in (q, k))
+        if one_a_head:
+            g = jnp.concatenate([jnp.broadcast_to(g[..., i:i + 1], (b, t, dk))
+                                 for i in range(h)], -1)
+    else:
+        q, k = (jnp.broadcast_to(x.reshape(b, t, -1, 1, dk),
+                                 (b, t, h // group, group, dk))
+                .reshape(b, t, h, dk) for x in (q, k))
+        v = v.reshape(b, t, h, dv)
+        g = (jnp.broadcast_to(g[..., None], (b, t, h, dk)) if one_a_head
+             else g.reshape(b, t, h, dk))
     pad = -t % CHUNK
     if pad:
         q, k, v, g, beta = (
             jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2))
             for x in (q, k, v, g, beta))
-    if (h > HEAD_GROUP and h % HEAD_GROUP == 0
-            and not _tiled(q.shape[-1], v.shape[-1])):
+    if (h > HEAD_GROUP and h % HEAD_GROUP == 0 and q.ndim == 4):
         grouped = lambda x: jnp.moveaxis(x.reshape(
             *x.shape[:2], h // HEAD_GROUP, HEAD_GROUP, *x.shape[3:]), 2, 0)
         out = lax.map(lambda xs: _scan(*xs),

@@ -93,11 +93,13 @@ def main():
 
     cases = [(n, f, args) for n, f in op_cases("op")]
     if tiled(a.dim, a.dim):
-        out, states, inverse = jax.jit(dr._forward_kernel)(*args)
+        # the kernels' own layout: the heads side by side, (B, T, H d)
+        flat = (*(x.reshape(1, a.tokens, -1) for x in args[:4]), args[4])
+        out, states, inverse = jax.jit(dr._forward_kernel)(*flat)
         cases += [
-            ("apex_kda_fwd", dr._forward_kernel, args),
+            ("apex_kda_fwd", dr._forward_kernel, flat),
             ("apex_kda_bwd", dr._backward_kernel,
-             (*args, states, inverse, jnp.cos(out))),
+             (*flat, states, inverse, jnp.cos(out))),
         ]
     for name, fn, xs in cases:
         print(f"{name}: {measure(fn, xs, a.iters):.3f} ms", flush=True)

@@ -59,7 +59,7 @@ def test_kernels_equal_chunked_and_recurrent(length, g_scale, monkeypatch):
     args = inputs(length, batch, length, 2, 128, 128, g_scale)
     both = lambda fn: (fn(*args), jax.grad(weighted(fn),
                                            argnums=range(5))(*args))
-    assert "/apex_kda_fwd/" in jax.jit(gated_delta_rule).lower(
+    assert "apex_kda_fwd/" in jax.jit(gated_delta_rule).lower(
         *args).as_text(debug_info=True)
     out, got = both(gated_delta_rule)
     ref, want = both(gated_delta_rule_reference)
@@ -91,7 +91,8 @@ def test_strong_decay_forms_nothing_unbounded_in_the_kernel():
     and every term it forms on the way (the levels' decayed operands, the
     chunk's six terms), at g = -5 a step."""
     q, k, v, g, beta = inputs(0, 1, 128, 2, 128, 128, 5.0)
-    for term in delta_rule._forward_kernel(q, k, v, g, beta):
+    flat = lambda x: x.reshape(1, 128, -1)      # the kernels' (B, T, H d)
+    for term in delta_rule._forward_kernel(*map(flat, (q, k, v, g)), beta):
         assert bool(jnp.all(jnp.isfinite(term)))
         assert float(jnp.max(jnp.abs(term))) < 1e3
     chunk = lambda x: x[0, :64, 0]
@@ -161,7 +162,7 @@ def test_scalar_decay_and_shared_key_heads_equal_recurrent(dim, length,
     args = shared(inputs(length, 1, length, 4, dim, dim, g_scale))
     assert args[0].shape[2] == 2 and args[3].shape == (1, length, 4)
     if dim == 128:
-        assert "/apex_kda_fwd/" in jax.jit(gated_delta_rule).lower(
+        assert "apex_kda_fwd/" in jax.jit(gated_delta_rule).lower(
             *args).as_text(debug_info=True)
     out, ref = gated_delta_rule(*args), gated_delta_rule_reference(*args)
     assert out.shape == ref.shape == (1, length, 4, dim)
@@ -202,3 +203,37 @@ def test_scalar_path_equals_per_channel_path_fed_a_broadcast(dim):
         assert float(jnp.max(jnp.abs(
             gated_delta_rule(*args)
             - gated_delta_rule_reference(*args)))) <= 2e-6
+
+
+@pytest.mark.parametrize("decay", ["a_channel", "a_head"])
+@pytest.mark.parametrize("ratio", [1, 2], ids=["own_keys", "shared_keys"])
+@pytest.mark.parametrize("dim", [16, 128])
+def test_heads_side_by_side_equal_heads_apart(dim, ratio, decay):
+    """``(B, T, H d)`` operands with the head size stated, as a projection
+    writes them and the kernels read them, against the same values as ``(B,
+    T, H, d)``: the output to the bit and the gradients in the layout each
+    came in; with a decay a channel and a head, own and shared key heads;
+    in the kernels (``dim`` 128, where the flat operands reach
+    ``apex_kda_fwd`` untouched) and in the chunked form (16)."""
+    args = shared(inputs(7, 1, 100, 4, dim, dim, 0.5),
+                  scalar=decay == "a_head", ratio=ratio)
+    flat = lambda x: x.reshape(*x.shape[:2], -1) if x.ndim == 4 else x
+    side_by_side = tuple(map(flat, args))
+    assert side_by_side[0].shape == (1, 100, 4 // ratio * dim)
+    assert side_by_side[3].shape == (1, 100, 4 * (dim if decay == "a_channel"
+                                                  else 1))
+    fn = lambda *a: gated_delta_rule(*a, head_dim=dim)
+    out = fn(*side_by_side)
+    assert out.shape == (1, 100, 4, dim)
+    assert bool(jnp.all(out == gated_delta_rule(*args)))
+    loss = lambda f: lambda *a: jnp.sum(f(*a) * jnp.cos(jnp.arange(dim)))
+    got = jax.grad(loss(fn), argnums=range(5))(*side_by_side)
+    want = jax.grad(loss(gated_delta_rule), argnums=range(5))(*args)
+    for name, x, a, b in zip("q k v g beta".split(), side_by_side, got, want):
+        assert a.shape == x.shape, name
+        assert float(jnp.max(jnp.abs(a - flat(b)))) <= 1e-6 * max(
+            1.0, float(jnp.max(jnp.abs(b)))), name
+    if dim == 128:
+        traced = str(jax.make_jaxpr(fn)(*side_by_side))
+        before = traced.split("apex_kda_fwd")[0]
+        assert "reshape" not in before and "transpose" not in before

@@ -147,7 +147,6 @@ def test_kda_kernels_in_the_lowered_step(monkeypatch):
     layer (the rerun of a block keeps what the forward kernel wrote,
     ``ops.KEPT_NAMES``, and so holds no kernel) and no triangular solve, and
     its loss and gradients are those of the same model on the chunked form."""
-    import re
     from apex_tpu.ops import _dispatch, delta_rule
     config = {**TOY, "num_hidden_layers": 2, "linear_attn_config": {
         **TOY["linear_attn_config"], "head_dim": 128}}
@@ -163,9 +162,13 @@ def test_kda_kernels_in_the_lowered_step(monkeypatch):
         m.setattr(_dispatch, "use_interpret", lambda: False)
         text = step().trace(params).lower(
             lowering_platforms=("tpu",)).as_text(debug_info=True)
-    kernels = re.findall(r'kernel_name = "(\w+)"', text)
-    assert kernels.count("apex_kda_fwd") == 2
-    assert kernels.count("apex_kda_bwd") == 2
+    kernels = _dispatch.kernel_calls(text)
+    assert kernels["apex_kda_fwd"] == 2
+    assert kernels["apex_kda_bwd"] == 2
+    # q, k and v of each layer: the convolution's forward in the forward
+    # and in the rerun (its output is not kept), its backward once
+    assert kernels["apex_short_conv_fwd"] == 12
+    assert kernels["apex_short_conv_bwd"] == 6
     assert "triangular_solve" not in text
     loss, grads = step()(params)
     with monkeypatch.context() as m:
@@ -178,6 +181,53 @@ def test_kda_kernels_in_the_lowered_step(monkeypatch):
     for (path, got), want in zip(flat, jax.tree_util.tree_leaves(ref_grads)):
         assert float(jnp.linalg.norm(got - want)) <= 2e-5 * max(
             float(jnp.linalg.norm(want)), 1e-3), jax.tree_util.keystr(path)
+
+
+def _kernel_operands(jaxpr, kernel, made_by=None, found=None):
+    """For every ``pallas_call`` named ``kernel`` in ``jaxpr`` (calls inside
+    calls walked through), what made each of its operands: the name of the
+    ``pallas_call`` whose result it is, untouched, or of whatever primitive
+    came between."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr, Var
+    made_by = {} if made_by is None else made_by
+    found = [] if found is None else found
+    tag = lambda v: made_by.get(v) if isinstance(v, Var) else None
+    for eqn in jaxpr.eqns:
+        inner = [p for p in eqn.params.values()
+                 if isinstance(p, (Jaxpr, ClosedJaxpr))]
+        if eqn.primitive.name == "pallas_call":
+            name = eqn.params["name"]
+            if name == kernel:
+                found.append([tag(v) for v in eqn.invars])
+            made = [name] * len(eqn.outvars)
+        elif inner:                     # pjit, custom_vjp_call, checkpoint
+            sub = getattr(inner[0], "jaxpr", inner[0])
+            inside = dict(zip(sub.invars, map(tag, eqn.invars)))
+            _kernel_operands(sub, kernel, inside, found)
+            made = [inside.get(v) if isinstance(v, Var) else None
+                    for v in sub.outvars]
+        else:
+            made = [eqn.primitive.name] * len(eqn.outvars)
+        made_by.update(zip(eqn.outvars, made))
+    return found
+
+
+def test_the_scan_reads_what_the_convolution_wrote():
+    """At the published head size the scan's forward kernel takes q, k and
+    v from the convolution kernel as they are: no ``reshape``, ``transpose``
+    or anything else between the two calls in the traced layer (each would
+    be a copy under the TPU's tiling), and the decay per channel arrives as
+    ``(B, T, H d)`` from the gate's own arithmetic."""
+    from apex_tpu.models.kimi_linear import KimiDeltaAttention
+    layer = KimiDeltaAttention(hidden=64, heads=2, head_dim=128)
+    x = jnp.ones((1, 128, 64))
+    params = layer.init(jax.random.PRNGKey(0), x)
+    traced = jax.make_jaxpr(lambda p, x: layer.apply(p, x))(params, x)
+    calls = _kernel_operands(traced.jaxpr, "apex_kda_fwd")
+    assert len(calls) == 1
+    q, k, v, g = calls[0][:4]
+    assert q == k == v == "apex_short_conv_fwd", calls[0]
+    assert g == "mul", g
 
 
 def test_o1_model_is_near_the_reference(toy):

@@ -592,3 +592,82 @@ def test_kda_kernels_take_a_scalar_decay_and_shared_key_heads_for_the_v5e(
         assert f"({kernel})" in text or f"/{kernel}/" in text, kernel
     assert "triangular-solve" not in text and "while" not in text
     assert [o.shape for o in compiled.out_info] == [a.shape for a in args]
+
+
+@pytest.mark.parametrize("cell,wide,channels,norm", [
+    ("kimi_q", 4096, 4096, ((0, 4096, 128 ** -0.5),)),
+    ("kimi_v", 4096, 4096, ()),
+    ("qwen_qkv", 12288, 8192, ((0, 2048, 128 ** -0.5), (2048, 4096, 1.0))),
+])
+@pytest.mark.parametrize("dtype,precision", [
+    (jnp.bfloat16, "default"), (jnp.float32, "highest")])
+def test_short_conv_kernels_compile_for_the_v5e_at_the_cells_shapes(
+        v5e_chip, monkeypatch, cell, wide, channels, norm, dtype, precision):
+    """Both decoder cells' short convolution at 8192 tokens: Kimi's q (every
+    head normalised and scaled) and v (none) over 4096 channels, Qwen's one
+    call over the first 8192 channels of a 12288-wide projection (q scaled,
+    k, then v plain); bfloat16 in as under O1, and float32 under a suite's
+    ``highest``. What interpret mode cannot see: the reads from the float32
+    scratch at sublane offsets 13, 14, 15 (the taps' shifts), the bfloat16
+    ``(16, 128)`` tiles of the rows before and after a block, the ``(4,
+    C)`` block of the taps' cotangent, the scoped VMEM."""
+    from apex_tpu.ops import _dispatch
+    from apex_tpu.ops.short_conv import short_conv
+    monkeypatch.setattr(_dispatch, "use_interpret", lambda: False)
+    shape = lambda *s, dt=jnp.float32: jax.ShapeDtypeStruct(
+        s, dt, sharding=v5e_chip)
+    x, taps = shape(1, 8192, wide, dt=dtype), shape(4, channels)
+    weight = shape(1, 8192, channels)
+    loss = lambda x, taps, w: jnp.sum(short_conv(x, taps, norm, 128) * w)
+    with jax.default_matmul_precision(precision):
+        compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+            x, taps, weight).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    for kernel in ("apex_short_conv_fwd", "apex_short_conv_bwd"):
+        assert f"({kernel})" in text or f"/{kernel}/" in text, kernel
+    _, (d_x, d_taps) = compiled.out_info
+    assert d_x.shape == x.shape and d_x.dtype == dtype
+    assert d_taps.shape == taps.shape and d_taps.dtype == jnp.float32
+
+
+def test_the_scan_kernels_read_the_convolution_kernels_for_the_v5e(
+        v5e_chip, monkeypatch):
+    """One KDA layer at the published head size, compiled for the v5e: in
+    the optimised program ``apex_kda_fwd`` takes q, k and v from the three
+    ``apex_short_conv_fwd`` calls themselves and each ``apex_short_conv_bwd``
+    takes its cotangent from ``apex_kda_bwd`` itself: XLA puts no copy, no
+    ``(B, T, H, d)`` relayout and no fusion between the kernels."""
+    import re
+    from apex_tpu.models.kimi_linear import KimiDeltaAttention
+    from apex_tpu.ops import _dispatch
+    monkeypatch.setattr(_dispatch, "use_interpret", lambda: False)
+    layer = KimiDeltaAttention(hidden=256, heads=4, head_dim=128)
+    x = jnp.ones((1, 512, 256))
+    params = jax.eval_shape(lambda: layer.init(jax.random.PRNGKey(0), x))
+    placed = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e_chip),
+        (params, x))
+    loss = lambda p, x: jnp.sum(layer.apply(p, x) ** 2)
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(jax.value_and_grad(loss)).lower(
+            *placed).compile().as_text()
+    calls = {m[0]: (m[1], m[2]) for m in re.findall(
+        r"%((apex_\w+?)(?:\.\d+)?) = .*? custom-call\(([^)]*)\)", text)}
+    made_by = lambda operand: calls.get(operand.strip().lstrip("%"),
+                                        (operand,))[0]
+    # a tuple's element: %pallas_call.N = ... get-tuple-element(%call)
+    element = dict(re.findall(
+        r"%([\w.-]+) = \S+ get-tuple-element\(%([\w.]+)\)", text))
+    source = lambda operand: made_by(element.get(
+        operand.strip().lstrip("%"), operand))
+    forward = [ops for name, (kernel, ops) in calls.items()
+               if kernel == "apex_kda_fwd"]
+    assert len(forward) == 1
+    assert [source(o) for o in forward[0].split(",")[:3]] == [
+        "apex_short_conv_fwd"] * 3, forward
+    backward = [ops for name, (kernel, ops) in calls.items()
+                if kernel == "apex_short_conv_bwd"]
+    assert len(backward) == 3
+    for ops in backward:        # x, rows before, rows after, taps, d y, d y
+        assert source(ops.split(",")[4]) == "apex_kda_bwd", ops

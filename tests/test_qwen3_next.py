@@ -239,7 +239,6 @@ def test_kernels_in_the_lowered_step(monkeypatch):
     differentiated loss holds each forward kernel once and each backward
     kernel once a layer (three DeltaNet layers, one attention layer on the
     two-kernel or the fused backward), and no triangular solve."""
-    import re
     from apex_tpu.ops import _dispatch, attention
     config = {**TOY, "linear_key_head_dim": 128, "linear_value_head_dim": 128}
     model = models.qwen3_next_from_config(config, remat=True)
@@ -253,11 +252,15 @@ def test_kernels_in_the_lowered_step(monkeypatch):
             m.setattr(mod, "use_interpret", lambda: False)
         text = step.trace(params).lower(
             lowering_platforms=("tpu",)).as_text(debug_info=True)
-    kernels = re.findall(r'kernel_name = "(\w+)"', text)
-    assert kernels.count("apex_kda_fwd") == 3
-    assert kernels.count("apex_kda_bwd") == 3
-    assert kernels.count("apex_attn_fwd") == 1
-    assert sum(k.startswith("apex_attn_bwd") for k in kernels) in (1, 2)
+    kernels = _dispatch.kernel_calls(text)
+    assert kernels["apex_kda_fwd"] == 3
+    assert kernels["apex_kda_bwd"] == 3
+    # one convolution over q, k and v a layer: forward, rerun, backward
+    assert kernels["apex_short_conv_fwd"] == 6
+    assert kernels["apex_short_conv_bwd"] == 3
+    assert kernels["apex_attn_fwd"] == 1
+    assert sum(n for k, n in kernels.items()
+               if k.startswith("apex_attn_bwd")) in (1, 2)
     assert "triangular_solve" not in text
     for scope in ("gdn/scan", "gdn/conv", "gattn/rope", "gattn/attn",
                   "moe/route", "moe/shared", "lm/head"):
