@@ -1,11 +1,13 @@
 """apex_tpu.models — the model families the reference's examples/configs
 exercise (BASELINE.json): ResNet (imagenet example), DCGAN (multi-loss amp
 example), BERT-style transformer (FusedLAMB config), RNN stacks
-(`apex.RNN`), and two decoders over one shell (`decoder.py`: pre-norm blocks,
-routed experts at one expert-parallel rank's share, next-token loss):
+(`apex.RNN`), and three decoders over one shell (`decoder.py`: pre-norm
+blocks, routed experts at one expert-parallel rank's share, next-token loss):
 Kimi-Linear (delta-rule linear attention with a decay a channel, latent
-attention) and Qwen3-Next (gated DeltaNet with a decay a head and shared key
-heads, gated grouped-query attention with partial rotary, softmax router).
+attention), Qwen3-Next (gated DeltaNet with a decay a head and shared key
+heads, gated grouped-query attention with partial rotary, softmax router) and
+LFM2-MoE (gated short convolutions, grouped-query attention with rotary over
+the whole head behind per-head q/k norms, no shared expert, a tied head).
 """
 
 from apex_tpu.models.resnet import (
@@ -18,7 +20,7 @@ from apex_tpu.models.transformer import (
 )
 from apex_tpu.models.dcgan import Generator, Discriminator
 from apex_tpu.models.decoder import (
-    Block, Decoder, ExpertFFN, RMSNorm, SwiGLU, lm_loss,
+    Block, Decoder, ExpertFFN, RMSNorm, SwiGLU, lm_loss, partial_rotary,
 )
 from apex_tpu.models.kimi_linear import (
     KimiLinear, KimiLinearDims, KimiDeltaAttention, LatentAttention,
@@ -26,7 +28,11 @@ from apex_tpu.models.kimi_linear import (
 )
 from apex_tpu.models.qwen3_next import (
     Qwen3Next, Qwen3NextDims, GatedDeltaNet, GatedAttention,
-    partial_rotary, qwen3_next_from_config,
+    qwen3_next_from_config,
+)
+from apex_tpu.models.lfm2 import (
+    Lfm2Moe, Lfm2Dims, GatedShortConv, GroupedQueryAttention,
+    lfm2_moe_from_config,
 )
 
 __all__ = [
@@ -40,4 +46,6 @@ __all__ = [
     "kimi_linear_from_config",
     "Qwen3Next", "Qwen3NextDims", "GatedDeltaNet", "GatedAttention",
     "partial_rotary", "qwen3_next_from_config",
+    "Lfm2Moe", "Lfm2Dims", "GatedShortConv", "GroupedQueryAttention",
+    "lfm2_moe_from_config",
 ]

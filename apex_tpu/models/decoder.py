@@ -1,16 +1,20 @@
 """What the decoders share: the pre-norm block shell over a float32 residual
 stream, RMSNorm, SwiGLU, the expert layer of one expert-parallel rank, the
-short convolution of the delta-rule layers, the untied head and the
-next-token loss.
+short convolution's taps, the rotary embedding, the head (its own matrix or
+the embedding's) and the next-token loss.
 
 A decoder is :class:`Decoder` over a ``dims`` of its own (a frozen
-dataclass: ``models/kimi_linear.py``, ``models/qwen3_next.py``). The shell
-asks ``dims`` for what differs between them and holds no model's name:
+dataclass: ``models/kimi_linear.py``, ``models/qwen3_next.py``,
+``models/lfm2.py``). The shell asks ``dims`` for what differs between them
+and holds no model's name:
 
 ``dims.mixer(kind)``   the token mixer of a layer of that kind, a module
-                       named by its kind (``kda``, ``mla``, ``gdn``, ``gattn``);
+                       named by its kind (``kda``, ``mla``, ``gdn``,
+                       ``gattn``, ``lconv``, ``gqa``);
 ``dims.norm(name)``    the norm of the blocks and the final one;
 ``dims.experts()``     the :class:`ExpertFFN` of an expert layer;
+``dims.tied_head``     where it is there and true, the logits are ``h E^T``
+                       with the embedding ``E`` and there is no ``lm_head``;
 ``dims.hidden``, ``dims.vocab_size``, ``dims.dense_width``, ``dims.held``,
 ``dims.top_k``, ``dims.n_routed``.
 
@@ -23,7 +27,8 @@ this rank holds, and the expert weights are ``(len(held), ...)``. The layer
 routes every token over all ``n_routed`` experts, normalises the weights
 over all the chosen ones, and returns the shared expert plus the chosen
 experts *that are in* ``held``: what this rank adds to the all-reduced sum
-of a deployment (the shared expert is every rank's alike, counted once).
+of a deployment (the shared expert, where the model has one, is every
+rank's alike, counted once).
 With ``held = range(n_routed)`` it is the whole layer. No token is dropped
 for any routing (``ops/moe.py``).
 
@@ -77,6 +82,27 @@ class RMSNorm(nn.Module):
             jnp.mean(jnp.square(x), -1, keepdims=True) + self.eps) * scale
 
 
+def partial_rotary(x, rotary_dim, theta, positions=None):
+    """Rotary position embedding on the first ``rotary_dim`` channels of each
+    head, the rest left as they are. ``x`` ``(B, T, H, D)``; channel ``m <
+    rotary_dim / 2`` pairs with ``m + rotary_dim / 2`` (half-split) and turns
+    by ``p theta^(-2m / rotary_dim)`` at position ``p`` (``positions``
+    ``(T,)``, default ``0 ... T - 1``). In the policy's dtype for ``rotary``
+    (a FLOAT op): float32."""
+    from apex_tpu.amp.policy import current_policy
+    x = x.astype(current_policy().op_dtype("rotary", x.dtype))
+    half = rotary_dim // 2
+    if positions is None:
+        positions = jnp.arange(x.shape[1])
+    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2 / rotary_dim)
+    angle = positions.astype(jnp.float32)[:, None] * freq         # (T, half)
+    cos, sin = (f(angle)[None, :, None, :].astype(x.dtype)
+                for f in (jnp.cos, jnp.sin))
+    a, b = x[..., :half], x[..., half:rotary_dim]
+    return jnp.concatenate(
+        [a * cos - b * sin, b * cos + a * sin, x[..., rotary_dim:]], -1)
+
+
 def _conv_init(key, shape, dtype=jnp.float32):
     bound = shape[0] ** -0.5            # a depthwise Conv1d's default
     return jax.random.uniform(key, shape, dtype, -bound, bound)
@@ -100,7 +126,8 @@ class ExpertFFN(nn.Module):
     ``scoring``: ``"sigmoid"`` scores with a selection bias ``e_bias`` that
     no gradient moves, or ``"softmax"`` over all the experts and no bias
     (``ops.moe.route``). ``shared_gate``: the shared expert's output times
-    ``sigmoid(w_s^T x)``, one learned gate a token."""
+    ``sigmoid(w_s^T x)``, one learned gate a token. ``shared_width`` 0: no
+    shared expert."""
     hidden: int
     width: int
     n_routed: int
@@ -130,6 +157,8 @@ class ExpertFFN(nn.Module):
                                     self.scale, self.scoring)
         y = moe.held_experts(rows, weights, chosen, w_gate, w_up, w_down,
                              tuple(self.held), self.n_routed)
+        if self.shared_width == 0:
+            return y.reshape(x.shape), moe.expert_load(chosen, self.held)
         with jax.named_scope("moe/shared"):
             shared = SwiGLU(self.hidden, self.shared_width or self.width,
                             name="shared")(x)
@@ -168,6 +197,10 @@ class Decoder(nn.Module):
     output, chunk-start states and ``(I + A)^-1``; attention's ``o`` and
     ``lse``), so it holds no kernel: projections, convolution, gates, norms
     and the experts run again, a forward kernel runs once a step.
+
+    The head is a matrix of its own, ``lm_head``, unless ``dims.tied_head``:
+    then the logits are ``h E^T`` with the embedding's ``E``, one parameter
+    whose gradient is the sum of both uses.
     """
     dims: Any
     layer_kinds: Sequence[Any]
@@ -176,8 +209,9 @@ class Decoder(nn.Module):
     @nn.compact
     def __call__(self, tokens):
         d = self.dims
-        x = nn.Embed(d.vocab_size, d.hidden, embedding_init=_init,
-                     name="embed")(tokens).astype(jnp.float32)
+        embed = nn.Embed(d.vocab_size, d.hidden, embedding_init=_init,
+                         name="embed")
+        x = embed(tokens).astype(jnp.float32)
         block = (nn.remat(Block, policy=_KEEP_KERNEL_OUTPUTS)
                  if self.remat else Block)
         loads = []
@@ -186,12 +220,16 @@ class Decoder(nn.Module):
             if load is not None:
                 loads.append(load)
         x = d.norm("final_norm")(x)
-        head = self.param("lm_head", _init, (d.hidden, d.vocab_size),
-                          jnp.float32)
+        tied = getattr(d, "tied_head", False)
+        head = (embed.embedding if tied else
+                self.param("lm_head", _init, (d.hidden, d.vocab_size),
+                           jnp.float32))
         with jax.named_scope("lm/head"):
             from apex_tpu.amp.policy import current_policy
             dtype = current_policy().op_dtype("matmul", x.dtype)
-            logits = x.astype(dtype) @ head.astype(dtype)
+            logits = (jnp.einsum("btd,vd->btv", x.astype(dtype),
+                                 head.astype(dtype)) if tied
+                      else x.astype(dtype) @ head.astype(dtype))
         return logits, (jnp.stack(loads) if loads else
                         jnp.zeros((0, len(d.held)), jnp.int32))
 

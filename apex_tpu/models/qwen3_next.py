@@ -32,7 +32,7 @@ import jax.numpy as jnp
 
 from apex_tpu import ops
 from apex_tpu.models.decoder import (
-    Decoder, ExpertFFN, RMSNorm, _conv_init, _dense)
+    Decoder, ExpertFFN, RMSNorm, _conv_init, _dense, partial_rotary)
 from apex_tpu.ops.delta_rule import gated_delta_rule
 from apex_tpu.ops.short_conv import short_conv
 
@@ -41,27 +41,6 @@ def _a_log_init(key, shape, dtype=jnp.float32):
     """``log U(0, 16)``; the draw is kept off 0, whose log no update moves."""
     return jnp.log(jnp.maximum(
         jax.random.uniform(key, shape, dtype, 0.0, 16.0), 1e-6))
-
-
-def partial_rotary(x, rotary_dim, theta, positions=None):
-    """Rotary position embedding on the first ``rotary_dim`` channels of each
-    head, the rest left as they are. ``x`` ``(B, T, H, D)``; channel ``m <
-    rotary_dim / 2`` pairs with ``m + rotary_dim / 2`` (half-split) and turns
-    by ``p theta^(-2m / rotary_dim)`` at position ``p`` (``positions``
-    ``(T,)``, default ``0 ... T - 1``). In the policy's dtype for ``rotary``
-    (a FLOAT op): float32."""
-    from apex_tpu.amp.policy import current_policy
-    x = x.astype(current_policy().op_dtype("rotary", x.dtype))
-    half = rotary_dim // 2
-    if positions is None:
-        positions = jnp.arange(x.shape[1])
-    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2 / rotary_dim)
-    angle = positions.astype(jnp.float32)[:, None] * freq         # (T, half)
-    cos, sin = (f(angle)[None, :, None, :].astype(x.dtype)
-                for f in (jnp.cos, jnp.sin))
-    a, b = x[..., :half], x[..., half:rotary_dim]
-    return jnp.concatenate(
-        [a * cos - b * sin, b * cos + a * sin, x[..., rotary_dim:]], -1)
 
 
 class GatedDeltaNet(nn.Module):

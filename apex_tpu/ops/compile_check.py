@@ -433,25 +433,28 @@ def _rel(label, got, want, tol):
     assert np.all(np.isfinite(got)) and err <= tol, f"{label}: {err:.3e}"
 
 
-def _gqa_cell_case(t=8192, prefix=1024):
-    """Qwen3-Next's gated attention at the cell's shape (16 q heads on 2
-    k/v heads of 256, causal, bfloat16, the op's own tiles), forward and
-    the three gradients, against the dense oracle on a prefix: under a
-    causal mask the first rows see nothing of the rest."""
+def _gqa_cell_case(batch=1, heads=16, kv_heads=2, d=256, t=8192,
+                   prefix=1024):
+    """Grouped-query attention at a cell's shape (Qwen3-Next's gated
+    attention: 16 q heads on 2 k/v heads of 256; causal, bfloat16, the op's
+    own tiles), forward and the three gradients, against the dense oracle
+    on a prefix: under a causal mask the first rows see nothing of the
+    rest."""
     from apex_tpu.ops.attention import attention_reference, flash_attention
-    q = _rand((1, t, 16, 256), 0, jnp.bfloat16, 0.5)
-    k = _rand((1, t, 2, 256), 1, jnp.bfloat16, 0.5)
-    v = _rand((1, t, 2, 256), 2, jnp.bfloat16, 0.5)
-    w = _rand((1, prefix, 16, 256), 3, jnp.float32, 0.5)
+    q = _rand((batch, t, heads, d), 0, jnp.bfloat16, 0.5)
+    k = _rand((batch, t, kv_heads, d), 1, jnp.bfloat16, 0.5)
+    v = _rand((batch, t, kv_heads, d), 2, jnp.bfloat16, 0.5)
+    w = _rand((batch, prefix, heads, d), 3, jnp.float32, 0.5)
+    scale = d ** -0.5
     loss = lambda fn: lambda q, k, v: jnp.sum(
-        fn(q, k, v, None, 1 / 16, True)[:, :prefix].astype(jnp.float32) * w)
+        fn(q, k, v, None, scale, True)[:, :prefix].astype(jnp.float32) * w)
     out, grads = jax.jit(lambda *a: (
-        flash_attention(*a, None, 1 / 16, True),
+        flash_attention(*a, None, scale, True),
         jax.grad(loss(flash_attention), argnums=(0, 1, 2))(*a)))(q, k, v)
     assert out.shape == q.shape and grads[1].shape == k.shape
     head = tuple(x[:, :prefix].astype(jnp.float32) for x in (q, k, v))
     _rel("gqa fwd", out[:, :prefix], attention_reference(
-        *head, None, 1 / 16, True), 3e-2)
+        *head, None, scale, True), 3e-2)
     want = jax.grad(loss(attention_reference), argnums=(0, 1, 2))(*head)
     for name, g, r in zip("qkv", grads, want):
         _rel(f"gqa d{name}", g[:, :prefix], r, 6e-2)
@@ -461,6 +464,14 @@ def _gqa_cell_case(t=8192, prefix=1024):
 @case("attention/gqa-d256-cell")
 def _():
     _gqa_cell_case()
+
+
+@case("attention/gqa-d64-causal-s8192-cell")
+def _():
+    # the cell named: two sequences, 32 q heads on 8 k/v heads of 64: the
+    # backward's 1024 x 1024 tiles, two heads a step, under the raised VMEM
+    # limit
+    _gqa_cell_case(2, 32, 8, 64)
 
 
 # --- gated delta rule --------------------------------------------------------
@@ -562,6 +573,32 @@ def _():
 @case("short_conv/qwen-cell-highest")
 def _():
     _short_conv_cell_case(*_QWEN_CONV, precision="highest")
+
+
+@case("short_conv/gated-k3-cell")
+def _():
+    """A gated short-convolution mixer's middle at its cell's shape: two
+    sequences of 8192 tokens, ``[B; C; z]`` of 3 x 2048 channels in
+    bfloat16, three taps, no activation: the gated kernels against the
+    ``jax.numpy`` form, output and both gradients (``d [B; C; z]`` one
+    array)."""
+    from apex_tpu.ops.short_conv import short_conv, short_conv_reference
+    x = _rand((2, 8192, 6144), 0, jnp.bfloat16)
+    taps = _rand((3, 2048), 1, scale=0.3)
+    w = _rand((2, 8192, 2048), 2)
+    form = lambda fn: lambda x, taps: fn(x, taps, (), 128, (4096, 2048))
+    both = lambda fn: jax.jit(lambda x, taps: (
+        fn(x, taps), jax.grad(lambda x, taps: jnp.sum(
+            fn(x, taps).astype(jnp.float32) * w), argnums=(0, 1))(x, taps)))(
+                x, taps)
+    out, (d_x, d_taps) = both(form(short_conv))
+    ref, (r_x, r_taps) = both(form(short_conv_reference))
+    assert out.shape == (2, 8192, 2048) and out.dtype == jnp.bfloat16
+    assert d_x.shape == x.shape and d_x.dtype == x.dtype
+    # both leave as bfloat16 on both sides: a last bit, at most
+    _rel("gated conv fwd", out, ref, 2 ** -7)
+    _rel("gated conv dx", d_x, r_x, 2 ** -7)
+    _rel("gated conv dtaps", d_taps, r_taps, 1e-4)
 
 
 # --- layer norm --------------------------------------------------------------

@@ -26,14 +26,31 @@ from apex_tpu.ops._dispatch import pallas_call
 LANES = 128
 
 
-def _row_block(v_padded: int, n_bufs: int, itemsize: int = 4) -> int:
+#: what a kernel's blocks and its float32 working copy of one may take of
+#: the 16 MiB of scoped VMEM
+VMEM_FITS = int(15.5 * 2 ** 20)
+
+
+def _row_block(v_padded: int, n_bufs: int, itemsize: int = 4,
+               rows: int = 0) -> int:
     """Rows per grid step: size the vocab-wide blocks to a ~6 MiB
     double-buffered budget over ``n_bufs`` logits-sized buffers of the
     actual ``itemsize`` (bf16 logits take 2-3x larger rows than the old
     fp32-assuming 1 MiB bound — per-step overhead amortizes over fewer,
-    fatter steps; measured on the BERT-vocab shapes)."""
+    fatter steps; measured on the BERT-vocab shapes).
+
+    A block's float32 working copy sits beside the buffers in scoped VMEM: a
+    budget used to the last byte (256 bf16 rows of a power-of-two vocabulary
+    of 8192) leaves it 384 KiB short. Only then the block shrinks: to the
+    largest multiple of 16 that fits and divides ``rows`` (no padded copy of
+    the logits), or, where none does, that fits."""
     r = (8 << 20) // (2 * n_bufs * itemsize * v_padded)
-    return max(16, min(256, (r // 16) * 16))
+    r = max(16, min(256, (r // 16) * 16))
+    fits = VMEM_FITS // ((2 * n_bufs * itemsize + 4) * v_padded) // 16 * 16
+    if r <= fits:
+        return r
+    whole = [b for b in range(fits, 15, -16) if rows and rows % b == 0]
+    return max(16, whole[0] if whole else fits)
 
 
 def _pad2(x2, rows, cols):
@@ -110,7 +127,7 @@ def _fwd_call(x2, labels, smoothing, block_rows=None):
         from apex_tpu.ops import autotune
         block_rows = autotune.tuned_rows("xentropy", (n, v), x2.dtype)
     r = (block_rows if block_rows is not None
-         else _row_block(-(-v // LANES) * LANES, 1, x2.dtype.itemsize))
+         else _row_block(-(-v // LANES) * LANES, 1, x2.dtype.itemsize, n))
     npad = -(-n // r) * r
     xp = _pad2(x2, npad, vp)
     # padding rows get label -1 → zero loss
@@ -140,7 +157,7 @@ def _bwd_call(x2, labels, lse, g, smoothing, block_rows=None):
         from apex_tpu.ops import autotune
         block_rows = autotune.tuned_rows("xentropy", (n, v), x2.dtype)
     r = (block_rows if block_rows is not None
-         else _row_block(-(-v // LANES) * LANES, 2, x2.dtype.itemsize))
+         else _row_block(-(-v // LANES) * LANES, 2, x2.dtype.itemsize, n))
     npad = -(-n // r) * r
     xp = _pad2(x2, npad, vp)
     lab = _broadcast_lanes(

@@ -99,6 +99,8 @@ def main(argv=None, config="kimi_linear", make_probes=kimi_probes,
         found["probes"][name] = {
             "loss_rel_diff": abs(loss - base[0]) / abs(base[0]),
             "logit_rel_diff": ref._rel(logits, base[1]),
+            **({"logit_row_rel_diff": ref._rows_rel(logits, base[1])}
+               if hasattr(ref, "_rows_rel") else {}),
             "grad_rel_diff": {"/".join(p): ref._rel(g, b) for p, g, b
                               in zip(paths, grads, base[2])}}
         if slow is not None:

@@ -718,6 +718,13 @@ MIB = 2 ** 20
     pytest.param(dict(batch=1, s=8192, d=256, nh=16, itemsize=2),
                  (1024, 1024, 1, 32 * MIB, "two_kernel_raised"),
                  id="qwen3-next-d256-raised"),
+    # LFM2's grouped-query attention: two sequences, 32 heads of 64 (the
+    # 8 k/v heads reach the kernels repeated), causal, 8192 tokens: two
+    # heads a step fill the lanes, 1024 x 1024 tiles under the raised limit
+    # (compiled for the v5e at 16.1 MiB for dk/dv: over the default)
+    pytest.param(dict(batch=2, s=8192, d=64, nh=32, itemsize=2),
+                 (1024, 1024, 2, 32 * MIB, "two_kernel_raised"),
+                 id="lfm2-causal-d64-32-heads-s8192-raised"),
 ])
 def test_backward_plan(shape, want):
     kw = dict(shape)

@@ -186,6 +186,26 @@ class TestSoftmaxCrossEntropy:
         loss = ops.softmax_cross_entropy_loss(x, labels, 0.1)
         assert loss.shape == (2, 5)
 
+    @pytest.mark.parametrize("vocab, rows, forward, backward", [
+        (30522, 1280, 64, 32), (20480, 8192, 96, 48), (18992, 8192, 96, 48),
+        (8192, 16384, 128, 128), (8192, 16000, 160, 128), (8192, 0, 240, 128)],
+        ids=["bert_large", "kimi_linear", "qwen3_next", "lfm2_moe",
+             "rows_with_another_divisor", "rows_unknown"])
+    def test_row_block_of_the_cells_vocabularies(self, vocab, rows, forward,
+                                                 backward):
+        """bfloat16 logits as O1 makes them. The three cells before PR 34
+        keep the rows the budget gives; a vocabulary of 8192 would take 256
+        rows forward, whose float32 working copy does not fit the v5e's
+        scoped VMEM beside them: the largest multiple of 16 that fits (240)
+        and divides the rows, so that no padded copy is made."""
+        from apex_tpu.ops.xentropy import LANES, VMEM_FITS, _row_block
+        padded = -(-vocab // LANES) * LANES
+        got = [_row_block(padded, bufs, 2, rows) for bufs in (1, 2)]
+        assert got == [forward, backward]
+        for bufs, block in zip((1, 2), got):
+            assert block % 16 == 0
+            assert (2 * bufs * 2 + 4) * padded * block <= VMEM_FITS
+
 
 class TestGroupBN:
     def test_single_device_module(self):

@@ -138,6 +138,34 @@ def test_the_shares_of_a_layer_with_a_gated_shared_expert_add_up():
     assert float(jnp.max(jnp.abs(total - 3 * shared_only - whole))) <= 1e-5
 
 
+def test_a_layer_with_no_shared_expert():
+    """``shared_width`` 0: no ``shared`` parameters, no ``moe/shared`` scope,
+    and the layer is the held experts' weighted sum alone; the default
+    (None) still builds a shared expert of the routed width."""
+    from apex_tpu import models
+    E, k, held = 16, 4, (0, 1, 2, 3, 4, 5, 6, 7)
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 40, D))
+    layer = models.ExpertFFN(D, F, E, k, held, 1.0, shared_width=0)
+    p = layer.init(jax.random.PRNGKey(1), x)["params"]
+    assert set(p) == {"router", "e_bias", "experts_gate", "experts_up",
+                      "experts_down"}
+    p = {**p, "router": p["router"] * 30}
+    y, load = layer.apply({"params": p}, x)
+    rows = x.reshape(-1, D)
+    chosen, w = moe.route(rows, p["router"], p["e_bias"], k, 1.0, "sigmoid")
+    want = plain(rows, w, chosen, p["experts_gate"], p["experts_up"],
+                 p["experts_down"], held)
+    assert float(jnp.max(jnp.abs(y.reshape(-1, D) - want))) <= 1e-5 * float(
+        jnp.max(jnp.abs(want)))
+    assert load.tolist() == moe.expert_load(chosen, held).tolist()
+    text = jax.jit(lambda p, x: layer.apply({"params": p}, x)[0]).lower(
+        p, x).as_text(debug_info=True)
+    assert "moe/experts" in text and "moe/shared" not in text
+    with_shared = models.ExpertFFN(D, F, E, k, held, 1.0).init(
+        jax.random.PRNGKey(1), x)["params"]
+    assert with_shared["shared"]["up_proj"]["kernel"].shape == (D, F)
+
+
 def test_softmax_route_normalises_over_the_chosen():
     x, *_ = weights(3, 1)
     router = jax.random.normal(jax.random.PRNGKey(4), (D, E))

@@ -631,6 +631,40 @@ def test_short_conv_kernels_compile_for_the_v5e_at_the_cells_shapes(
     assert d_taps.shape == taps.shape and d_taps.dtype == jnp.float32
 
 
+@pytest.mark.parametrize("dtype,precision", [
+    (jnp.bfloat16, "default"), (jnp.float32, "highest")])
+def test_gated_short_conv_kernels_compile_for_the_v5e_at_the_cells_shape(
+        v5e_chip, monkeypatch, dtype, precision):
+    """LFM2's mixer between its projections at its cell's shape: two
+    sequences of 8192 tokens, ``[B; C; z]`` of 3 x 2048 channels, three
+    taps. What interpret mode cannot see: three blocks of one array at
+    three channel offsets, the backward's fourth grid axis over the parts of
+    ``d x`` with two bfloat16 blocks kept in VMEM for their turn, the scoped
+    VMEM. ``d [B; C; z]`` leaves the kernel as one array."""
+    from apex_tpu.ops import _dispatch
+    from apex_tpu.ops.short_conv import short_conv
+    monkeypatch.setattr(_dispatch, "use_interpret", lambda: False)
+    shape = lambda *s, dt=jnp.float32: jax.ShapeDtypeStruct(
+        s, dt, sharding=v5e_chip)
+    x, taps = shape(2, 8192, 6144, dt=dtype), shape(3, 2048)
+    weight = shape(2, 8192, 2048)
+    loss = lambda x, taps, w: jnp.sum(short_conv(
+        x, taps, (), 128, (4096, 2048)).astype(jnp.float32) * w)
+    with jax.default_matmul_precision(precision):
+        compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+            x, taps, weight).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    for kernel in ("apex_short_conv_fwd", "apex_short_conv_bwd"):
+        assert f"({kernel})" in text or f"/{kernel}/" in text, kernel
+    # the backward reads x as it is, six times over, and no concatenation
+    # or pad follows it
+    assert "concatenate" not in text and " pad(" not in text
+    _, (d_x, d_taps) = compiled.out_info
+    assert d_x.shape == x.shape and d_x.dtype == dtype
+    assert d_taps.shape == taps.shape and d_taps.dtype == jnp.float32
+
+
 def test_the_scan_kernels_read_the_convolution_kernels_for_the_v5e(
         v5e_chip, monkeypatch):
     """One KDA layer at the published head size, compiled for the v5e: in

@@ -1,7 +1,9 @@
 """``ops.short_conv`` (convolution + SiLU + per-head l2-norm in the scan's
 ``(B, T, H d)`` layout, two Pallas kernels, interpreted here) against the
 ``jax.numpy`` form it replaces in the delta-rule layers, ``_short_conv`` +
-``_l2_normalised``: outputs and the gradients of ``x`` and of the taps."""
+``_l2_normalised``: outputs and the gradients of ``x`` and of the taps.
+Then its gated, activation-free form (a gated short-convolution mixer
+between its projections) against the sum written out."""
 
 import jax
 import jax.numpy as jnp
@@ -190,3 +192,106 @@ def test_calls_of_one_shape_share_one_trace(monkeypatch):
     for n in (norm, norm, norm, ()):
         ref = plain(ref, taps, n)
     assert float(jnp.max(jnp.abs(thrice(x, taps) - ref))) <= 1e-6
+
+
+# --- the gated form: no activation, a gate before the taps and one after ----
+
+def gated_plain(x, taps, before, after):
+    """``g_after * conv(g_before * x)`` written out: the sum over the taps
+    of the gated input shifted, zeros before the sequence."""
+    c, (k, t) = taps.shape[1], (taps.shape[0], x.shape[1])
+    part = lambda at: x[..., at:at + c].astype(jnp.float32)
+    v = jnp.pad(part(0) * part(before), ((0, 0), (k - 1, 0), (0, 0)))
+    return part(after) * sum(v[:, j:j + t] * taps[j] for j in range(k))
+
+
+def gated_inputs(b, t, c, k, dtype, parts=3, seed=0):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    x = jax.random.normal(keys[0], (b, t, parts * c)).astype(dtype)
+    taps = jax.random.uniform(keys[1], (k, c), minval=-3 ** -0.5,
+                              maxval=3 ** -0.5)
+    return x, taps, jax.random.normal(keys[2], (b, t, c))
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bfloat16", "float32"])
+@pytest.mark.parametrize("shape", [(2, 100, 256, 3, 3), (1, 64, 128, 3, 4),
+                                   (1, 20, 384, 2, 3)],
+                         ids=["b2_no_whole_block", "x_four_parts_wide",
+                              "under_a_block_k2"])
+def test_the_gated_form_against_the_sum_written_out(shape, dtype,
+                                                    small_blocks):
+    """``[x; g_after; g_before]`` side by side as LFM2's projection writes
+    ``[B; C; z]``, three taps, no activation: the output in ``x``'s dtype
+    and all four cotangents (``d B``, ``d C``, ``d z`` as one array of
+    ``x``'s shape, a part that is no operand zero, and ``d taps``), kernels
+    and ``jax.numpy`` form alike."""
+    b, t, c, k, parts = shape
+    x, taps, weight = gated_inputs(b, t, c, k, dtype, parts)
+    gates = (2 * c, c)
+    both = lambda fn: (fn(x, taps), jax.grad(lambda x, taps: jnp.sum(
+        fn(x, taps).astype(jnp.float32) * weight), argnums=(0, 1))(x, taps))
+    ref, (r_x, r_taps) = both(lambda x, taps: gated_plain(x, taps, *gates))
+    f32 = lambda a: a.astype(jnp.float32)
+    top = lambda a: float(jnp.max(jnp.abs(f32(a))))
+    for fn in (short_conv, short_conv_reference):
+        out, (d_x, d_taps) = both(
+            lambda x, taps: fn(x, taps, (), HEAD, gates))
+        assert out.shape == ref.shape and out.dtype == dtype
+        assert d_x.shape == x.shape and d_x.dtype == dtype
+        assert d_taps.shape == taps.shape and d_taps.dtype == jnp.float32
+        half = 1e-2 if dtype == jnp.bfloat16 else 1e-5
+        assert top(f32(out) - ref) <= half * top(ref)
+        for part, name in enumerate(("d_x", "d_after", "d_before")):
+            cut = slice(part * c, (part + 1) * c)
+            assert top(f32(d_x[..., cut]) - f32(r_x[..., cut])) <= half * top(
+                r_x[..., cut]), name
+        assert not bool(jnp.any(d_x[..., 3 * c:]))
+        assert top(d_taps - r_taps) <= (1e-2 if dtype == jnp.bfloat16
+                                        else 2e-5) * top(r_taps)
+
+
+@pytest.mark.parametrize("row", [31, 32], ids=["last_of_block_0",
+                                               "first_of_block_1"])
+def test_a_gated_impulse_crosses_a_block_border(row, small_blocks):
+    """Three taps: a token's output reaches the two after it and a
+    cotangent the two before it, across a block's border, through both
+    gates."""
+    x, taps, _ = gated_inputs(1, 96, 128, 3, jnp.float32)
+    at = jnp.zeros((1, 96, 128)).at[:, row].set(1.0)
+    only = x.at[..., :128].set(at)         # B one token, C and z everywhere
+    run = lambda x: short_conv(x, taps, (), HEAD, (256, 128))
+    out = run(only)
+    assert float(jnp.max(jnp.abs(out - gated_plain(only, taps, 256, 128)))
+                 ) <= 1e-6
+    assert bool(jnp.all(out[:, row:row + 3] != 0)) and not bool(
+        jnp.any(out[:, row + 3:])) and not bool(jnp.any(out[:, :row]))
+    d_x = jax.grad(lambda x: jnp.sum(run(x) * at))(x)
+    d_ref = jax.grad(lambda x: jnp.sum(gated_plain(x, taps, 256, 128) * at))(x)
+    assert float(jnp.max(jnp.abs(d_x - d_ref))) <= 1e-6
+    assert bool(jnp.all(d_x[:, row - 2:row + 1, :128] != 0))
+    assert not bool(jnp.any(d_x[:, row + 1:, :128])) and not bool(
+        jnp.any(d_x[:, :row - 2, :128]))
+
+
+def test_the_gated_form_takes_the_same_two_kernels():
+    """``gates`` picks the body, the head size the path: whole lane tiles
+    lower to ``apex_short_conv_fwd`` / ``_bwd`` with one ``d x`` of ``x``'s
+    shape, 64 channels take the ``jax.numpy`` form; a norm with gates or a
+    gate that is no whole part of its own is refused."""
+    def lowered(c):
+        x, taps, _ = gated_inputs(1, 32, c, 3, jnp.bfloat16)
+        return jax.jit(jax.value_and_grad(lambda x, taps: jnp.sum(short_conv(
+            x, taps, (), c, (2 * c, c)).astype(jnp.float32)),
+            argnums=(0, 1))).lower(x, taps).as_text(debug_info=True)
+    assert "apex_short_conv" not in lowered(64)
+    text = lowered(HEAD)
+    assert "apex_short_conv_fwd/pallas_call" in text
+    assert "apex_short_conv_bwd/pallas_call" in text
+    assert "concatenate" not in text
+    x, taps, _ = gated_inputs(1, 32, HEAD, 3, jnp.float32)
+    for bad in (dict(norm=((0, 128, 1.0),), gates=(256, 128)),
+                dict(gates=(64, 128)), dict(gates=(128, 128)),
+                dict(gates=(0, 128))):
+        with pytest.raises(AssertionError):
+            short_conv(x, taps, head_dim=HEAD, **bad)
