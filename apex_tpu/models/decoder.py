@@ -30,14 +30,18 @@ experts *that are in* ``held``: what this rank adds to the all-reduced sum
 of a deployment (the shared expert, where the model has one, is every
 rank's alike, counted once).
 With ``held = range(n_routed)`` it is the whole layer. No token is dropped
-for any routing (``ops/moe.py``).
+for any routing, and there is one path: the assignments to held experts
+become rows sorted by expert, the experts' matmuls are grouped matmuls over
+the live rows alone, and gathers take the rows there and back
+(``ops/moe.py``). A step's cost follows the rows its routing sent here.
 
 The loss returns, beside itself, what a monitor wants of the routing:
-``rows_routed_here``, ``expert_load`` and ``experts_over_capacity``, a row
-for each expert layer.
+``rows_routed_here``, ``expert_load`` and ``expert_rows_run`` (the rows the
+grouped matmul visited: the live ones and the tiles' rounding), a row for
+each expert layer.
 
 The expert layer and the head run under ``jax.named_scope``s a device trace
-can be cut by: ``moe/{route,dispatch,experts,combine,shared,overflow}``,
+can be cut by: ``moe/{route,dispatch,experts,combine,shared}``,
 ``lm/head``; each mixer names its own.
 """
 
@@ -238,17 +242,16 @@ def lm_loss(model, variables, tokens):
     """Mean cross-entropy of token ``t + 1`` at position ``t`` (the last
     position of each sequence has no label), over the fused softmax-CE.
     Returns ``(loss, {"rows_routed_here", "expert_load",
-    "experts_over_capacity"})``, one row for each expert layer; the last
-    counts the held experts whose rows passed their capacity and took a
-    dense turn over every row (``moe/overflow``) in this step."""
+    "expert_rows_run"})``, one row for each expert layer; the last is the
+    rows the experts' grouped matmul visited in this step (visited tiles
+    times a tile's rows): over ``rows_routed_here`` by what the tiles'
+    edges round up, and by nothing else."""
     logits, load = model.apply(variables, tokens)
     labels = jnp.concatenate(
         [tokens[:, 1:], jnp.full_like(tokens[:, :1], -1)], 1)
     with jax.named_scope("lm/head"):
         total = jnp.sum(ops.softmax_cross_entropy_loss(logits, labels))
     loss = total / max(labels.shape[0] * (labels.shape[1] - 1), 1)
-    d = model.dims
-    cap = moe.capacity(tokens.size, d.top_k, d.n_routed)
     return loss, {"rows_routed_here": jnp.sum(load, -1), "expert_load": load,
-                  "experts_over_capacity": jnp.sum(load > cap, -1,
-                                                   dtype=jnp.int32)}
+                  "expert_rows_run": moe.expert_rows_run(
+                      load, tokens.size * model.dims.top_k)}

@@ -14,7 +14,7 @@ decay, the delta-rule state, the router and the norms are float32
 
 Every part runs under a ``jax.named_scope`` a device trace can be cut by:
 ``kda/{proj,conv,gate,scan,out}``, ``mla/{proj,attn,out}``,
-``moe/{route,dispatch,experts,combine,shared,overflow}``, ``lm/head``.
+``moe/{route,dispatch,experts,combine,shared}``, ``lm/head``.
 """
 
 from __future__ import annotations

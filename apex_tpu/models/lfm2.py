@@ -16,7 +16,7 @@ float32 (``amp/lists.py``).
 
 Every part runs under a ``jax.named_scope`` a device trace can be cut by:
 ``lconv/{proj,conv,out}``, ``gqa/{proj,rope,attn,out}``,
-``moe/{route,dispatch,experts,combine,overflow}``, ``lm/head``.
+``moe/{route,dispatch,experts,combine}``, ``lm/head``.
 """
 
 from __future__ import annotations

@@ -18,7 +18,7 @@ the router and the norms are float32 (``amp/lists.py``).
 
 Every part runs under a ``jax.named_scope`` a device trace can be cut by:
 ``gdn/{proj,conv,gate,scan,out}``, ``gattn/{proj,rope,attn,out}``,
-``moe/{route,dispatch,experts,combine,shared,overflow}``, ``lm/head``.
+``moe/{route,dispatch,experts,combine,shared}``, ``lm/head``.
 """
 
 from __future__ import annotations

@@ -42,6 +42,7 @@ from apex_tpu.ops.delta_rule import (
     gated_delta_rule,
     gated_delta_rule_reference,
 )
+from apex_tpu.ops import grouped_matmul  # the module: its two functions
 from apex_tpu.ops import moe
 from apex_tpu.ops import short_conv     # the module: short_conv.short_conv
 from apex_tpu.ops import autotune
@@ -59,5 +60,6 @@ __all__ = [
     "flash_attention", "attention_reference", "mask_softmax_dropout",
     "SelfMultiheadAttn", "EncdecMultiheadAttn",
     "gated_delta_rule", "gated_delta_rule_reference", "moe", "short_conv",
+    "grouped_matmul",
     "KEPT_ATTN", "KEPT_KDA", "KEPT_NAMES",
 ]

@@ -44,6 +44,8 @@ KERNEL_NAMES = (
     "apex_kda_fwd", "apex_kda_bwd",
     # short_conv.py: convolution + SiLU + l2-norm in the scan's layout
     "apex_short_conv_fwd", "apex_short_conv_bwd",
+    # grouped_matmul.py: rows sorted by group, a matrix a group (ops/moe.py)
+    "apex_gmm", "apex_tgmm", "apex_unwritten",
     # flat-buffer row kernels through launch(): multi_tensor, optim_kernels
     "apex_rows_scale", "apex_rows_axpby", "apex_rows_l2norm",
     "apex_rows_maxnorm", "apex_rows_adam", "apex_rows_sgd",

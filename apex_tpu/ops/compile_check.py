@@ -601,6 +601,68 @@ def _():
     _rel("gated conv dtaps", d_taps, r_taps, 1e-4)
 
 
+# --- routed experts ----------------------------------------------------------
+
+def _experts_cell_case(tokens, top_k, routed, n_held, hidden, width):
+    """One rank's expert layer at a cell's shape, bfloat16 as under O1, a
+    router that spreads the rows evenly: the sorted rows, ``apex_gmm`` (its
+    three forms) and ``apex_tgmm`` against a loop of dense experts over
+    every row under a 0/1 mask, value and all five gradients; then every
+    assignment held, which is the most rows the kernels can be sent."""
+    from apex_tpu.ops import moe
+    held = tuple(range(n_held))
+    x = _rand((tokens, hidden), 0, jnp.bfloat16)
+    w_gate, w_up = (_rand((n_held, hidden, width), s, jnp.bfloat16, 0.02)
+                    for s in (1, 2))
+    w_down = _rand((n_held, width, hidden), 3, jnp.bfloat16, 0.02)
+    out_w = _rand((tokens, hidden), 4)
+
+    def plain(x, w, a, b, c, chosen):
+        y = jnp.zeros((tokens, hidden), jnp.float32)
+        for n, e in enumerate(held):
+            h = jax.nn.silu(x @ a[n]) * (x @ b[n])
+            y += jnp.sum(jnp.where(chosen == e, w, 0.0), -1)[:, None] * (
+                h @ c[n]).astype(jnp.float32)
+        return y
+
+    ours = lambda x, w, a, b, c, chosen: moe.held_experts(
+        x, w, chosen, a, b, c, held, routed)
+    for every_row_held in (False, True):
+        scores = jax.random.uniform(jax.random.PRNGKey(5), (tokens, routed))
+        if every_row_held and n_held >= top_k:
+            scores = scores.at[:, :n_held].add(2.0)
+        w, chosen = jax.lax.top_k(scores, top_k)
+        chosen = chosen.astype(jnp.int32)
+        both = lambda fn: jax.jit(lambda *a: (
+            fn(*a, chosen), jax.grad(lambda *a: jnp.sum(
+                fn(*a, chosen) * out_w), argnums=range(5))(*a)))(
+                    x, w, w_gate, w_up, w_down)
+        got, d_got = both(ours)
+        want, d_want = both(plain)
+        assert got.shape == (tokens, hidden) and got.dtype == jnp.float32
+        # both sides round each matmul's float32 sum to bfloat16 once
+        _rel("experts fwd", got, want, 2e-2)
+        for name, a, b in zip(("x", "w", "gate", "up", "down"), d_got, d_want):
+            assert a.shape == b.shape and a.dtype == b.dtype, name
+            _rel(f"experts d{name}", a, b, 3e-2)
+
+
+@case("moe/4-of-64-cell")
+def _():
+    # two sequences of 8192, 4 of 64 chosen, 8 held, 2048 x 1536
+    _experts_cell_case(16384, 4, 64, 8, 2048, 1536)
+
+
+@case("moe/8-of-256-cell")
+def _():
+    _experts_cell_case(8192, 8, 256, 8, 2304, 1024)
+
+
+@case("moe/10-of-512-cell")
+def _():
+    _experts_cell_case(8192, 10, 512, 32, 2048, 512)
+
+
 # --- layer norm --------------------------------------------------------------
 
 def _ln_case(n, h, dtype=jnp.float32, atol=1e-4):

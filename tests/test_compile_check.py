@@ -62,3 +62,17 @@ def test_the_qwen3_next_cell_cases_run_at_a_small_size():
     cc._gqa_cell_case(t=256, prefix=128)
     cc._delta_rule_cell_case(t=192, prefix=64)
     cc._delta_rule_cell_case(t=128, prefix=64, precision="highest")
+
+
+def test_the_expert_layers_cell_cases_run_at_a_small_size(monkeypatch):
+    """``moe/{4-of-64,8-of-256,10-of-512}-cell`` hold each decoder cell's
+    routing (tokens, chosen of routed, held) and widths; on the chip they
+    compile ``apex_gmm`` and ``apex_tgmm`` at those, here the same case runs
+    interpreted at 64 tokens with the sorted rows in several tiles."""
+    from apex_tpu.ops import grouped_matmul as gm
+    names = [n for n, _ in cc.CASES]
+    for name in ("moe/4-of-64-cell", "moe/8-of-256-cell",
+                 "moe/10-of-512-cell"):
+        assert name in names
+    monkeypatch.setattr(gm, "ROW_TILE", 32)
+    cc._experts_cell_case(64, 2, 16, 4, 32, 16)

@@ -61,14 +61,18 @@ def test_one_forward_kernel_a_layer(five, remat):
     the step once: the rerun of a block finds their outputs kept and holds
     no call. The one forward kernel a rerun may hold is the short
     convolution's (three a KDA layer: q, k, v), whose output is not kept:
-    twice a layer under ``remat``, once without."""
+    twice a layer under ``remat``, once without. An expert layer's kernels
+    are its own ``custom_vjp``'s, recomputed or not: 3 + 5 ``apex_gmm``, 1 + 4
+    ``apex_tgmm`` (the tokens' sums and the weights' gradients) and the
+    unwritten buffers of its loops over the live rows."""
     params, tokens = five
     # (at this length attention's backward is the one fused kernel)
     assert kernels(value_and_grad(remat, tokens), params) == {
         "apex_kda_fwd": 4, "apex_kda_bwd": 4, "apex_attn_fwd": 1,
         "apex_attn_bwd": 1, "apex_xentropy_fwd": 1, "apex_xentropy_bwd": 1,
         "apex_short_conv_fwd": 24 if remat else 12,
-        "apex_short_conv_bwd": 12}
+        "apex_short_conv_bwd": 12,
+        "apex_gmm": 4 * 8, "apex_tgmm": 4 * 5, "apex_unwritten": 4 * 14}
 
 
 def test_remat_changes_nothing_with_kernels(five):

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import pytest
 
 from apex_tpu import amp, models
+from apex_tpu.ops import moe
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 
@@ -167,7 +168,14 @@ def test_float32_model_equals_the_reference(toy):
         routing["expert_load"].sum(-1).tolist()
     assert load.tolist() == routing["expert_load"].tolist()
     assert 0 < int(routing["rows_routed_here"][0]) < 2 * LENGTH * 2
-    assert routing["experts_over_capacity"].tolist() == [0] * 4
+    # what the grouped matmul ran on: the rows routed here and the tiles'
+    # rounding, a tile more for each expert at most
+    run, here = routing["expert_rows_run"], routing["rows_routed_here"]
+    tile = moe.row_tile(tokens.size * model.dims.top_k)
+    assert run.shape == (4,) and run.dtype == jnp.int32
+    assert bool(jnp.all(run >= here)) and bool(jnp.all(run % tile == 0))
+    assert bool(jnp.all(run <= -(-here // tile) * tile
+                        + tile * (len(model.dims.held) - 1)))
 
 
 def test_the_tied_heads_gradient_is_the_sum_of_both_uses(toy):

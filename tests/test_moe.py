@@ -1,13 +1,17 @@
 """One expert-parallel rank's expert layer (``ops/moe.py``): the held
 experts' part of the result is exact for any routing (no token dropped),
-the shares of all ranks add up to the whole layer, and the counters say
-what reached this rank."""
+the shares of all ranks add up to the whole layer, the counters say what
+reached this rank and what the grouped matmul ran on, and the grouped
+matmul itself (``ops/grouped_matmul.py``, interpreted here) gives each
+group's product for any sizes."""
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from apex_tpu import amp
+from apex_tpu.ops import grouped_matmul as gm
 from apex_tpu.ops import moe
 
 T, D, F, E, K = 160, 32, 16, 64, 2
@@ -47,25 +51,33 @@ def routing(kind, held):
         away = [e for e in range(E) if e not in held]
         chosen = jnp.stack([jnp.full((T,), away[0]),
                             jnp.full((T,), away[1])], 1)
+    elif kind == "every_assignment_held":
+        first = jax.random.randint(key, (T,), 0, len(held))
+        chosen = jnp.stack([jnp.asarray(held)[first],
+                            jnp.asarray(held)[(first + 1) % len(held)]], 1)
     w = jax.random.uniform(jax.random.fold_in(key, 1), (T, K), minval=0.2)
     return chosen.astype(jnp.int32), w
 
 
+@pytest.mark.parametrize("tile", [512, 32])
 @pytest.mark.parametrize("kind", ["uniform", "all_to_one_held",
-                                  "two_over_rest_spread", "none_to_any"])
-def test_exact_for_any_routing(kind):
+                                  "two_over_rest_spread", "none_to_any",
+                                  "every_assignment_held"])
+def test_exact_for_any_routing(kind, tile, monkeypatch):
+    """Values and all six gradients against the dense sum, with the sorted
+    rows in one tile (what 320 assignments make of 512) and in ten, and the
+    tokens in one tile of the way back and in five."""
+    monkeypatch.setattr(gm, "ROW_TILE", tile)
+    monkeypatch.setattr(moe, "TOKEN_TILE", tile)
     held = (4, 5, 6, 7)
     x, w_gate, w_up, w_down = weights(0, len(held))
     chosen, w = routing(kind, held)
-    cap = moe.capacity(T, K, E)
     load = moe.expert_load(chosen, held)
-    # experts over their capacity run over every row, the others compacted
-    assert int(jnp.sum(load > cap)) == {"all_to_one_held": 1,
-                                        "two_over_rest_spread": 2}.get(kind, 0)
-    if kind == "two_over_rest_spread":
-        assert int(jnp.sum((load > 0) & (load <= cap))) == 2
-    assert int(load.sum()) == {"all_to_one_held": T, "none_to_any": 0}.get(
+    assert int(load.sum()) == {"all_to_one_held": T, "none_to_any": 0,
+                               "every_assignment_held": T * K}.get(
         kind, int(load.sum()))
+    if kind == "two_over_rest_spread":      # two with half the rows each
+        assert load[:2].tolist() == [T // 2, T // 2] and int(load[2:].sum())
     run = lambda fn: lambda x, w, a, b, c: jnp.sum(
         fn(x, w, chosen, a, b, c, held) * jnp.sin(jnp.arange(D)))
     ours = lambda x, w, chosen, a, b, c, held: moe.held_experts(
@@ -78,8 +90,189 @@ def test_exact_for_any_routing(kind):
     g_got = jax.grad(run(ours), argnums=range(5))(x, w, w_gate, w_up, w_down)
     g_want = jax.grad(run(plain), argnums=range(5))(x, w, w_gate, w_up, w_down)
     for a, b in zip(g_got, g_want):
+        assert bool(jnp.all(jnp.isfinite(a)))
         assert float(jnp.max(jnp.abs(a - b))) <= 1e-5 * max(
             1.0, float(jnp.max(jnp.abs(b))))
+
+
+def per_group(lhs, rhs, sizes, transposed=False):
+    """Each group's rows times its own matrix, one ``einsum`` a group."""
+    out = np.zeros((lhs.shape[0], rhs.shape[1 if transposed else 2]),
+                   np.float32)
+    start = 0
+    for g, n in enumerate(sizes):
+        w = np.asarray(rhs[g], np.float32)
+        out[start:start + n] = np.einsum(
+            "mk,kn->mn", np.asarray(lhs[start:start + n], np.float32),
+            w.T if transposed else w)
+        start += n
+    return out
+
+
+def per_group_t(lhs, rhs, sizes):
+    out = np.zeros((len(sizes), lhs.shape[1], rhs.shape[1]), np.float32)
+    start = 0
+    for g, n in enumerate(sizes):
+        out[g] = np.einsum("mk,mn->kn",
+                           np.asarray(lhs[start:start + n], np.float32),
+                           np.asarray(rhs[start:start + n], np.float32))
+        start += n
+    return out
+
+
+GROUPS = {"even": [32, 32, 32, 32], "one_takes_all": [0, 128, 0, 0],
+          "some_empty": [0, 50, 0, 33], "no_multiple_of_the_tile":
+          [7, 9, 61, 3], "no_live_row": [0, 0, 0, 0],
+          "the_last_to_the_end": [1, 0, 0, 127],
+          "more_groups_than_tiles": [3, 0, 5, 1, 1, 0, 9, 2, 4, 6, 0, 8]}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("case", sorted(GROUPS))
+def test_grouped_matmul_and_its_two_backward_forms(case, dtype, monkeypatch):
+    """``apex_gmm`` (plain, against transposed weights, two pairs summed)
+    and ``apex_tgmm`` against a per-group ``einsum``: the live rows' values,
+    zeros for what a visited tile holds past them, and exactly zero for a
+    group that got no row."""
+    monkeypatch.setattr(gm, "ROW_TILE", 32)
+    sizes = GROUPS[case]
+    m, k, n, g = 128, 24, 40, len(sizes)
+    live = sum(sizes)
+    ks = jax.random.split(jax.random.PRNGKey(g), 4)
+    lhs = jax.random.normal(ks[0], (m, k)).astype(dtype)
+    rhs = jax.random.normal(ks[1], (g, k, n)).astype(dtype)
+    rhs_t = jax.random.normal(ks[2], (g, n, k)).astype(dtype)
+    other = jax.random.normal(ks[3], (m, n)).astype(dtype)
+    sz = jnp.asarray(sizes, jnp.int32)
+    # one rounding of a float32 sum, as the per-group einsum has none
+    tol = lambda want: (2 ** -8 if dtype == jnp.bfloat16 else 1e-5) * max(
+        1.0, float(np.max(np.abs(want))))
+    f32 = lambda a: np.asarray(a.astype(jnp.float32))
+    for got, want in (
+            (gm.grouped_matmul(lhs, rhs, sz), per_group(lhs, rhs, sizes)),
+            (gm.grouped_matmul(lhs, rhs_t, sz, transposed=True),
+             per_group(lhs, rhs_t, sizes, True)),
+            (gm.grouped_matmul((lhs, lhs), (rhs, rhs), sz),
+             2 * per_group(lhs, rhs, sizes))):
+        assert got.dtype == dtype and got.shape == want.shape
+        assert np.max(np.abs(f32(got)[:live] - want[:live]),
+                      initial=0.0) <= tol(want)
+        # a visited tile is written whole: zeros past the last live row
+        assert not np.any(f32(got)[live:-(-live // 32) * 32])
+    got = gm.grouped_matmul_t(lhs, other, sz)
+    want = per_group_t(lhs, other, sizes)
+    assert got.dtype == dtype and got.shape == (g, k, n)
+    assert np.max(np.abs(f32(got) - want)) <= tol(want)
+    for i, size in enumerate(sizes):
+        if size == 0:
+            assert not np.any(f32(got)[i])
+    # a left operand in parts, summed in float32 before anything is rounded
+    parts = (lhs, (0.5 * lhs).astype(dtype), (0.25 * lhs).astype(dtype))
+    got = gm.grouped_matmul_t(parts, other, sz, jnp.float32)
+    assert got.dtype == jnp.float32
+    assert np.max(np.abs(np.asarray(got) - 1.75 * want)) <= 1e-5 * max(
+        1.0, float(np.max(np.abs(want))))
+    # the grid's bound: tiles that hold a live row, one more where a group
+    # starts inside another's tile
+    *_, steps = gm.visits(sz, m, 32)
+    assert -(-live // 32) <= int(steps) <= -(-live // 32) + g - 1
+    if case == "no_live_row":
+        assert int(steps) == 0
+    assert int(gm.visits(sz, m, 32, empty=True)[3]) >= g * (live == 0)
+
+
+def test_grouped_matmul_refuses_rows_that_are_no_whole_tiles():
+    lhs, rhs = jnp.zeros((520, 8)), jnp.zeros((2, 8, 8))
+    with pytest.raises(ValueError, match="whole tiles"):
+        gm.grouped_matmul(lhs, rhs, jnp.array([1, 2]))
+    with pytest.raises(ValueError, match="whole tiles"):
+        gm.grouped_matmul_t(lhs, lhs, jnp.array([1, 2]))
+    assert gm.row_tile(65536) == 512 and gm.row_tile(320) == 320
+    assert gm.row_tile(330) == 336 and moe.sorted_rows(330) == 336
+    assert moe.sorted_rows(81920) == 81920 and moe.sorted_rows(600) == 1024
+
+
+def _equations(jaxpr, found, outer=""):
+    """Every equation under ``jaxpr`` as ``(primitive, scope path)``; a
+    jitted launcher's equations carry its call site's path before theirs,
+    and a kernel is one equation (its body is not the step's)."""
+    for eqn in jaxpr.eqns:
+        path = f"{outer}/{eqn.source_info.name_stack}"
+        found.append((eqn.primitive.name, path))
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _equations(sub, found, path)
+    return found
+
+
+def test_every_move_is_a_gather_and_there_is_one_path():
+    """The step of a toy layer, forward and backward: no ``scatter`` under
+    ``moe/dispatch`` or ``moe/combine``, no loop under ``moe/`` but those
+    over the chunks that hold a live row (``live_rows``: the gather of the
+    tokens' rows and what is done to the sorted rows between the kernels; no
+    matmul is in one), nothing named ``moe/overflow``, and the grouped
+    matmuls where they belong."""
+    from apex_tpu import models
+    held = (0, 1, 2, 3)
+    layer = models.ExpertFFN(D, F, 16, 4, held, 1.0)
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 40, D))
+    p = layer.init(jax.random.PRNGKey(1), x)["params"]
+    loss = lambda p, x: jnp.sum(layer.apply({"params": p}, x)[0])
+    step = jax.grad(loss, argnums=(0, 1))
+    found = _equations(jax.make_jaxpr(step)(p, x).jaxpr, [])
+    under = lambda scope: [name for name, path in found if scope in path]
+    for scope in ("moe/dispatch", "moe/combine"):
+        assert under(scope) and "gather" in under(scope)
+        assert not [n for n in under(scope) if "scatter" in n], scope
+    loops = [path for name, path in found
+             if "moe/" in path and name in ("while", "scan", "cond")]
+    assert len(loops) == 3 + 5 and all(       # forward, backward
+        path.endswith("live_rows") for path in loops)
+    assert not [name for name, path in found if "live_rows" in path
+                and "apex_unwritten" not in path
+                and name in ("dot_general", "pallas_call", "scatter-add")]
+    assert not under("moe/overflow")
+    kernels = [path for name, path in found if name == "pallas_call"]
+    matmuls = [path for path in kernels if "apex_unwritten" not in path]
+    experts = [path for path in matmuls if "moe/experts" in path]
+    assert len(experts) == 3 + 3 + 2 + 3
+    assert sum("apex_tgmm" in path for path in experts) == 3
+    # the way back to the tokens: a sum of rows as a matmul, each direction
+    back = [path for path in matmuls if path not in experts]
+    assert len(back) == 2 and all("apex_tgmm" in path for path in back)
+    assert sum("moe/combine" in path for path in back) == 1
+    assert sum("moe/dispatch" in path for path in back) == 1
+    # a loop's buffers start unwritten: no pass over every row fills them
+    assert len(kernels) > len(matmuls) and all(
+        "live_rows" in path for path in kernels if path not in matmuls)
+    text = jax.jit(step).lower(p, x).as_text(debug_info=True)
+    assert "moe/experts" in text and "moe/overflow" not in text
+
+
+def test_expert_rows_run_follows_the_routing(monkeypatch):
+    """The counter beside ``rows_routed_here``: visited tiles times a tile's
+    rows, never under the rows routed here and over them by the tiles'
+    rounding alone, whether one expert got everything or all got some."""
+    monkeypatch.setattr(gm, "ROW_TILE", 32)
+    held = (4, 5, 6, 7)
+    run = {}
+    for kind in ("all_to_one_held", "uniform", "every_assignment_held",
+                 "none_to_any"):
+        load = moe.expert_load(routing(kind, held)[0], held)
+        run[kind] = int(moe.expert_rows_run(load, T * K))
+        here = int(load.sum())
+        assert here <= run[kind] <= -(-here // 32) * 32 + 32 * (len(held) - 1)
+        assert run[kind] % 32 == 0
+    assert run["all_to_one_held"] == T == 160      # five whole tiles
+    assert run["none_to_any"] == 0
+    assert run["every_assignment_held"] >= T * K > run["all_to_one_held"]
+    assert run["uniform"] < run["all_to_one_held"]
+    # a row for each layer, as ``lm_loss`` stacks the loads
+    loads = jnp.stack([moe.expert_load(routing(kind, held)[0], held)
+                       for kind in ("uniform", "all_to_one_held")])
+    assert moe.expert_rows_run(loads, T * K).tolist() == [
+        run["uniform"], run["all_to_one_held"]]
 
 
 @pytest.mark.parametrize("scoring", ["sigmoid", "softmax"])
@@ -220,18 +413,22 @@ def test_amp_lists_give_the_new_ops_their_dtypes(level, dtype):
     held = (0, 1, 2, 3)
     x, w_gate, w_up, w_down = weights(5, len(held))
     chosen, w = routing("uniform", held)
-    seen = []
-    real = moe._swiglu
-    try:
-        moe._swiglu = lambda x, *a: seen.append(x.dtype) or real(x, *a)
+    def layer(x, w, w_gate, w_up, w_down):
         with amp.policy_scope(policy):
-            y = moe.held_experts(x, w, chosen, w_gate, w_up, w_down, held, E)
-    finally:
-        moe._swiglu = real
+            return moe.held_experts(x, w, chosen, w_gate, w_up, w_down, held,
+                                    E)
+    y = layer(x, w, w_gate, w_up, w_down)
+
+    def operands(jaxpr, seen):      # of the experts' matmuls, in the trace
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                if "apex_gmm" in str(eqn.params["name"]):
+                    seen += [v.aval.dtype for v in eqn.invars
+                             if v.aval.dtype != jnp.int32]
+                continue
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                operands(sub, seen)
+        return seen
+    seen = operands(jax.make_jaxpr(layer)(x, w, w_gate, w_up, w_down).jaxpr,
+                    [])
     assert seen and set(seen) == {jnp.dtype(dtype)} and y.dtype == jnp.float32
-
-
-def test_capacity_is_eight_even_shares_in_sublanes():
-    assert moe.capacity(8192, 8, 256) == 2048
-    assert moe.capacity(160, 2, 64) == 40
-    assert moe.capacity(8, 1, 64) == 8

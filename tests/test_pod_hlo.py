@@ -665,6 +665,57 @@ def test_gated_short_conv_kernels_compile_for_the_v5e_at_the_cells_shape(
     assert d_taps.shape == taps.shape and d_taps.dtype == jnp.float32
 
 
+@pytest.mark.parametrize("cell,tokens,top_k,routed,n_held,hidden,width,dtype", [
+    ("lfm2", 16384, 4, 64, 8, 2048, 1536, jnp.bfloat16),
+    ("kimi", 8192, 8, 256, 8, 2304, 1024, jnp.bfloat16),
+    ("qwen", 8192, 10, 512, 32, 2048, 512, jnp.bfloat16),
+    ("kimi_float32", 8192, 8, 256, 8, 2304, 1024, jnp.float32),
+])
+def test_the_expert_layer_compiles_for_the_v5e_at_the_cells_shapes(
+        v5e_chip, monkeypatch, cell, tokens, top_k, routed, n_held, hidden,
+        width, dtype):
+    """One rank's expert layer of each decoder cell, forward and backward,
+    through Mosaic: ``apex_gmm`` in its three forms (3 forward, 3 again and 2
+    in the backward) and ``apex_tgmm`` (3 for the weights' gradients, and the
+    two sums over each token's rows: three left operands and a float32
+    result forward, one backward), with the grid's bound, the visits' groups
+    and tiles read on the device. What interpret mode cannot
+    see: the scoped VMEM of the tiles ``_gmm_columns`` / ``_tgmm_tiles``
+    pick (bfloat16 as under O1, and float32), the transposed-lhs ``dot`` of
+    ``apex_tgmm``, the 32-bit select over packed rows. No ``scatter`` is in
+    the compiled layer."""
+    from apex_tpu import amp
+    from apex_tpu.ops import _dispatch, grouped_matmul, moe
+    policy = amp.Policy.from_opt_level(
+        "O1" if dtype == jnp.bfloat16 else "O0")
+    for module in (_dispatch, grouped_matmul):
+        monkeypatch.setattr(module, "use_interpret", lambda: False)
+    shape = lambda *s, dt=dtype: jax.ShapeDtypeStruct(
+        s, dt, sharding=v5e_chip)
+    held = tuple(range(n_held))
+
+    def loss(x, w, chosen, gate, up, down):
+        with amp.policy_scope(policy):
+            return jnp.sum(moe.held_experts(x, w, chosen, gate, up, down,
+                                            held, routed))
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 3, 4, 5))).lower(
+        shape(tokens, hidden), shape(tokens, top_k, dt=jnp.float32),
+        shape(tokens, top_k, dt=jnp.int32), shape(n_held, hidden, width),
+        shape(n_held, hidden, width), shape(n_held, width, hidden)).compile()
+    text = compiled.as_text()
+    calls = lambda kernel: sum(
+        "tpu_custom_call" in line and f"/{kernel}/pallas_call" in line
+        for line in text.splitlines())
+    assert calls("apex_gmm") == 3 + 3 + 2
+    assert calls("apex_tgmm") == 3 + 1 + 1
+    assert " scatter(" not in text
+    _, (d_x, d_w, d_gate, _, d_down) = compiled.out_info
+    assert d_x.shape == (tokens, hidden) and d_x.dtype == dtype
+    assert d_w.shape == (tokens, top_k) and d_w.dtype == jnp.float32
+    assert d_gate.shape == (n_held, hidden, width) and d_gate.dtype == dtype
+    assert d_down.shape == (n_held, width, hidden)
+
+
 def test_the_scan_kernels_read_the_convolution_kernels_for_the_v5e(
         v5e_chip, monkeypatch):
     """One KDA layer at the published head size, compiled for the v5e: in

@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import pytest
 
 from apex_tpu import amp, models
+from apex_tpu.ops import moe
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 
@@ -197,27 +198,14 @@ def test_float32_model_equals_the_reference(toy):
         routing["expert_load"].sum(-1).tolist()
     assert load.tolist() == routing["expert_load"].tolist()
     assert 0 < int(routing["rows_routed_here"][0]) < 2 * LENGTH * 2
-    assert routing["experts_over_capacity"].tolist() == [0] * 4
-
-
-def test_experts_over_capacity_counts_the_overflow_turns(toy, monkeypatch):
-    """With half an even share for a capacity most held experts pass it: the
-    counter is the number that did, a layer, and the loss is what it was (an
-    expert over its capacity runs over every row; no token is dropped)."""
-    from apex_tpu.ops import moe
-    model, params, tokens = toy
-    loss, routing = models.lm_loss(model, {"params": params}, tokens)
-    assert routing["experts_over_capacity"].tolist() == [0] * 4
-    monkeypatch.setattr(moe, "CAPACITY_FACTOR", 0.5)
-    cap = moe.capacity(tokens.size, 2, 16)
-    assert cap == 24 < tokens.size
-    tight, counted = models.lm_loss(model, {"params": params}, tokens)
-    over = counted["experts_over_capacity"]
-    assert over.dtype == jnp.int32 and over.shape == (4,)
-    assert over.tolist() == jnp.sum(counted["expert_load"] > cap, -1).tolist()
-    assert int(over.sum()) >= 4
-    assert counted["expert_load"].tolist() == routing["expert_load"].tolist()
-    assert float(abs(tight - loss)) <= 1e-6 * float(loss)
+    # what the grouped matmul ran on: the rows routed here and the tiles'
+    # rounding, a tile more for each expert at most
+    run, here = routing["expert_rows_run"], routing["rows_routed_here"]
+    tile = moe.row_tile(tokens.size * model.dims.top_k)
+    assert run.shape == (4,) and run.dtype == jnp.int32
+    assert bool(jnp.all(run >= here)) and bool(jnp.all(run % tile == 0))
+    assert bool(jnp.all(run <= -(-here // tile) * tile
+                        + tile * (len(model.dims.held) - 1)))
 
 
 def test_remat_changes_nothing(toy):
