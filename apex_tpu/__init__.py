@@ -45,21 +45,20 @@ CUDA kernels of the reference are Pallas kernels over a flat parameter arena.
 
 __version__ = "0.1.0"
 
-from apex_tpu import amp
-from apex_tpu import arena
-from apex_tpu import ckpt
-from apex_tpu import fp16_utils
-from apex_tpu import guard
-from apex_tpu import lint
-from apex_tpu import monitor
-from apex_tpu import ops
-from apex_tpu import optim
-from apex_tpu import parallel
-from apex_tpu import prof
-from apex_tpu import reparam
-from apex_tpu import trace
-from apex_tpu import utils
+import importlib as _importlib
+import time as _time
 
-__all__ = ["amp", "arena", "ckpt", "fp16_utils", "guard", "lint",
-           "monitor", "ops", "optim", "parallel", "prof", "reparam",
-           "trace", "utils", "__version__"]
+_SUBPACKAGES = ("amp", "arena", "ckpt", "fp16_utils", "guard", "lint",
+                "monitor", "ops", "optim", "parallel", "prof", "reparam",
+                "trace", "utils")
+
+# each subpackage's import is a span of prof.compile_watch's timeline:
+# the clock is read before the first and after each
+_start = _time.perf_counter()
+_ends = []
+for _name in _SUBPACKAGES:
+    _importlib.import_module(f"{__name__}.{_name}")
+    _ends.append((_name, _time.perf_counter()))
+prof.compile_watch.record_import(__name__, _start, _ends)  # noqa: F821
+
+__all__ = [*_SUBPACKAGES, "__version__"]

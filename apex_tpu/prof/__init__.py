@@ -17,9 +17,11 @@ FLOPs analyzers). TPU-native, the same capability is:
   attribution (params / optimizer state / activations / comm) from the
   optimized HLO + ``memory_analysis()``, peak-live estimate, what-if
   batch scaler vs HBM capacity (docs/memory.md);
-- :mod:`~apex_tpu.prof.compile_watch` — trace/lower/compile counters +
-  retrace detector naming the argument whose shape changed (autotune-
-  origin compiles tagged separately via ``autotune_scope``);
+- :mod:`~apex_tpu.prof.compile_watch` — the set-up timeline: import,
+  trace, lower, compile and cache load as spans on one clock
+  (``setup_report``), the counters folded from them, and the retrace
+  detector naming the argument whose shape changed (autotune-origin
+  compiles tagged separately via ``autotune_scope``);
 - :mod:`~apex_tpu.prof.roofline` — per-op efficiency attribution:
   measured device time joined with analytic FLOPs/bytes against the
   chip's peak table, compute/memory bound classes, per-family
@@ -38,7 +40,8 @@ FLOPs analyzers). TPU-native, the same capability is:
 from apex_tpu.prof.annotate import (CallRecord, annotate, annotate_modules,
                                     scope)
 from apex_tpu.prof.compile_watch import (CompileWatcher, FunctionWatch,
-                                         autotune_scope, global_counters)
+                                         SetupReport, autotune_scope,
+                                         global_counters, setup_report)
 from apex_tpu.prof.hlo import (OpEstimate, compiled_hlo, cost_analysis,
                                op_estimates, op_estimates_from_text)
 from apex_tpu.prof.memory import (BufferRecord, MemoryReport,
@@ -63,7 +66,7 @@ __all__ = [
     "MemoryReport", "BufferRecord", "memory_report", "hbm_capacity",
     "device_memory_sample",
     "CompileWatcher", "FunctionWatch", "autotune_scope",
-    "global_counters",
+    "global_counters", "SetupReport", "setup_report",
     "RooflineReport", "RooflineRow", "roofline_report",
     "ShardRecord", "ShardReport", "shard_report",
 ]

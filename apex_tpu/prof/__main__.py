@@ -18,6 +18,11 @@ cover its whole steps — from the start of the second run to the end of the
 last but one, the first and the last may be clipped by the profiler — and
 read in ms per step; otherwise they cover the whole trace.
 
+Where the logdir holds a ``timeline.json`` (``json.dump`` of
+``prof.compile_watch.timeline()``, written by the traced process), the
+last table names each import, trace, lower, compile or cache load that
+lies inside the trace, with the device's idle time under it.
+
 Usage::
 
     python -m apex_tpu.prof /tmp/trace            # top-30 op table + rollups
@@ -28,6 +33,8 @@ Usage::
 from __future__ import annotations
 
 import argparse
+import json
+import os
 import sys
 
 
@@ -84,6 +91,21 @@ def main(argv=None) -> int:
     head = tp.by_head()
     if head:
         _rollup("MLM head, by the branch the steps took", head, steps, total)
+    timeline = os.path.join(args.logdir, "timeline.json")
+    if os.path.isfile(timeline):
+        with open(timeline) as f:
+            found = tp.spans_over_idle(json.load(f))
+        print(f"\n{'set-up span inside the trace':<52} {'ms':>10} "
+              f"{'idle ms':>8}")
+        for span, a, b, idle_us in found:
+            what = f"{span['name']} {span['program'] or ''}"
+            if span.get("cache"):
+                what += f" (cache {span['cache']})"
+            print(f"{what[:52]:<52} {(b - a) / 1e6:>10.3f} "
+                  f"{idle_us / 1e3:>8.3f}")
+        if not found:
+            print("none: nothing was imported, traced, lowered, compiled "
+                  "or loaded from the cache inside it")
     return 0
 
 

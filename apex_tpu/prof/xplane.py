@@ -43,7 +43,7 @@ from typing import Dict, List, Optional, Tuple
 
 __all__ = ["OpRecord", "TraceProfile", "parse_trace", "latest_xplane",
            "COLLECTIVE_PREFIXES", "decode_xspace", "strip_scope",
-           "own_scope", "HLO_TEXT_SCOPE_RE"]
+           "own_scope", "HLO_TEXT_SCOPE_RE", "place"]
 
 # HLO instruction text → opcode: "%fusion.3 = f32[8]{0} fusion(...)" → the
 # first word that opens a paren after a space. The result shape before it
@@ -217,6 +217,43 @@ class TraceProfile:
     _device: Optional["_DeviceEvents"] = dataclasses.field(
         default=None, repr=False, compare=False)
 
+    @property
+    def start_epoch_ns(self) -> Optional[int]:
+        """The epoch nanosecond the file's clock counts from
+        (``profile_start_time`` of its ``Task Environment`` plane), None
+        where it states none."""
+        return self._device and self._device.start_epoch_ns
+
+    def spans_over_idle(self, timeline: Dict
+                        ) -> List[Tuple[Dict, float, float, float]]:
+        """``(span, start_ns, end_ns, idle_us)`` for each span of a
+        ``prof.compile_watch.timeline()`` that lies, in part, inside
+        this profile (its window, else first op to last): the part
+        inside on the profile's clock, and how much of it no op ran on
+        the device. A compile or a cache load found here is what the
+        device waited for."""
+        dev = self._device
+        if (dev is None or not dev.ops or self.start_epoch_ns is None
+                or not timeline.get("anchor")):
+            return []
+        lo, hi = self.window_ns or (min(a for _, a, _b in dev.ops) / 1e3,
+                                    max(b for _, _a, b in dev.ops) / 1e3)
+        found = []
+        for span in timeline["spans"]:
+            a, b = place(span, timeline["anchor"], self.start_epoch_ns)
+            a, b = max(a, lo), min(b, hi)
+            if b <= a:
+                continue
+            busy, covered_to = 0.0, a * 1e3
+            for op_a, op_b in sorted((oa, ob) for _, oa, ob in dev.ops
+                                     if ob > a * 1e3 and oa < b * 1e3):
+                op_a, op_b = max(op_a, covered_to), min(op_b, b * 1e3)
+                if op_b > op_a:
+                    busy += op_b - op_a
+                    covered_to = op_b
+            found.append((span, a, b, (b - a) / 1e3 - busy / 1e6))
+        return found
+
     def window(self, lo_ns: float, hi_ns: float) -> "TraceProfile":
         """The same trace cut to ``[lo_ns, hi_ns]`` on the device's
         clock: an op counts with the part of it that lies inside. A
@@ -305,6 +342,18 @@ class TraceProfile:
         return "\n".join(lines)
 
 
+def place(span: Dict, anchor, start_epoch_ns: int = 0
+          ) -> Tuple[float, float]:
+    """``(start_ns, end_ns)`` of a ``prof.compile_watch`` span on a
+    profile's clock. The span's times are ``time.perf_counter()``
+    seconds; ``anchor`` is the timeline's ``(time.perf_counter_ns(),
+    time.time_ns())`` pair and ``start_epoch_ns`` the epoch nanosecond
+    the profile counts from (:attr:`TraceProfile.start_epoch_ns`)."""
+    perf_ns, epoch_ns = anchor
+    shift = epoch_ns - perf_ns - start_epoch_ns     # whole numbers: exact
+    return span["start"] * 1e9 + shift, span["end"] * 1e9 + shift
+
+
 def latest_xplane(logdir: str) -> Optional[str]:
     """Newest ``*.xplane.pb`` under a profiler logdir, or None."""
     files = glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
@@ -318,7 +367,8 @@ def latest_xplane(logdir: str) -> Optional[str]:
 # (tag = field_no << 3 | wire_type, payload) pair; messages are
 # length-delimited. This decoder covers exactly the XSpace subset
 # parse_trace consumes (field numbers pinned against the tsl proto:
-# XSpace.planes=1; XPlane.name=2/lines=3/event_metadata=4/stat_metadata=5,
+# XSpace.planes=1; XPlane.name=2/lines=3/event_metadata=4/stat_metadata=5/
+# stats=6,
 # both maps with entries key=1/value=2; XLine.name=2/timestamp_ns=3/
 # events=4; XEvent.metadata_id=1/offset_ps=2/duration_ps=3;
 # XEventMetadata.id=1/name=2/display_name=4/stats=5; XStatMetadata.id=1/
@@ -458,8 +508,11 @@ def _decode_stat_metadata(buf: bytes) -> _Msg:
 
 def _decode_plane(buf: bytes) -> _Msg:
     plane = _Msg(name="", lines=[], event_metadata={}, stat_metadata={})
+    raw_stats = []
     for fno, _wt, v in _fields(buf):
-        if fno == 2:
+        if fno == 6:
+            raw_stats.append(_decode_stat(v))
+        elif fno == 2:
             plane.name = _text(v)
         elif fno == 3:
             plane.lines.append(_decode_line(v))
@@ -473,6 +526,8 @@ def _decode_plane(buf: bytes) -> _Msg:
                 plane.stat_metadata[key or sm.id] = sm.name
     # the stats by name, a reference resolved to the name it points at
     names = plane.stat_metadata
+    plane.stats = {names.get(sid, str(sid)): value
+                   for sid, value, _is_ref in raw_stats}
     for md in plane.event_metadata.values():
         md.stats = {names.get(sid, str(sid)):
                     (names.get(value, "") if is_ref else value)
@@ -529,6 +584,7 @@ class _DeviceEvents:
     metadata: Dict[int, _Msg]
     ops: List[Tuple[int, int, int]]       # (metadata id, start_ps, end_ps)
     modules: List[Tuple[int, int, int]]
+    start_epoch_ns: Optional[int] = None
 
 
 def _events(line: Optional[_Msg]) -> List[Tuple[int, int, int]]:
@@ -641,5 +697,8 @@ def parse_trace(logdir_or_file: str, device_index: int = 0) -> TraceProfile:
     dev = _DeviceEvents(
         name=plane.name, metadata=plane.event_metadata,
         ops=_events(lines.get("XLA Ops")),
-        modules=_events(lines.get("XLA Modules")))
+        modules=_events(lines.get("XLA Modules")),
+        start_epoch_ns=next(
+            (p.stats["profile_start_time"] for p in xs.planes
+             if "profile_start_time" in p.stats), None))
     return _aggregate(path, dev, None)
