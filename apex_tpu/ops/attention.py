@@ -146,7 +146,13 @@ def _tile_valid(iq, ik, bq, bk, kv_len, q_len, causal, off, *,
     ``kv_len % bk``, the q-row-pad term iff ``need_rows and q_len %
     bq``; only the causal frontier is inherently dynamic. ONE
     definition for all four native-layout kernels — each retained term
-    costs a full-tile iota/compare/AND VPU sweep."""
+    costs a full-tile iota/compare/AND VPU sweep.
+
+    The multi-block kernels call this only for a tile that
+    :class:`_Frontier` lets run (one that the frontier crosses or that
+    lies below it); a tile wholly above the frontier, where this mask
+    would be all-false, is skipped before it. A tile wholly below the
+    frontier still builds its all-true causal term here."""
     valid = None
 
     def land(a, b):
@@ -160,6 +166,103 @@ def _tile_valid(iq, ik, bq, bk, kv_len, q_len, causal, off, *,
         rows = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + iq * bq
         valid = land(valid, rows < q_len)
     return valid
+
+
+class _Frontier(NamedTuple):
+    """The causal frontier of an (nq, nk) grid of (bq, bk) tiles, in tile
+    units: tile ``(iq, ik)`` holds an entry that :func:`_causal_mask`
+    keeps iff its first column is at or left of its last row's frontier,
+    ``ik·bk <= iq·bq + bq − 1 + off``. ``off`` is the Python int ``kv_len −
+    q_len`` for a static frontier, or the traced ``causal_offset`` read
+    from SMEM inside a kernel. Every other tile is masked whole: the
+    kernels run no arithmetic for it (:func:`_when_tile_runs`) and, where
+    ``off`` is static, the BlockSpec maps name no new block for it
+    (``k_block`` / ``q_block``), so nothing is fetched either."""
+    bq: int
+    bk: int
+    nq: int
+    nk: int
+    off: object
+
+    def runs(self, iq, ik):
+        return ik * self.bk <= iq * self.bq + (self.bq - 1) + self.off
+
+    def last_k(self, iq):
+        """The last k tile that q tile ``iq`` runs (below 0: none)."""
+        return (iq * self.bq + (self.bq - 1) + self.off) // self.bk
+
+    def first_q(self, ik):
+        """The first q tile that k tile ``ik`` runs (``nq`` or more:
+        none)."""
+        return (ik * self.bk - self.off) // self.bq
+
+    def k_block(self, iq, ik):
+        """Index-map form, k innermost: the skipped steps trail a row, and
+        name the block its last running step holds (no DMA)."""
+        return jnp.minimum(ik, jnp.maximum(self.last_k(iq), 0))
+
+    def q_block(self, iq, ik):
+        """Index-map form, q innermost: the skipped steps lead a column,
+        and name the block its first running step will fetch."""
+        return jnp.maximum(iq, jnp.minimum(self.first_q(ik), self.nq - 1))
+
+    def tiles_run(self):
+        """Tiles of the grid that run, a head group (static ``off``)."""
+        return sum(min(max(self.last_k(iq) + 1, 0), self.nk)
+                   for iq in range(self.nq))
+
+
+def _frontier(causal, bq, bk, q_len, kv_len, off) -> Optional[_Frontier]:
+    """The frontier the native multi-block kernels skip by, or None where
+    nothing is skipped: no causal mask, or one tile in all (the forward's
+    single-k form and the fused backward never ask). ONE definition for
+    the kernels' predicate, the wrappers' index maps and the count of
+    tiles run (tests/test_attention.py disables the skip by patching
+    this to return None)."""
+    nq, nk = -(-q_len // bq), -(-kv_len // bk)
+    if not causal or nq * nk == 1:
+        return None
+    return _Frontier(bq, bk, nq, nk, off)
+
+
+def _when_tile_runs(fr, iq, ik):
+    """Decorator for a tile's arithmetic: under the one ``pl.when`` of the
+    skip where there is a frontier, called as it is where there is
+    none."""
+    if fr is None:
+        return lambda body: body()
+    return pl.when(fr.runs(iq, ik))
+
+
+def _kernel_frontier(causal, q_ref, k_ref, q_len, kv_len, off_ref):
+    """A kernel's frontier, from its q and k blocks; ``off_ref`` is the
+    traced offset's SMEM ref (causal calls alone have one), or None for
+    the static ``kv_len − q_len``."""
+    off = kv_len - q_len if off_ref is None else off_ref[0]
+    return _frontier(causal, q_ref.shape[1], k_ref.shape[1], q_len, kv_len,
+                     off)
+
+
+def _group_id_outside(fr, dropout_rate):
+    """Grid axis 0's id for the dropout hash, read ahead of the skip's
+    branch (a program id is not read inside one); None where there is no
+    branch, and :func:`_group_id` reads it in place as it always has."""
+    if fr is None or dropout_rate == 0.0:
+        return None
+    return pl.program_id(0)
+
+
+def _group_id(t):
+    return pl.program_id(0) if t is None else t
+
+
+def _causal_tiles(bq, bk, q_len, kv_len, causal):
+    """(tiles run, tiles in the grid) a head group, for a call without a
+    traced ``causal_offset``: what ``_BwdPlan.tiles`` and the forward's
+    grid are read by."""
+    fr = _frontier(causal, bq, bk, q_len, kv_len, kv_len - q_len)
+    grid = -(-q_len // bq) * -(-kv_len // bk)
+    return (grid if fr is None else fr.tiles_run()), grid
 
 
 def _keep_mask(seed, iq, ik, bq, bk, rate, gb=None):
@@ -731,8 +834,11 @@ def _fwd_kernel_nl(scale, causal, kv_len, q_len, dropout_rate, d, g,
             l_scr[:] = jnp.zeros_like(l_scr)
             acc[:] = jnp.zeros_like(acc)
 
-    for h in range(g):
-        sl = slice(h * d, (h + 1) * d)
+    fr = None if single_k else _kernel_frontier(
+        causal, q_ref, k_ref, q_len, kv_len, off_ref)
+    t = _group_id_outside(fr, dropout_rate)
+
+    def tile(h, sl):
         q, k, v = q_ref[0][:, sl], k_ref[0][:, sl], v_ref[0][:, sl]
         bq, bk = q.shape[0], k.shape[0]
 
@@ -773,7 +879,7 @@ def _fwd_kernel_nl(scale, causal, kv_len, q_len, dropout_rate, d, g,
             lse_ref[h * bq:(h + 1) * bq] = \
                 (m_new + jnp.log(safe_l)) \
                 + jnp.zeros((bq, lse_ref.shape[1]), jnp.float32)
-            continue
+            return
 
         m_prev = m_scr[h][:, :1]
         l_prev = l_scr[h][:, :1]
@@ -785,13 +891,21 @@ def _fwd_kernel_nl(scale, causal, kv_len, q_len, dropout_rate, d, g,
         if dropout_rate > 0.0:
             iqo, iko = _dbo_shift(iq, ik, dbo_ref, has_dbo)
             keep = _keep_mask(seed_ref[0], iqo, iko, bq, bk, dropout_rate,
-                              gb=pl.program_id(0) * g + h)
+                              gb=_group_id(t) * g + h)
             pd = jnp.where(keep, p * (1.0 / (1.0 - dropout_rate)), 0.0)
         acc[0, :, sl] = acc[0][:, sl] * alpha + jax.lax.dot_general(
             pd.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
         l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
+
+    for h in range(g):
+        sl = slice(h * d, (h + 1) * d)
+        # a row's skipped tiles trail it: the init above and the write-out
+        # below stay outside the skip
+        _when_tile_runs(fr, iq, ik)(functools.partial(tile, h, sl))
+        if single_k:
+            continue
 
         @pl.when(ik == nk - 1)
         def _(h=h, sl=sl):
@@ -804,17 +918,21 @@ def _fwd_kernel_nl(scale, causal, kv_len, q_len, dropout_rate, d, g,
                 + jnp.zeros((bq_, lse_ref.shape[1]), jnp.float32)
 
 
-def _head_specs(nh, g, bq, bk, gd):
-    """(q, k) BlockSpecs over (B, S, H) with head columns in the lane
-    axis; grid dim 0 enumerates (batch, head-group) pairs group-minor."""
+def _head_specs(nh, g, bq, bk, gd, fr=None):
+    """(q, k, bias index map) over (B, S, H) with head columns in the lane
+    axis; grid dim 0 enumerates (batch, head-group) pairs group-minor,
+    k innermost. Under a static frontier ``fr`` a skipped step's k / v /
+    bias block is the one its row's last running step holds: no fetch."""
     hg = nh // g
+    kb = (lambda i, j: j) if fr is None else fr.k_block
     q_spec = pl.BlockSpec((1, bq, gd),
                           lambda t, i, j: (t // hg, i, t % hg),
                           memory_space=pltpu.VMEM)
     k_spec = pl.BlockSpec((1, bk, gd),
-                          lambda t, i, j: (t // hg, j, t % hg),
+                          lambda t, i, j: (t // hg, kb(i, j), t % hg),
                           memory_space=pltpu.VMEM)
-    return q_spec, k_spec
+    bias_idx = lambda row: lambda t, i, j: (row(t), i, kb(i, j))
+    return q_spec, k_spec, bias_idx
 
 
 def _lse_reorder(lse_rows, bh, g, nq, bq):
@@ -899,7 +1017,11 @@ def _flash_fwd_nl(q2, k2, v2, nh, d, scale, causal, block_q, block_k,
                   bias_per_head=bias_per_head, carry_scratch=nk > 1)
     gd = g * d
     hg = nh // g
-    q_spec, k_spec = _head_specs(nh, g, bq, bk, gd)
+    # the single-k kernel has no k loop to skip in; a traced offset skips
+    # the arithmetic alone (the maps cannot read it)
+    fr = (None if nk == 1 or causal_off is not None
+          else _frontier(causal, bq, bk, sq, sk, sk - sq))
+    q_spec, k_spec, bias_idx = _head_specs(nh, g, bq, bk, gd, fr)
     in_specs = [q_spec, k_spec, k_spec]
     args = [qp, kp, vp]
     _append_common(
@@ -907,7 +1029,7 @@ def _flash_fwd_nl(q2, k2, v2, nh, d, scale, causal, block_q, block_k,
         bias_p=(None if bias_g is None
                 else _pad_bias_nl(bias_g, sqp, skp)),
         bias_mode=bias_mode, g=g, hg=hg, bias_dims=(bq, bk),
-        bias_idx=lambda row: lambda t, i, j: (row(t), i, j),
+        bias_idx=bias_idx,
         dropout_rate=dropout_rate, seed=seed, dbo=dbo,
         causal_off=causal_off)
 
@@ -954,8 +1076,10 @@ def _bwd_dq_kernel_nl(scale, causal, kv_len, q_len, dropout_rate, d, g,
     def _():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    for h in range(g):
-        sl = slice(h * d, (h + 1) * d)
+    fr = _kernel_frontier(causal, q_ref, k_ref, q_len, kv_len, off_ref)
+    t = _group_id_outside(fr, dropout_rate)
+
+    def tile(h, sl):
         q, k, v = q_ref[0][:, sl], k_ref[0][:, sl], v_ref[0][:, sl]
         do = do_ref[0][:, sl]
         bq, bk = q.shape[0], k.shape[0]
@@ -978,12 +1102,17 @@ def _bwd_dq_kernel_nl(scale, causal, kv_len, q_len, dropout_rate, d, g,
         if dropout_rate > 0.0:
             iqo, iko = _dbo_shift(iq, ik, dbo_ref, has_dbo)
             keep = _keep_mask(seed_ref[0], iqo, iko, bq, bk, dropout_rate,
-                              gb=pl.program_id(0) * g + h)
+                              gb=_group_id(t) * g + h)
             dp = jnp.where(keep, dp * (1.0 / (1.0 - dropout_rate)), 0.0)
         ds = (p * (dp - delta)).astype(q.dtype)
         dq_acc[0, :, sl] = dq_acc[0][:, sl] + jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
+
+    for h in range(g):
+        sl = slice(h * d, (h + 1) * d)
+        # as the forward: the skipped tiles trail a row
+        _when_tile_runs(fr, iq, ik)(functools.partial(tile, h, sl))
 
         @pl.when(ik == nk - 1)
         def _(sl=sl):
@@ -1005,8 +1134,10 @@ def _bwd_dkv_kernel_nl(scale, causal, kv_len, q_len, dropout_rate, d, g,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    for h in range(g):
-        sl = slice(h * d, (h + 1) * d)
+    fr = _kernel_frontier(causal, q_ref, k_ref, q_len, kv_len, off_ref)
+    t = _group_id_outside(fr, dropout_rate)
+
+    def tile(h, sl):
         q, k, v = q_ref[0][:, sl], k_ref[0][:, sl], v_ref[0][:, sl]
         do = do_ref[0][:, sl]
         bq, bk = q.shape[0], k.shape[0]
@@ -1031,7 +1162,7 @@ def _bwd_dkv_kernel_nl(scale, causal, kv_len, q_len, dropout_rate, d, g,
         if dropout_rate > 0.0:
             iqo, iko = _dbo_shift(iq, ik, dbo_ref, has_dbo)
             keep = _keep_mask(seed_ref[0], iqo, iko, bq, bk, dropout_rate,
-                              gb=pl.program_id(0) * g + h)
+                              gb=_group_id(t) * g + h)
             inv_keep = 1.0 / (1.0 - dropout_rate)
             pv = jnp.where(keep, p * inv_keep, 0.0)
             dp = jnp.where(keep, dp * inv_keep, 0.0)
@@ -1042,6 +1173,13 @@ def _bwd_dkv_kernel_nl(scale, causal, kv_len, q_len, dropout_rate, d, g,
         dk_acc[0, :, sl] = dk_acc[0][:, sl] + jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
+
+    # a column's skipped tiles lead it: the init above and the write-out
+    # below stay outside the skip
+    @_when_tile_runs(fr, iq, ik)
+    def _():
+        for h in range(g):
+            tile(h, slice(h * d, (h + 1) * d))
 
     @pl.when(iq == nq - 1)
     def _():
@@ -1203,6 +1341,13 @@ class _BwdPlan(NamedTuple):
     vmem_limit: Optional[int]   # None: Mosaic's 16 MiB default
     form: str                   # "fused" | "two_kernel" | "two_kernel_raised"
 
+    def tiles(self, sq, sk, causal):
+        """(tiles_run, tiles_grid) a head group, in each of the backward's
+        kernels (the forward's come from :func:`_causal_tiles` at its own
+        tiles): under a static causal frontier the tiles above it run
+        nothing."""
+        return _causal_tiles(self.bq, self.bk, sq, sk, causal)
+
 
 def _bwd_plan(nh, d, sq, sk, bh, itemsize, block_q, block_k, *,
               dropout_rate=0.0, bias_isz=0, bias_per_head=False,
@@ -1333,7 +1478,9 @@ def _flash_bwd_nl(q2, k2, v2, nh, d, lse, delta, do2, scale, causal,
     delta_l = _lanes_nl(delta, bh, g, nq, bq, sq)
 
     hg = nh // g
-    q_spec, k_spec = _head_specs(nh, g, bq, bk, gd)
+    fr = (None if causal_off is not None
+          else _frontier(causal, bq, bk, sq, sk, sk - sq))
+    q_spec, k_spec, bias_idx = _head_specs(nh, g, bq, bk, gd, fr)
     lane_spec = pl.BlockSpec((g * bq, LANES),
                              lambda t, i, j: (t * nq + i, 0),
                              memory_space=pltpu.VMEM)
@@ -1343,8 +1490,7 @@ def _flash_bwd_nl(q2, k2, v2, nh, d, lse, delta, do2, scale, causal,
     args = [qp, kp, vp]
     _append_common(
         in_specs, args, bias_p=bias_p, bias_mode=bias_mode, g=g, hg=hg,
-        bias_dims=(bq, bk),
-        bias_idx=lambda row: lambda t, i, j: (row(t), i, j),
+        bias_dims=(bq, bk), bias_idx=bias_idx,
         dropout_rate=dropout_rate, seed=seed, dbo=dbo,
         causal_off=causal_off)
     in_specs += [q_spec, lane_spec, lane_spec]
@@ -1368,22 +1514,24 @@ def _flash_bwd_nl(q2, k2, v2, nh, d, lse, delta, do2, scale, causal,
         **extra,
     )(*args)
 
-    # dk/dv: grid loops q innermost
+    # dk/dv: grid loops q innermost; a skipped step's q / do / lse /
+    # delta / bias block is the one its column's first running step takes
+    qb = (lambda i, j: i) if fr is None else fr.q_block
     q_spec_k = pl.BlockSpec((1, bq, gd),
-                            lambda t, j, i: (t // hg, i, t % hg),
+                            lambda t, j, i: (t // hg, qb(i, j), t % hg),
                             memory_space=pltpu.VMEM)
     k_spec_k = pl.BlockSpec((1, bk, gd),
                             lambda t, j, i: (t // hg, j, t % hg),
                             memory_space=pltpu.VMEM)
     lane_spec_k = pl.BlockSpec((g * bq, LANES),
-                               lambda t, j, i: (t * nq + i, 0),
+                               lambda t, j, i: (t * nq + qb(i, j), 0),
                                memory_space=pltpu.VMEM)
     in_specs2 = [q_spec_k, k_spec_k, k_spec_k]
     args2 = [qp, kp, vp]
     _append_common(
         in_specs2, args2, bias_p=bias_p, bias_mode=bias_mode, g=g,
         hg=hg, bias_dims=(bq, bk),
-        bias_idx=lambda row: lambda t, j, i: (row(t), i, j),
+        bias_idx=lambda row: lambda t, j, i: (row(t), qb(i, j), j),
         dropout_rate=dropout_rate, seed=seed, dbo=dbo,
         causal_off=causal_off)
     in_specs2 += [q_spec_k, lane_spec_k, lane_spec_k]
@@ -1441,6 +1589,16 @@ def flash_attention(q, k, v, bias=None, scale=None, causal=False,
     path the offset rides SMEM into the kernels so ring hops need no
     O(S²) additive bias; geometries that fall back to the bias path
     build the mask from the offset internally.
+
+    A causal call over more than one tile runs, on the native path, only
+    the tiles at or below the frontier: tile ``(iq, ik)`` of ``(block_q,
+    block_k)`` entries runs iff ``ik·block_k <= iq·block_q + block_q − 1 +
+    offset`` (:class:`_Frontier`); the others do no arithmetic and, with
+    the static offset ``Sk − Sq``, fetch no block (the index maps repeat
+    the neighbouring running step's). A traced ``causal_offset`` skips
+    the arithmetic and keeps its fetches. The results are the full grid's
+    bit for bit; a q tile that runs no k tile (a ring hop wholly in the
+    future) reads ``o = 0`` and ``lse ≈ −1e30``.
     """
     if k.shape[2] != q.shape[2]:
         k, v = (jnp.repeat(x, q.shape[2] // x.shape[2], 2) for x in (k, v))

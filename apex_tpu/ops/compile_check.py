@@ -474,6 +474,119 @@ def _():
     _gqa_cell_case(2, 32, 8, 64)
 
 
+@contextlib.contextmanager
+def _no_causal_skip():
+    """The native kernels as they were before they skipped a causal tile:
+    every grid step runs and fetches (the frontier helper answers None)."""
+    from apex_tpu.ops import attention
+    real = attention._frontier
+    attention._frontier = lambda *a: None
+    try:
+        yield
+    finally:
+        attention._frontier = real
+
+
+def _causal_skip_case(batch, heads, kv_heads, d, t=8192, tiles=()):
+    """A cell's causal call over all its tokens, forward and the three
+    gradients, with the tiles above the frontier skipped and not fetched
+    against the same kernels running their whole grid: a skipped tile would
+    have added ``p = 0`` under ``alpha = 1``, so the two agree bit for bit
+    (a zero's sign aside). The oracle cases hold a prefix of the rows; this
+    one holds every row, the last q tiles' long k loops among them."""
+    from apex_tpu.ops.attention import (DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q,
+                                        _causal_tiles, flash_attention)
+    q = _rand((batch, t, heads, d), 0, jnp.bfloat16, 0.5)
+    k = _rand((batch, t, kv_heads, d), 1, jnp.bfloat16, 0.5)
+    v = _rand((batch, t, kv_heads, d), 2, jnp.bfloat16, 0.5)
+    w = _rand((batch, t, heads, d), 3, jnp.float32, 0.5)
+    run, grid = _causal_tiles(*(tiles or (DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K)),
+                              t, t, True)
+    assert run < grid, (run, grid)
+
+    def both():
+        # a fresh function a variant: the frontier is consulted while the
+        # kernels are traced
+        def loss(q, k, v, w):
+            o = flash_attention(q, k, v, None, d ** -0.5, True, *tiles)
+            return jnp.sum(o.astype(jnp.float32) * w), o
+        return jax.jit(lambda *a: jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True)(*a))(q, k, v, w)
+
+    (_, o), grads = both()
+    with _no_causal_skip():
+        (_, o_all), grads_all = both()
+    for name, a, b in zip(("o", "dq", "dk", "dv"), (o, *grads),
+                          (o_all, *grads_all)):
+        a, b = (np.asarray(x, np.float32) for x in (a, b))
+        assert np.all(np.isfinite(a)), name
+        assert np.array_equal(a, b), (
+            f"{name}: {run} of {grid} tiles differ from the whole grid by "
+            f"{float(np.max(np.abs(a - b))):.3e}")
+
+
+@case("attention/causal-skip-mla-d192-s8192-cell")
+def _():
+    # Kimi's latent attention: 32 heads of 192 at the 1024 x 256 tiles
+    # models/kimi_linear.py passes, two heads a step: 144 of 256 tiles run
+    _causal_skip_case(1, 32, 32, 192, tiles=(1024, 256))
+
+
+@case("attention/causal-skip-gqa-d64-s8192-cell")
+def _():
+    # the cell named: two sequences, 32 q heads on 8 k/v heads of 64, two
+    # heads a step: 36 of 64 tiles run
+    _causal_skip_case(2, 32, 8, 64)
+
+
+@case("attention/causal-skip-gqa-d256-s8192-cell")
+def _():
+    # Qwen3-Next's: 16 q heads on 2 k/v heads of 256, a head a step: 36 of 64
+    _causal_skip_case(1, 16, 2, 256)
+
+
+def _causal_skip_reach_case(t=2048, tile=1024, heads=4, d=64, sharding=None):
+    """``attention/causal-skip-no-extra-dispatch``: the skip reaches the
+    causal multi-block kernels and nothing else. With the frontier helper
+    answering None (the kernels as they were) the lowered program of a
+    non-causal multi-block call, of a single-block causal call (the
+    single-k forward and the fused backward) and of a single-block
+    non-causal one is the same text; the causal multi-block call's is
+    not. ``sharding`` lowers for a described device (tests/test_pod_hlo.py:
+    the Mosaic payloads compared without a chip)."""
+    from apex_tpu.ops.attention import flash_attention
+
+    def lowered(causal, s):
+        x = jax.ShapeDtypeStruct((1, s, heads, d), jnp.bfloat16,
+                                 sharding=sharding)
+
+        def loss(q, k, v):
+            return jnp.sum(flash_attention(
+                q, k, v, None, None, causal, tile, tile).astype(jnp.float32))
+        return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            x, x, x).as_text()
+
+    for causal, s in ((False, t), (True, tile), (False, tile), (True, t)):
+        texts = []
+        for form in (contextlib.nullcontext, _no_causal_skip):
+            with form():
+                # ONE call site for both forms: the text records the Python
+                # stack it was traced under, down to the Mosaic kernels'
+                # payloads
+                texts.append(lowered(causal, s))
+        if causal and s > tile:
+            assert texts[0] != texts[1], (
+                "the causal multi-block kernels skip nothing")
+        else:
+            assert texts[0] == texts[1], (
+                f"the causal skip changed causal={causal} at {s} tokens")
+
+
+@case("attention/causal-skip-no-extra-dispatch")
+def _():
+    _causal_skip_reach_case()
+
+
 # --- gated delta rule --------------------------------------------------------
 
 def _delta_rule_cell_case(t=8192, prefix=512, precision=None):

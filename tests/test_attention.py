@@ -644,6 +644,176 @@ class TestCausalOffset:
             np.testing.assert_allclose(a, b, atol=5e-4, rtol=1e-3)
 
 
+def _causal_case(id_, sq=512, sk=None, nh=2, d=64, tiles=(128, 128),
+                 off=None, **kw):
+    return pytest.param(dict(sq=sq, sk=sk or sq, nh=nh, d=d, tiles=tiles,
+                             off=off, kw=kw), id=id_)
+
+
+class TestCausalTileSkip:
+    """The native multi-block kernels run no arithmetic for a causal tile
+    that lies wholly above the frontier (``_Frontier``), and with a static
+    offset their index maps fetch nothing for it. A skipped tile would have
+    added ``p = 0`` under ``alpha = 1``: forward and the three gradients
+    are the unskipped kernels' bit for bit (a zero's sign aside), which is
+    what every case holds them to, the skip disabled by patching
+    ``_frontier``."""
+
+    @staticmethod
+    def _run(case, rng, bias=None):
+        sq, sk, nh, d = (case[k] for k in ("sq", "sk", "nh", "d"))
+        q = jnp.asarray(rng.randn(1, sq, nh, d), jnp.float32)
+        k = jnp.asarray(rng.randn(1, sk, nh, d), jnp.float32)
+        v = jnp.asarray(rng.randn(1, sk, nh, d), jnp.float32)
+        w = jnp.asarray(rng.randn(1, sq, nh, d), jnp.float32)
+        bq, bk = case["tiles"]
+
+        def both(q, k, v, off):
+            def loss(q, k, v):
+                o, lse = A.flash_attention_lse(
+                    q, k, v, bias, None, True, bq, bk, causal_offset=off,
+                    **case["kw"])
+                return jnp.sum(o * w) + 0.01 * jnp.sum(
+                    jnp.where(lse > -1e29, lse, 0.0)), (o, lse)
+            (_, out), grads = jax.value_and_grad(
+                loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+            return (*out, *grads)
+
+        off = case["off"]
+        if off is None:
+            return jax.jit(lambda q, k, v: both(q, k, v, None))(q, k, v)
+        return jax.jit(both)(q, k, v, jnp.int32(off))
+
+    @pytest.mark.parametrize("case", [
+        _causal_case("square-128x128-g2-d64"),
+        _causal_case("square-256x128-g2-d64", tiles=(256, 128)),
+        _causal_case("square-128x256-g2-d192", d=192, tiles=(128, 256)),
+        _causal_case("square-256x128-g1-d256", nh=1, d=256,
+                     tiles=(256, 128)),
+        _causal_case("square-128x128-g1-d128", nh=3, d=128),
+        _causal_case("kv-longer-bottom-right", sq=256, sk=512),
+        _causal_case("q-longer-bottom-right", sq=512, sk=256),
+        _causal_case("padded-q-and-kv-450", sq=450),
+        _causal_case("padded-kv-only", sq=384, sk=450),
+        _causal_case("dropout", dropout_rate=0.2, dropout_seed=5),
+        _causal_case("dropout-256x128", tiles=(256, 128), dropout_rate=0.1,
+                     dropout_seed=9),
+        _causal_case("bias-per-head", bias="head"),
+        _causal_case("bias-shared-256x128", tiles=(256, 128),
+                     bias="shared"),
+        _causal_case("traced-offset-zero", off=0),
+        _causal_case("traced-offset-plus-64", off=64),
+        _causal_case("traced-offset-plus-200", off=200),
+        _causal_case("traced-offset-plus-4096", off=4096),
+        _causal_case("traced-offset-minus-64", off=-64),
+        _causal_case("traced-offset-minus-200-256x128", off=-200,
+                     tiles=(256, 128)),
+        _causal_case("traced-offset-minus-200-d192", off=-200, d=192,
+                     tiles=(128, 256)),
+    ])
+    def test_bit_identical_to_the_unskipped_kernels(self, case, monkeypatch):
+        case = dict(case, kw=dict(case["kw"]))
+        sq, sk, nh = case["sq"], case["sk"], case["nh"]
+        bias = case["kw"].pop("bias", None)
+        if bias is not None:
+            bias = jnp.asarray(np.random.RandomState(3).randn(
+                1, nh if bias == "head" else 1, sq, sk), jnp.float32) * 0.3
+        frontiers = []
+        real = A._frontier
+
+        def spy(*a):
+            frontiers.append(real(*a))
+            return frontiers[-1]
+
+        monkeypatch.setattr(A, "_frontier", spy)
+        got = self._run(case, np.random.RandomState(21), bias)
+        # the forward and both backward kernels each asked and were given one
+        assert sum(f is not None for f in frontiers) >= 3
+        monkeypatch.setattr(A, "_frontier", lambda *a: None)
+        want = self._run(case, np.random.RandomState(21), bias)
+
+        off = sk - sq if case["off"] is None else case["off"]
+        seen = np.arange(sq) + off >= 0     # rows with a key to attend
+        for name, a, b in zip(("o", "lse", "dq", "dk", "dv"), got, want):
+            a, b = np.asarray(a), np.asarray(b)
+            assert np.all(np.isfinite(a)), name
+            if name == "o":
+                a, b = a[:, seen], b[:, seen]
+            elif name == "lse":
+                # a row with no key: out of contract but for lse ~ NEG_INF
+                assert np.all(a[..., ~seen] < -1e29)
+                a, b = a[..., seen], b[..., seen]
+            assert np.array_equal(a, b), name
+
+    @pytest.mark.parametrize("off", [-512, -640])
+    def test_a_hop_wholly_in_the_future_is_zeros(self, off):
+        """A ring hop whose keys all lie after its queries: every tile is
+        skipped, the write-out finds the init's ``l = 0`` and ``m =
+        NEG_INF``, which is the single-block kernel's answer too."""
+        rng = np.random.RandomState(4)
+        q, k, v = rand_qkv(rng, 1, 512, 2, 64)
+
+        def fn(q, k, v, off_):
+            return A.flash_attention_lse(q, k, v, None, None, True, 128,
+                                         128, causal_offset=off_)
+
+        o, lse = jax.jit(fn)(q, k, v, jnp.int32(off))
+        assert not np.any(np.asarray(o))
+        assert np.all(np.asarray(lse) < -1e29)
+        grads = jax.jit(jax.grad(
+            lambda q, k, v, off_: jnp.sum(fn(q, k, v, off_)[0] ** 2),
+            argnums=(0, 1, 2)))(q, k, v, jnp.int32(off))
+        for g in grads:
+            assert not np.any(np.asarray(g))
+
+    @pytest.mark.parametrize("traced", [False, True])
+    def test_skipped_tiles_take_no_part(self, traced):
+        """Keys and values that a row meets in skipped tiles alone may be
+        anything: a NaN there does not reach the row (under the unskipped
+        kernels ``p = 0`` times a NaN value is a NaN). Static frontier: the
+        last k tile's NaNs stay out of the q tiles before the last. Traced,
+        at 0 over 256 queries and 512 keys: the k tiles 2 and 3 reach no
+        row."""
+        rng = np.random.RandomState(8)
+        sq, hole, clean = (256, 256, 256) if traced else (512, 384, 384)
+        q, k, v = rand_qkv(rng, 1, sq, 2, 64, sk=512)
+        nan = jnp.arange(512)[None, :, None, None] >= hole
+        k, v = (jnp.where(nan, jnp.nan, x) for x in (k, v))
+        kw = dict(causal_offset=jnp.int32(0)) if traced else {}
+        out = jax.jit(lambda q, k, v: A.flash_attention(
+            q, k, v, None, None, True, 128, 128, **kw))(q, k, v)
+        assert np.all(np.isfinite(np.asarray(out)[:, :clean]))
+
+    @pytest.mark.parametrize("bq,bk,nq,nk", [
+        (128, 128, 4, 4), (256, 128, 2, 4), (128, 256, 4, 2),
+        (1024, 256, 8, 32), (8, 128, 5, 3), (128, 384, 7, 2)])
+    @pytest.mark.parametrize("off", [0, 1, 127, 128, 300, -1, -128, -300,
+                                     10 ** 6, -10 ** 6])
+    def test_frontier_in_tile_units(self, bq, bk, nq, nk, off):
+        """``last_k``, ``first_q``, the two index-map clamps and the count
+        against the one predicate, and the predicate against the mask."""
+        fr = A._Frontier(bq, bk, nq, nk, off)
+        rows = np.arange(nq * bq)[:, None] + off
+        cols = np.arange(nk * bk)[None, :]
+        kept = (rows >= cols).reshape(nq, bq, nk, bk).any((1, 3))
+        runs = np.array([[bool(fr.runs(i, j)) for j in range(nk)]
+                         for i in range(nq)])
+        assert np.array_equal(runs, kept)
+        assert fr.tiles_run() == kept.sum()
+        for i in range(nq):
+            assert np.array_equal(runs[i], np.arange(nk) <= fr.last_k(i))
+        for j in range(nk):
+            assert np.array_equal(runs[:, j], np.arange(nq) >= fr.first_q(j))
+        i, j = np.arange(nq)[:, None], np.arange(nk)[None, :]
+        kb, qb = np.asarray(fr.k_block(i, j)), np.asarray(fr.q_block(i, j))
+        assert kb.min() >= 0 and kb.max() < nk and 0 <= qb.min() < nq > qb.max()
+        # a running step's own block, else the neighbouring running step's
+        assert np.array_equal(kb, np.where(
+            runs, j, np.maximum(fr.last_k(i), 0)))
+        assert np.array_equal(qb, np.where(
+            runs, i, np.minimum(fr.first_q(j), nq - 1)))
+
+
 def test_lse_variant_offsets_are_keyword_only():
     """A ninth positional argument used to be ``causal_offset`` and would
     now bind to ``dropout_rate``: it must fail at the call site."""
@@ -725,11 +895,35 @@ MIB = 2 ** 20
     pytest.param(dict(batch=2, s=8192, d=64, nh=32, itemsize=2),
                  (1024, 1024, 2, 32 * MIB, "two_kernel_raised"),
                  id="lfm2-causal-d64-32-heads-s8192-raised"),
+    # tiles_run / tiles_grid a head group of the three decoder cells' causal
+    # calls (PR 38: a tile wholly above the frontier runs nothing): 43.75%
+    # of each grid is skipped; without the mask all of it runs
+    pytest.param(dict(batch=1, s=8192, d=192, nh=32, itemsize=2,
+                      blocks=(1024, 256), causal=True, tiles=(144, 256)),
+                 (1024, 256, 2, None, "two_kernel"), id="kimi-tiles-run"),
+    pytest.param(dict(batch=2, s=8192, d=64, nh=32, itemsize=2, causal=True,
+                      tiles=(36, 64)),
+                 (1024, 1024, 2, 32 * MIB, "two_kernel_raised"),
+                 id="lfm2-tiles-run"),
+    pytest.param(dict(batch=1, s=8192, d=256, nh=16, itemsize=2, causal=True,
+                      tiles=(36, 64)),
+                 (1024, 1024, 1, 32 * MIB, "two_kernel_raised"),
+                 id="qwen3-next-tiles-run"),
+    pytest.param(dict(batch=1, s=8192, d=256, nh=16, itemsize=2,
+                      causal=False, tiles=(64, 64)),
+                 (1024, 1024, 1, 32 * MIB, "two_kernel_raised"),
+                 id="non-causal-runs-the-whole-grid"),
+    pytest.param(dict(batch=16, s=512, d=64, nh=16, itemsize=2, causal=True,
+                      tiles=(1, 1)),
+                 (512, 512, 8, None, "fused"), id="single-block-one-tile"),
 ])
 def test_backward_plan(shape, want):
     kw = dict(shape)
     batch, s, d, nh, itemsize = (
         kw.pop(k) for k in ("batch", "s", "d", "nh", "itemsize"))
     blocks = kw.pop("blocks", (A.DEFAULT_BLOCK_Q, A.DEFAULT_BLOCK_K))
+    causal, tiles = kw.pop("causal", None), kw.pop("tiles", None)
     plan = A._bwd_plan(nh, d, s, s, batch * nh, itemsize, *blocks, **kw)
     assert tuple(plan) == want
+    if tiles is not None:
+        assert plan.tiles(s, s, causal) == tiles

@@ -4,6 +4,8 @@ driver/JSON plumbing."""
 
 import json
 
+import pytest
+
 from apex_tpu.ops import compile_check as cc
 
 
@@ -76,3 +78,29 @@ def test_the_expert_layers_cell_cases_run_at_a_small_size(monkeypatch):
         assert name in names
     monkeypatch.setattr(gm, "ROW_TILE", 32)
     cc._experts_cell_case(64, 2, 16, 4, 32, 16)
+
+
+@pytest.mark.parametrize("heads,kv_heads,d,tiles", [
+    pytest.param(2, 2, 192, (128, 256), id="mla-d192"),
+    pytest.param(4, 1, 64, (128, 128), id="gqa-d64"),
+    pytest.param(2, 1, 256, (256, 128), id="gqa-d256")])
+def test_the_causal_skip_cell_cases_run_at_a_small_size(heads, kv_heads, d,
+                                                         tiles):
+    """``attention/causal-skip-*-s8192-cell`` hold the three decoder cells'
+    heads and tiles over 8192 tokens on the chip: the skipped grid against
+    the whole grid, bit for bit. Here the same case, interpreted, at 512
+    tokens in several tiles."""
+    names = [n for n, _ in cc.CASES]
+    for name in ("attention/causal-skip-mla-d192-s8192-cell",
+                 "attention/causal-skip-gqa-d64-s8192-cell",
+                 "attention/causal-skip-gqa-d256-s8192-cell",
+                 "attention/causal-skip-no-extra-dispatch"):
+        assert name in names
+    cc._causal_skip_case(1, heads, kv_heads, d, t=512, tiles=tiles)
+
+
+def test_the_causal_skip_reaches_the_causal_multi_block_kernels_alone():
+    """``attention/causal-skip-no-extra-dispatch`` at 256 tokens in 128-tiles:
+    non-causal and single-block programs are the same text with the skip and
+    without it."""
+    cc._causal_skip_reach_case(t=256, tile=128, heads=2)

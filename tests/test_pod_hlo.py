@@ -566,6 +566,49 @@ def test_gated_attention_compiles_for_the_v5e_at_the_cell_s_shape(
     assert dk.shape == dv.shape == (1, 8192, 2, 256)
 
 
+def test_grouped_query_attention_compiles_for_the_v5e_at_the_cell_s_shape(
+        v5e_chip, monkeypatch):
+    """``lfm2_moe.lm_s8192_b2_v8k``'s attention: two sequences, 32 q heads
+    on 8 k/v heads of 64, causal, 8192 tokens, the op's own 1024 x 1024
+    tiles with two heads a step. With the two tests above it puts the three
+    decoder cells' causal kernels through Mosaic as PR 38 left them: the
+    arithmetic of a tile under one ``scf.if`` on the frontier, and k / v
+    (q / do / lse / delta in dk/dv) block indices clamped to it."""
+    from apex_tpu import ops
+    from apex_tpu.ops import _dispatch, attention
+    for mod in (_dispatch, attention):
+        monkeypatch.setattr(mod, "use_interpret", lambda: False)
+    q = jax.ShapeDtypeStruct((2, 8192, 32, 64), jnp.bfloat16,
+                             sharding=v5e_chip)
+    kv = jax.ShapeDtypeStruct((2, 8192, 8, 64), jnp.bfloat16,
+                              sharding=v5e_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(ops.flash_attention(
+            q, k, v, None, 1 / 8, True).astype(jnp.float32))
+
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            q, kv, kv).compile()
+    text = compiled.as_text()
+    for kernel in ("apex_attn_fwd", "apex_attn_bwd_dq", "apex_attn_bwd_dkv"):
+        assert f"({kernel})" in text or f"/{kernel}/" in text, kernel
+
+
+def test_the_causal_skip_leaves_the_other_kernels_mosaic_text_alone(
+        v5e_chip, monkeypatch):
+    """``attention/causal-skip-no-extra-dispatch`` lowered for the described
+    v5e: with the skip and without it, the non-causal multi-block kernels,
+    the single-k forward and the fused backward are the same Mosaic payloads
+    (BERT's kernels are among them), and the causal multi-block ones are
+    not."""
+    from apex_tpu.ops import _dispatch, attention, compile_check
+    for mod in (_dispatch, attention):
+        monkeypatch.setattr(mod, "use_interpret", lambda: False)
+    with jax.default_matmul_precision("default"):
+        compile_check._causal_skip_reach_case(sharding=v5e_chip)
+
+
 @pytest.mark.parametrize("dtype,precision", [
     (jnp.float32, "default"), (jnp.bfloat16, "default"),
     (jnp.float32, "highest")])
