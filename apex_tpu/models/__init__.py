@@ -7,7 +7,10 @@ Kimi-Linear (delta-rule linear attention with a decay a channel, latent
 attention), Qwen3-Next (gated DeltaNet with a decay a head and shared key
 heads, gated grouped-query attention with partial rotary, softmax router) and
 LFM2-MoE (gated short convolutions, grouped-query attention with rotary over
-the whole head behind per-head q/k norms, no shared expert, a tied head).
+the whole head behind per-head q/k norms, no shared expert, a tied head) and
+Laguna (sliding-window and global grouped-query attention, 3 : 1, with a
+sigmoid gate a head and YaRN rotary on the global layers, softmax-routed
+experts beside an ungated shared one).
 """
 
 from apex_tpu.models.resnet import (
@@ -21,6 +24,7 @@ from apex_tpu.models.transformer import (
 from apex_tpu.models.dcgan import Generator, Discriminator
 from apex_tpu.models.decoder import (
     Block, Decoder, ExpertFFN, RMSNorm, SwiGLU, lm_loss, partial_rotary,
+    yarn_frequencies,
 )
 from apex_tpu.models.kimi_linear import (
     KimiLinear, KimiLinearDims, KimiDeltaAttention, LatentAttention,
@@ -33,6 +37,9 @@ from apex_tpu.models.qwen3_next import (
 from apex_tpu.models.lfm2 import (
     Lfm2Moe, Lfm2Dims, GatedShortConv, GroupedQueryAttention,
     lfm2_moe_from_config,
+)
+from apex_tpu.models.laguna import (
+    Laguna, LagunaDims, HeadGatedAttention, laguna_from_config,
 )
 
 __all__ = [
@@ -47,5 +54,6 @@ __all__ = [
     "Qwen3Next", "Qwen3NextDims", "GatedDeltaNet", "GatedAttention",
     "partial_rotary", "qwen3_next_from_config",
     "Lfm2Moe", "Lfm2Dims", "GatedShortConv", "GroupedQueryAttention",
-    "lfm2_moe_from_config",
+    "lfm2_moe_from_config", "yarn_frequencies",
+    "Laguna", "LagunaDims", "HeadGatedAttention", "laguna_from_config",
 ]

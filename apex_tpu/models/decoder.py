@@ -5,12 +5,16 @@ the embedding's) and the next-token loss.
 
 A decoder is :class:`Decoder` over a ``dims`` of its own (a frozen
 dataclass: ``models/kimi_linear.py``, ``models/qwen3_next.py``,
-``models/lfm2.py``). The shell asks ``dims`` for what differs between them
-and holds no model's name:
+``models/lfm2.py``, ``models/laguna.py``). The shell asks ``dims`` for what
+differs between them and holds no model's name:
 
 ``dims.mixer(kind)``   the token mixer of a layer of that kind, a module
                        named by its kind (``kda``, ``mla``, ``gdn``,
-                       ``gattn``, ``lconv``, ``gqa``);
+                       ``gattn``, ``lconv``, ``gqa``); a kind is whatever
+                       the layer's entry of ``layer_kinds`` holds, so a
+                       model whose layers differ in more than the kind
+                       carries it there (Laguna's ``("swa", 72)``: a
+                       window layer of 72 q heads);
 ``dims.norm(name)``    the norm of the blocks and the final one;
 ``dims.experts()``     the :class:`ExpertFFN` of an expert layer;
 ``dims.tied_head``     where it is there and true, the logits are ``h E^T``
@@ -47,11 +51,13 @@ can be cut by: ``moe/{route,dispatch,experts,combine,shared}``,
 
 from __future__ import annotations
 
+import math
 from typing import Any, Optional, Sequence
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from apex_tpu import ops
 from apex_tpu.ops import moe
@@ -86,25 +92,61 @@ class RMSNorm(nn.Module):
             jnp.mean(jnp.square(x), -1, keepdims=True) + self.eps) * scale
 
 
-def partial_rotary(x, rotary_dim, theta, positions=None):
+def partial_rotary(x, rotary_dim, theta, positions=None, inv_freq=None,
+                   scale=1.0):
     """Rotary position embedding on the first ``rotary_dim`` channels of each
     head, the rest left as they are. ``x`` ``(B, T, H, D)``; channel ``m <
     rotary_dim / 2`` pairs with ``m + rotary_dim / 2`` (half-split) and turns
     by ``p theta^(-2m / rotary_dim)`` at position ``p`` (``positions``
-    ``(T,)``, default ``0 ... T - 1``). In the policy's dtype for ``rotary``
-    (a FLOAT op): float32."""
+    ``(T,)``, default ``0 ... T - 1``). ``inv_freq`` (``rotary_dim / 2``
+    numbers) replaces ``theta^(-2m / rotary_dim)``, and ``cos`` and ``sin``
+    are multiplied by ``scale``: YaRN's frequencies and attention factor
+    (:func:`yarn_frequencies`). In the policy's dtype for ``rotary`` (a FLOAT
+    op): float32."""
     from apex_tpu.amp.policy import current_policy
     x = x.astype(current_policy().op_dtype("rotary", x.dtype))
     half = rotary_dim // 2
     if positions is None:
         positions = jnp.arange(x.shape[1])
-    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2 / rotary_dim)
+    freq = (theta ** (-jnp.arange(half, dtype=jnp.float32) * 2 / rotary_dim)
+            if inv_freq is None else jnp.asarray(inv_freq, jnp.float32))
     angle = positions.astype(jnp.float32)[:, None] * freq         # (T, half)
-    cos, sin = (f(angle)[None, :, None, :].astype(x.dtype)
-                for f in (jnp.cos, jnp.sin))
+    cos, sin = (f(angle)[None, :, None, :] for f in (jnp.cos, jnp.sin))
+    if scale != 1.0:            # no multiply by one in the lowered text
+        cos, sin = cos * scale, sin * scale
+    cos, sin = cos.astype(x.dtype), sin.astype(x.dtype)
     a, b = x[..., :half], x[..., half:rotary_dim]
     return jnp.concatenate(
         [a * cos - b * sin, b * cos + a * sin, x[..., rotary_dim:]], -1)
+
+
+def yarn_frequencies(rope, rotary_dim):
+    """``(inv_freq, attention_factor)`` of a ``rope_type`` ``"yarn"`` entry
+    of a ``rope_parameters`` (``rope_theta``, ``factor``,
+    ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``,
+    ``attention_factor``) for ``rotary_dim`` rotated channels, as numpy
+    float32. Pair ``m`` turns at ``theta^(-2m / rotary_dim)`` below the
+    correction range, at that over ``factor`` from its top on, and on the
+    linear ramp between; the range's ends are the pairs that turn
+    ``beta_fast`` and ``beta_slow`` times over the original context,
+    ``floor`` and ``ceil`` of ``rotary_dim ln(L / (2 pi beta)) / (2 ln
+    theta)``. Without an ``attention_factor`` it is ``0.1 ln(factor) + 1``."""
+    base, factor = float(rope["rope_theta"]), float(rope["factor"])
+    length = rope["original_max_position_embeddings"]
+
+    def pair(turns):
+        return rotary_dim * math.log(length / (turns * 2 * math.pi)) / (
+            2 * math.log(base))
+
+    low = max(math.floor(pair(rope["beta_fast"])), 0)
+    high = min(math.ceil(pair(rope["beta_slow"])), rotary_dim - 1)
+    kept = base ** -(np.arange(0, rotary_dim, 2, dtype=np.float32)
+                     / rotary_dim)
+    ramp = np.clip((np.arange(rotary_dim // 2, dtype=np.float32) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    inv_freq = kept / factor * ramp + kept * (1.0 - ramp)
+    scale = rope.get("attention_factor") or 0.1 * math.log(factor) + 1.0
+    return inv_freq.astype(np.float32), float(scale)
 
 
 def _conv_init(key, shape, dtype=jnp.float32):

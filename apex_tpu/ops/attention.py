@@ -125,12 +125,17 @@ def _g_pack(bh, nq, has_bias, dropout_rate, bq, bk, dp, itemsize=2):
     return 1
 
 
-def _causal_mask(iq, ik, bq, bk, offset):
+def _causal_mask(iq, ik, bq, bk, offset, window=None):
     """Bottom-right-aligned causal mask: query i attends keys
-    0..i+(Sk-Sq), matching the oracle's tril(k=sk-sq) for cross lengths."""
+    0..i+(Sk-Sq), matching the oracle's tril(k=sk-sq) for cross lengths;
+    under a ``window`` only the newest ``window`` of them, keys
+    i+(Sk-Sq)-window+1..i+(Sk-Sq) (the band's lower edge)."""
     rows = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + iq * bq
     cols = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1) + ik * bk
-    return rows + offset >= cols
+    if window is None:
+        return rows + offset >= cols
+    lag = rows + offset - cols
+    return jnp.logical_and(lag >= 0, lag < window)
 
 
 def _kv_valid(ik, bk, kv_len, bq):
@@ -139,7 +144,7 @@ def _kv_valid(ik, bk, kv_len, bq):
 
 
 def _tile_valid(iq, ik, bq, bk, kv_len, q_len, causal, off, *,
-                need_rows):
+                need_rows, window=None):
     """Validity mask for one (bq, bk) score tile, or None when it is
     statically all-true. kv_len/q_len/bq/bk are Python ints, so each
     term elides independently at trace time: the kv-pad term exists iff
@@ -152,7 +157,9 @@ def _tile_valid(iq, ik, bq, bk, kv_len, q_len, causal, off, *,
     :class:`_Frontier` lets run (one that the frontier crosses or that
     lies below it); a tile wholly above the frontier, where this mask
     would be all-false, is skipped before it. A tile wholly below the
-    frontier still builds its all-true causal term here."""
+    frontier still builds its all-true causal term here. Under a
+    ``window`` the causal term is the band's, and a tile wholly left of
+    the band is skipped too."""
     valid = None
 
     def land(a, b):
@@ -161,7 +168,7 @@ def _tile_valid(iq, ik, bq, bk, kv_len, q_len, causal, off, *,
     if kv_len % bk:
         valid = land(valid, _kv_valid(ik, bk, kv_len, bq))
     if causal:
-        valid = land(valid, _causal_mask(iq, ik, bq, bk, off))
+        valid = land(valid, _causal_mask(iq, ik, bq, bk, off, window))
     if need_rows and q_len % bq:
         rows = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + iq * bq
         valid = land(valid, rows < q_len)
@@ -177,42 +184,83 @@ class _Frontier(NamedTuple):
     from SMEM inside a kernel. Every other tile is masked whole: the
     kernels run no arithmetic for it (:func:`_when_tile_runs`) and, where
     ``off`` is static, the BlockSpec maps name no new block for it
-    (``k_block`` / ``q_block``), so nothing is fetched either."""
+    (``k_block`` / ``q_block``), so nothing is fetched either.
+
+    Under a ``window`` (a static int: row ``r`` keeps the keys ``r + off −
+    window + 1 … r + off``) the band has a lower edge too: a tile runs iff
+    also its last column reaches its first row's oldest key, ``ik·bk + bk −
+    1 >= iq·bq + off − window + 1``. The tiles a q row runs are then
+    ``first_k … last_k`` and those a k column runs ``first_q … last_q``;
+    the index maps clamp from both sides, so a skipped step before the band
+    names the block its first running step fetches, one after it the block
+    its last running step held."""
     bq: int
     bk: int
     nq: int
     nk: int
     off: object
+    window: Optional[int] = None
 
     def runs(self, iq, ik):
-        return ik * self.bk <= iq * self.bq + (self.bq - 1) + self.off
+        below = ik * self.bk <= iq * self.bq + (self.bq - 1) + self.off
+        if self.window is None:
+            return below
+        return below & (ik * self.bk + (self.bk - 1)
+                        >= iq * self.bq + self.off - self.window + 1)
 
     def last_k(self, iq):
         """The last k tile that q tile ``iq`` runs (below 0: none)."""
         return (iq * self.bq + (self.bq - 1) + self.off) // self.bk
+
+    def first_k(self, iq):
+        """The first k tile that q tile ``iq`` runs: 0 without a window."""
+        if self.window is None:
+            return 0
+        return (iq * self.bq + self.off - self.window + 1) // self.bk
 
     def first_q(self, ik):
         """The first q tile that k tile ``ik`` runs (``nq`` or more:
         none)."""
         return (ik * self.bk - self.off) // self.bq
 
+    def last_q(self, ik):
+        """The last q tile that k tile ``ik`` runs: ``nq − 1`` without a
+        window (below 0: none)."""
+        if self.window is None:
+            return self.nq - 1
+        return (ik * self.bk + self.bk - 2 - self.off + self.window) // self.bq
+
     def k_block(self, iq, ik):
         """Index-map form, k innermost: the skipped steps trail a row, and
-        name the block its last running step holds (no DMA)."""
-        return jnp.minimum(ik, jnp.maximum(self.last_k(iq), 0))
+        name the block its last running step holds (no DMA); under a
+        window they lead it too, and name the block its first running step
+        will fetch."""
+        last = jnp.maximum(self.last_k(iq), 0)
+        if self.window is None:
+            return jnp.minimum(ik, last)
+        first = jnp.minimum(self.first_k(iq), self.nk - 1)
+        return jnp.minimum(jnp.maximum(ik, first), last)
 
     def q_block(self, iq, ik):
         """Index-map form, q innermost: the skipped steps lead a column,
-        and name the block its first running step will fetch."""
-        return jnp.maximum(iq, jnp.minimum(self.first_q(ik), self.nq - 1))
+        and name the block its first running step will fetch; under a
+        window they trail it too, and name the block its last running step
+        held."""
+        first = jnp.minimum(self.first_q(ik), self.nq - 1)
+        if self.window is None:
+            return jnp.maximum(iq, first)
+        return jnp.minimum(jnp.maximum(iq, first),
+                           jnp.maximum(self.last_q(ik), 0))
 
     def tiles_run(self):
         """Tiles of the grid that run, a head group (static ``off``)."""
-        return sum(min(max(self.last_k(iq) + 1, 0), self.nk)
+        return sum(max(min(self.last_k(iq), self.nk - 1)
+                       - max(self.first_k(iq), 0) + 1, 0)
                    for iq in range(self.nq))
 
 
-def _frontier(causal, bq, bk, q_len, kv_len, off) -> Optional[_Frontier]:
+def _frontier(causal, bq, bk, q_len, kv_len, off,
+              window=None) -> Optional[_Frontier]:
     """The frontier the native multi-block kernels skip by, or None where
     nothing is skipped: no causal mask, or one tile in all (the forward's
     single-k form and the fused backward never ask). ONE definition for
@@ -222,7 +270,7 @@ def _frontier(causal, bq, bk, q_len, kv_len, off) -> Optional[_Frontier]:
     nq, nk = -(-q_len // bq), -(-kv_len // bk)
     if not causal or nq * nk == 1:
         return None
-    return _Frontier(bq, bk, nq, nk, off)
+    return _Frontier(bq, bk, nq, nk, off, window)
 
 
 def _when_tile_runs(fr, iq, ik):
@@ -234,13 +282,14 @@ def _when_tile_runs(fr, iq, ik):
     return pl.when(fr.runs(iq, ik))
 
 
-def _kernel_frontier(causal, q_ref, k_ref, q_len, kv_len, off_ref):
+def _kernel_frontier(causal, q_ref, k_ref, q_len, kv_len, off_ref,
+                     window=None):
     """A kernel's frontier, from its q and k blocks; ``off_ref`` is the
     traced offset's SMEM ref (causal calls alone have one), or None for
     the static ``kv_len − q_len``."""
     off = kv_len - q_len if off_ref is None else off_ref[0]
     return _frontier(causal, q_ref.shape[1], k_ref.shape[1], q_len, kv_len,
-                     off)
+                     off, window)
 
 
 def _group_id_outside(fr, dropout_rate):
@@ -256,11 +305,11 @@ def _group_id(t):
     return pl.program_id(0) if t is None else t
 
 
-def _causal_tiles(bq, bk, q_len, kv_len, causal):
+def _causal_tiles(bq, bk, q_len, kv_len, causal, window=None):
     """(tiles run, tiles in the grid) a head group, for a call without a
     traced ``causal_offset``: what ``_BwdPlan.tiles`` and the forward's
     grid are read by."""
-    fr = _frontier(causal, bq, bk, q_len, kv_len, kv_len - q_len)
+    fr = _frontier(causal, bq, bk, q_len, kv_len, kv_len - q_len, window)
     grid = -(-q_len // bq) * -(-kv_len // bk)
     return (grid if fr is None else fr.tiles_run()), grid
 
@@ -809,7 +858,8 @@ def _append_common(in_specs, args, *, bias_p, bias_mode, g, hg,
 
 
 def _fwd_kernel_nl(scale, causal, kv_len, q_len, dropout_rate, d, g,
-                   has_off, has_bias, bias_per_head, has_dbo, refs):
+                   has_off, has_bias, bias_per_head, has_dbo, refs,
+                   window=None):
     refs = list(refs)
     q_ref, k_ref, v_ref = refs[:3]
     b_ref, seed_ref, dbo_ref, off_ref, pos = _unpack_common(
@@ -835,7 +885,7 @@ def _fwd_kernel_nl(scale, causal, kv_len, q_len, dropout_rate, d, g,
             acc[:] = jnp.zeros_like(acc)
 
     fr = None if single_k else _kernel_frontier(
-        causal, q_ref, k_ref, q_len, kv_len, off_ref)
+        causal, q_ref, k_ref, q_len, kv_len, off_ref, window)
     t = _group_id_outside(fr, dropout_rate)
 
     def tile(h, sl):
@@ -850,7 +900,7 @@ def _fwd_kernel_nl(scale, causal, kv_len, q_len, dropout_rate, d, g,
         off = ((off_ref[0] if has_off else kv_len - q_len)
                if causal else None)
         valid = _tile_valid(iq, ik, bq, bk, kv_len, q_len, causal, off,
-                            need_rows=False)
+                            need_rows=False, window=window)
         masked = valid is not None
         if masked:
             s = jnp.where(valid, s, NEG_INF)
@@ -901,8 +951,8 @@ def _fwd_kernel_nl(scale, causal, kv_len, q_len, dropout_rate, d, g,
 
     for h in range(g):
         sl = slice(h * d, (h + 1) * d)
-        # a row's skipped tiles trail it: the init above and the write-out
-        # below stay outside the skip
+        # a row's skipped tiles trail it (and under a window lead it too):
+        # the init above and the write-out below stay outside the skip
         _when_tile_runs(fr, iq, ik)(functools.partial(tile, h, sl))
         if single_k:
             continue
@@ -994,7 +1044,7 @@ def _pad_bias_nl(bias_g, sqp, skp):
 
 def _flash_fwd_nl(q2, k2, v2, nh, d, scale, causal, block_q, block_k,
                   dropout_rate=0.0, seed=None, causal_off=None,
-                  bias_g=None, bias_mode=None, dbo=None):
+                  bias_g=None, bias_mode=None, dbo=None, window=None):
     b, sq, H = q2.shape
     sk = k2.shape[1]
     bh = b * nh
@@ -1020,7 +1070,7 @@ def _flash_fwd_nl(q2, k2, v2, nh, d, scale, causal, block_q, block_k,
     # the single-k kernel has no k loop to skip in; a traced offset skips
     # the arithmetic alone (the maps cannot read it)
     fr = (None if nk == 1 or causal_off is not None
-          else _frontier(causal, bq, bk, sq, sk, sk - sq))
+          else _frontier(causal, bq, bk, sq, sk, sk - sq, window))
     q_spec, k_spec, bias_idx = _head_specs(nh, g, bq, bk, gd, fr)
     in_specs = [q_spec, k_spec, k_spec]
     args = [qp, kp, vp]
@@ -1037,7 +1087,7 @@ def _flash_fwd_nl(q2, k2, v2, nh, d, scale, causal, block_q, block_k,
                                dropout_rate, d, g,
                                causal_off is not None,
                                bias_g is not None, bias_per_head,
-                               dbo is not None)
+                               dbo is not None, window=window)
     o, lse = pallas_call(
         lambda *refs: kernel(refs),
         grid=(bh // g, nq, nk),
@@ -1063,7 +1113,8 @@ def _flash_fwd_nl(q2, k2, v2, nh, d, scale, causal, block_q, block_k,
 
 
 def _bwd_dq_kernel_nl(scale, causal, kv_len, q_len, dropout_rate, d, g,
-                      has_off, has_bias, bias_per_head, has_dbo, refs):
+                      has_off, has_bias, bias_per_head, has_dbo, refs,
+                      window=None):
     refs = list(refs)
     q_ref, k_ref, v_ref = refs[:3]
     b_ref, seed_ref, dbo_ref, off_ref, pos = _unpack_common(
@@ -1076,7 +1127,8 @@ def _bwd_dq_kernel_nl(scale, causal, kv_len, q_len, dropout_rate, d, g,
     def _():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    fr = _kernel_frontier(causal, q_ref, k_ref, q_len, kv_len, off_ref)
+    fr = _kernel_frontier(causal, q_ref, k_ref, q_len, kv_len, off_ref,
+                          window)
     t = _group_id_outside(fr, dropout_rate)
 
     def tile(h, sl):
@@ -1094,7 +1146,7 @@ def _bwd_dq_kernel_nl(scale, causal, kv_len, q_len, dropout_rate, d, g,
         off = ((off_ref[0] if has_off else kv_len - q_len)
                if causal else None)
         valid = _tile_valid(iq, ik, bq, bk, kv_len, q_len, causal, off,
-                            need_rows=False)
+                            need_rows=False, window=window)
         if valid is not None:
             p = jnp.where(valid, p, 0.0)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
@@ -1120,7 +1172,8 @@ def _bwd_dq_kernel_nl(scale, causal, kv_len, q_len, dropout_rate, d, g,
 
 
 def _bwd_dkv_kernel_nl(scale, causal, kv_len, q_len, dropout_rate, d, g,
-                       has_off, has_bias, bias_per_head, has_dbo, refs):
+                       has_off, has_bias, bias_per_head, has_dbo, refs,
+                       window=None):
     refs = list(refs)
     q_ref, k_ref, v_ref = refs[:3]
     b_ref, seed_ref, dbo_ref, off_ref, pos = _unpack_common(
@@ -1134,7 +1187,8 @@ def _bwd_dkv_kernel_nl(scale, causal, kv_len, q_len, dropout_rate, d, g,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    fr = _kernel_frontier(causal, q_ref, k_ref, q_len, kv_len, off_ref)
+    fr = _kernel_frontier(causal, q_ref, k_ref, q_len, kv_len, off_ref,
+                          window)
     t = _group_id_outside(fr, dropout_rate)
 
     def tile(h, sl):
@@ -1152,7 +1206,7 @@ def _bwd_dkv_kernel_nl(scale, causal, kv_len, q_len, dropout_rate, d, g,
         off = ((off_ref[0] if has_off else kv_len - q_len)
                if causal else None)
         valid = _tile_valid(iq, ik, bq, bk, kv_len, q_len, causal, off,
-                            need_rows=True)
+                            need_rows=True, window=window)
         if valid is not None:
             p = jnp.where(valid, p, 0.0)
 
@@ -1174,8 +1228,8 @@ def _bwd_dkv_kernel_nl(scale, causal, kv_len, q_len, dropout_rate, d, g,
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
 
-    # a column's skipped tiles lead it: the init above and the write-out
-    # below stay outside the skip
+    # a column's skipped tiles lead it (and under a window trail it too):
+    # the init above and the write-out below stay outside the skip
     @_when_tile_runs(fr, iq, ik)
     def _():
         for h in range(g):
@@ -1189,7 +1243,7 @@ def _bwd_dkv_kernel_nl(scale, causal, kv_len, q_len, dropout_rate, d, g,
 
 def _bwd_fused_kernel_nl(scale, causal, kv_len, q_len, dropout_rate, d,
                          g, has_off, self_delta, has_bias,
-                         bias_per_head, has_dbo, refs):
+                         bias_per_head, has_dbo, refs, window=None):
     """Single-sweep backward for single-block grids (Sq, Sk each one
     tile): s and p are computed ONCE per head and all three gradients
     come out of the same sweep — the two-kernel split pays a redundant
@@ -1232,7 +1286,7 @@ def _bwd_fused_kernel_nl(scale, causal, kv_len, q_len, dropout_rate, d,
         off = ((off_ref[0] if has_off else kv_len - q_len)
                if causal else None)
         valid = _tile_valid(0, 0, bq, bk, kv_len, q_len, causal, off,
-                            need_rows=True)
+                            need_rows=True, window=window)
         masked = valid is not None
         if self_delta:
             if masked:
@@ -1284,7 +1338,7 @@ def _bwd_fused_kernel_nl(scale, causal, kv_len, q_len, dropout_rate, d,
 def _flash_bwd_fused_nl(qp, kp, vp, dop, lse_l, delta_l, nh, d, g,
                         scale, causal, sq, sk, sqp, skp, bq, bk, seed,
                         dropout_rate, causal_off=None, bias_p=None,
-                        bias_mode=None, dbo=None):
+                        bias_mode=None, dbo=None, window=None):
     """``lse_l``/``delta_l`` None ⇒ the kernel self-computes the
     normalizer and delta (the single-block identity, no lane operands)."""
     self_delta = lse_l is None
@@ -1319,7 +1373,8 @@ def _flash_bwd_fused_nl(qp, kp, vp, dop, lse_l, delta_l, nh, d, g,
         lambda *refs: functools.partial(
             _bwd_fused_kernel_nl, scale, causal, sk, sq, dropout_rate,
             d, g, causal_off is not None, self_delta,
-            bias_p is not None, bias_per_head, dbo is not None)(refs),
+            bias_p is not None, bias_per_head, dbo is not None,
+            window=window)(refs),
         grid=(bh // g,),
         in_specs=in_specs,
         out_specs=(q_spec, k_spec, k_spec),
@@ -1341,12 +1396,12 @@ class _BwdPlan(NamedTuple):
     vmem_limit: Optional[int]   # None: Mosaic's 16 MiB default
     form: str                   # "fused" | "two_kernel" | "two_kernel_raised"
 
-    def tiles(self, sq, sk, causal):
+    def tiles(self, sq, sk, causal, window=None):
         """(tiles_run, tiles_grid) a head group, in each of the backward's
         kernels (the forward's come from :func:`_causal_tiles` at its own
         tiles): under a static causal frontier the tiles above it run
-        nothing."""
-        return _causal_tiles(self.bq, self.bk, sq, sk, causal)
+        nothing, and under a window neither do those left of the band."""
+        return _causal_tiles(self.bq, self.bk, sq, sk, causal, window)
 
 
 def _bwd_plan(nh, d, sq, sk, bh, itemsize, block_q, block_k, *,
@@ -1430,7 +1485,7 @@ def _bwd_plan(nh, d, sq, sk, bh, itemsize, block_q, block_k, *,
 def _flash_bwd_nl(q2, k2, v2, nh, d, lse, delta, do2, scale, causal,
                   block_q, block_k, dropout_rate=0.0, seed=None,
                   causal_off=None, delta_shifted=False, bias_g=None,
-                  bias_mode=None, dbo=None):
+                  bias_mode=None, dbo=None, window=None):
     """Native-layout backward: operands/outputs (B, S, H); ``lse`` and
     ``delta`` arrive (B·H, Sq).
 
@@ -1471,7 +1526,8 @@ def _flash_bwd_nl(q2, k2, v2, nh, d, lse, delta, do2, scale, causal,
                                    sqp, skp, bq, bk, seed,
                                    dropout_rate, causal_off,
                                    bias_p=bias_p,
-                                   bias_mode=bias_mode, dbo=dbo)
+                                   bias_mode=bias_mode, dbo=dbo,
+                                   window=window)
 
     gd = g * d
     lse_l = _lanes_nl(lse, bh, g, nq, bq, sq)
@@ -1479,7 +1535,7 @@ def _flash_bwd_nl(q2, k2, v2, nh, d, lse, delta, do2, scale, causal,
 
     hg = nh // g
     fr = (None if causal_off is not None
-          else _frontier(causal, bq, bk, sq, sk, sk - sq))
+          else _frontier(causal, bq, bk, sq, sk, sk - sq, window))
     q_spec, k_spec, bias_idx = _head_specs(nh, g, bq, bk, gd, fr)
     lane_spec = pl.BlockSpec((g * bq, LANES),
                              lambda t, i, j: (t * nq + i, 0),
@@ -1504,7 +1560,7 @@ def _flash_bwd_nl(q2, k2, v2, nh, d, lse, delta, do2, scale, causal,
         lambda *refs: functools.partial(
             _bwd_dq_kernel_nl, scale, causal, sk, sq, dropout_rate, d,
             g, causal_off is not None, bias_p is not None,
-            bias_per_head, dbo is not None)(refs),
+            bias_per_head, dbo is not None, window=window)(refs),
         grid=(bh // g, nq, nk),
         in_specs=in_specs,
         out_specs=q_spec,
@@ -1541,7 +1597,7 @@ def _flash_bwd_nl(q2, k2, v2, nh, d, lse, delta, do2, scale, causal,
         lambda *refs: functools.partial(
             _bwd_dkv_kernel_nl, scale, causal, sk, sq, dropout_rate, d,
             g, causal_off is not None, bias_p is not None,
-            bias_per_head, dbo is not None)(refs),
+            bias_per_head, dbo is not None, window=window)(refs),
         grid=(bh // g, nk, nq),
         in_specs=in_specs2,
         out_specs=(k_spec_k, k_spec_k),
@@ -1559,7 +1615,7 @@ def _flash_bwd_nl(q2, k2, v2, nh, d, lse, delta, do2, scale, causal,
 def flash_attention(q, k, v, bias=None, scale=None, causal=False,
                     block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
                     dropout_rate=0.0, dropout_seed=None,
-                    causal_offset=None):
+                    causal_offset=None, window=None):
     """Blockwise softmax attention.
 
     q: (B, Sq, H, D); k/v: (B, Sk, H, D), or (B, Sk, H_kv, D) with H_kv a
@@ -1599,20 +1655,67 @@ def flash_attention(q, k, v, bias=None, scale=None, causal=False,
     the arithmetic and keeps its fetches. The results are the full grid's
     bit for bit; a q tile that runs no k tile (a ring hop wholly in the
     future) reads ``o = 0`` and ``lse ≈ −1e30``.
+
+    ``window`` (a static int, causal calls only) is sliding-window
+    attention: query ``i`` attends the ``window`` newest keys at or before
+    its frontier, ``i + Sk − Sq − window + 1 … i + Sk − Sq``. The native
+    multi-block kernels then also skip, and fetch nothing for, the tiles
+    wholly left of that band; a tile the band's edge crosses is masked
+    inside (:class:`_Frontier`). Left at the defaults, the tiles are then
+    no wider than the window (:func:`_window_blocks`): at 4096 tokens and
+    ``window`` 512 the band is 15 of 64 tiles of 512 × 512, where causal
+    attention runs 36 of 64 (10 of 16 of 1024 × 1024). A window of ``Sk``
+    or more is causal attention. It takes the native path alone (the
+    heads' columns lane-aligned in groups), and no bias, dropout or
+    ``causal_offset``.
     """
     if k.shape[2] != q.shape[2]:
         k, v = (jnp.repeat(x, q.shape[2] // x.shape[2], 2) for x in (k, v))
+    window = _band(window, q, k, bias, causal, dropout_rate, causal_offset)
+    block_q, block_k = _window_blocks(window, block_q, block_k)
     return _flash_attention(q, k, v, bias, scale, causal, block_q, block_k,
-                            dropout_rate, dropout_seed, causal_offset)
+                            dropout_rate, dropout_seed, causal_offset,
+                            window)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 11))
 def _flash_attention(q, k, v, bias, scale, causal, block_q, block_k,
-                     dropout_rate, dropout_seed, causal_offset):
+                     dropout_rate, dropout_seed, causal_offset, window):
     o, _ = _flash_attention_fwd_res(q, k, v, bias, dropout_seed, scale,
                                     causal, block_q, block_k,
-                                    dropout_rate, causal_offset)
+                                    dropout_rate, causal_offset,
+                                    window=window)
     return o
+
+
+def _band(window, q, k, bias, causal, dropout_rate, causal_offset):
+    """``window`` checked against the call, and None where it keeps every
+    causal key (``window >= Sk``): then the call is causal attention's."""
+    if window is None or window >= k.shape[1]:
+        return None
+    if not causal or window < 1:
+        raise ValueError(f"window={window} needs causal=True and a window "
+                         "of at least one key")
+    if (bias is not None or dropout_rate > 0.0 or causal_offset is not None
+            or _native_g0(q.shape[2], q.shape[3]) is None):
+        raise NotImplementedError(
+            "window= runs on the native attention path (lane-groupable "
+            "heads), without a bias, dropout or a causal_offset")
+    return int(window)
+
+
+def _window_blocks(window, block_q, block_k):
+    """The tiles of a windowed call that left them at the defaults: the
+    power of two at or above the window, at least a lane and at most the
+    default. A tile much wider than the band evaluates pairs it masks: at
+    4096 tokens and a window of 512, 512 x 512 tiles run 15 of 64 (2.0 x
+    the band's pairs), 1024 x 1024 ones 7 of 16 (3.7 x). Explicit tiles and
+    calls without a window keep theirs."""
+    if window is None or (block_q, block_k) != (DEFAULT_BLOCK_Q,
+                                                DEFAULT_BLOCK_K):
+        return block_q, block_k
+    tile = max(LANES, 1 << (window - 1).bit_length())
+    return min(tile, block_q), min(tile, block_k)
 
 
 def _to3(q, k, v):
@@ -1721,7 +1824,7 @@ def _kept(o, lse):
 
 def _flash_attention_fwd_res(q, k, v, bias, dropout_seed, scale, causal,
                              block_q, block_k, dropout_rate,
-                             causal_offset=None, dbo=None):
+                             causal_offset=None, dbo=None, window=None):
     block_q, block_k = _tuned_qk(q, k, block_q, block_k, dropout_rate)
     b, sq, h, d = q.shape
     scale = scale if scale is not None else 1.0 / np.sqrt(d)
@@ -1763,7 +1866,7 @@ def _flash_attention_fwd_res(q, k, v, bias, dropout_seed, scale, causal,
         o2, lse = _flash_fwd_nl(q2, k2, v2, h, d, scale, causal,
                                 block_q, block_k, dropout_rate, seed,
                                 causal_off=off, bias_g=bias_nl,
-                                bias_mode=bias_mode, dbo=dbo)
+                                bias_mode=bias_mode, dbo=dbo, window=window)
         o, lse = _kept(o2.reshape(b, sq, h, d), lse)
         return o, (q, k, v, bias, dropout_seed, o, lse, causal_offset)
     eff_bias, eff_causal = bias, causal
@@ -1780,14 +1883,15 @@ def _flash_attention_fwd_res(q, k, v, bias, dropout_seed, scale, causal,
 
 
 def _fa_fwd(q, k, v, bias, scale, causal, block_q, block_k, dropout_rate,
-            dropout_seed, causal_offset):
+            dropout_seed, causal_offset, window):
     o, res = _flash_attention_fwd_res(q, k, v, bias, dropout_seed, scale,
                                       causal, block_q, block_k,
-                                      dropout_rate, causal_offset)
+                                      dropout_rate, causal_offset,
+                                      window=window)
     return o, res
 
 
-def _fa_bwd(scale, causal, block_q, block_k, dropout_rate, res, do):
+def _fa_bwd(scale, causal, block_q, block_k, dropout_rate, window, res, do):
     q, k, v, bias, dropout_seed, o, lse, causal_offset = res
     block_q, block_k = _tuned_qk(q, k, block_q, block_k, dropout_rate)
     b, sq, h, d = q.shape
@@ -1809,7 +1913,7 @@ def _fa_bwd(scale, causal, block_q, block_k, dropout_rate, res, do):
             q2, k2, v2, h, d, lse, delta, do2, scale_, causal,
             block_q, block_k, dropout_rate=dropout_rate, seed=seed,
             causal_off=_off_arr(causal_offset, causal),
-            bias_g=bias_nl, bias_mode=bias_mode)
+            bias_g=bias_nl, bias_mode=bias_mode, window=window)
         dbias = None if bias is None else _bias_grad(
             q, k, v, bias, o, lse, do, scale_, causal,
             dropout_rate=dropout_rate, seed=seed,
@@ -1895,16 +1999,18 @@ def _bias_grad(q, k, v, bias, o, lse, do, scale, causal, *,
 _flash_attention.defvjp(_fa_fwd, _fa_bwd)
 
 
-def attention_reference(q, k, v, bias=None, scale=None, causal=False):
+def attention_reference(q, k, v, bias=None, scale=None, causal=False,
+                        window=None):
     """Pure-jnp oracle — the reference's ``impl='default'`` python path
     (`self_multihead_attn_func.py:6-232`). Runs with the O1 raw-op patch
-    suspended: its fp32 einsums are the point of the oracle."""
+    suspended: its fp32 einsums are the point of the oracle. ``window``:
+    :func:`flash_attention`'s band, as a dense mask."""
     from apex_tpu.amp.functional_patch import suspend
     with suspend():
-        return _attention_reference(q, k, v, bias, scale, causal)
+        return _attention_reference(q, k, v, bias, scale, causal, window)
 
 
-def _attention_reference(q, k, v, bias, scale, causal):
+def _attention_reference(q, k, v, bias, scale, causal, window=None):
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / np.sqrt(d)
     if k.shape[2] != q.shape[2]:    # grouped-query: a k/v head a group
@@ -1916,6 +2022,8 @@ def _attention_reference(q, k, v, bias, scale, causal):
     if causal:
         sq, sk = s.shape[-2:]
         mask = np.tril(np.ones((sq, sk), bool), k=sk - sq)
+        if window is not None:
+            mask &= ~np.tril(np.ones((sq, sk), bool), k=sk - sq - window)
         s = jnp.where(mask, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))
@@ -1942,7 +2050,8 @@ def mask_softmax_dropout(scores, mask=None, dropout_rate=0.0,
 def flash_attention_lse(q, k, v, bias=None, scale=None, causal=False,
                         block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
                         *, dropout_rate=0.0, dropout_seed=None,
-                        causal_offset=None, dropout_block_offset=None):
+                        causal_offset=None, dropout_block_offset=None,
+                        window=None):
     """Like :func:`flash_attention` but returns ``(out, lse)`` with
     ``lse`` (B, H, Sq) differentiable — the building block ring attention
     needs to merge partial results across sequence shards.
@@ -1961,28 +2070,31 @@ def flash_attention_lse(q, k, v, bias=None, scale=None, causal=False,
     ``dropout_block_offset`` are keyword-only: they were inserted ahead
     of ``causal_offset`` historically, so a positional caller would
     silently bind an offset to ``dropout_rate`` — now it fails loudly
-    at the call site (ADVICE r5).
+    at the call site (ADVICE r5). ``window`` is :func:`flash_attention`'s
+    (q and k with the same heads here).
     """
+    window = _band(window, q, k, bias, causal, dropout_rate, causal_offset)
+    block_q, block_k = _window_blocks(window, block_q, block_k)
     return _flash_attention_lse(q, k, v, bias, scale, causal, block_q,
                                 block_k, dropout_rate, dropout_seed,
-                                causal_offset, dropout_block_offset)
+                                causal_offset, dropout_block_offset, window)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 12))
 def _flash_attention_lse(q, k, v, bias, scale, causal, block_q, block_k,
                          dropout_rate, dropout_seed, causal_offset,
-                         dropout_block_offset):
+                         dropout_block_offset, window):
     # positional custom_vjp core — custom_vjp cannot resolve
     # keyword-only parameters, hence the public wrapper above
     (o, lse), _ = _fal_fwd(q, k, v, bias, scale, causal, block_q,
                            block_k, dropout_rate, dropout_seed,
-                           causal_offset, dropout_block_offset)
+                           causal_offset, dropout_block_offset, window)
     return o, lse
 
 
 def _fal_fwd(q, k, v, bias, scale, causal, block_q, block_k,
              dropout_rate, dropout_seed, causal_offset,
-             dropout_block_offset):
+             dropout_block_offset, window):
     if dropout_rate > 0.0 and _native_g0(q.shape[2], q.shape[3]) is None:
         # the lse variant's backward has no transposed dropout path —
         # fail at trace time, not at the first jax.grad deep in a step
@@ -1993,12 +2105,14 @@ def _fal_fwd(q, k, v, bias, scale, causal, block_q, block_k,
            else jnp.asarray(dropout_block_offset, jnp.int32).reshape(2))
     o, res = _flash_attention_fwd_res(q, k, v, bias, dropout_seed,
                                       scale, causal, block_q, block_k,
-                                      dropout_rate, causal_offset, dbo)
+                                      dropout_rate, causal_offset, dbo,
+                                      window)
     b, sq, h, _ = q.shape
     return (o, res[6].reshape(b, h, sq)), res + (dbo,)
 
 
-def _fal_bwd(scale, causal, block_q, block_k, dropout_rate, res, cot):
+def _fal_bwd(scale, causal, block_q, block_k, dropout_rate, window, res,
+             cot):
     do, dlse = cot
     q, k, v, bias, dropout_seed, o, lse, causal_offset, dbo = res
     b, sq, h, d = q.shape
@@ -2022,7 +2136,7 @@ def _fal_bwd(scale, causal, block_q, block_k, dropout_rate, res, cot):
             block_q, block_k, dropout_rate=dropout_rate, seed=seed,
             causal_off=_off_arr(causal_offset, causal),
             delta_shifted=True, bias_g=bias_nl, bias_mode=bias_mode,
-            dbo=dbo)
+            dbo=dbo, window=window)
         dbias = None if bias is None else _bias_grad(
             q, k, v, bias, o, lse, do, scale_, causal,
             dropout_rate=dropout_rate, seed=seed,

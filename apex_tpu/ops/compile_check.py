@@ -434,27 +434,29 @@ def _rel(label, got, want, tol):
 
 
 def _gqa_cell_case(batch=1, heads=16, kv_heads=2, d=256, t=8192,
-                   prefix=1024):
+                   prefix=1024, window=None):
     """Grouped-query attention at a cell's shape (Qwen3-Next's gated
     attention: 16 q heads on 2 k/v heads of 256; causal, bfloat16, the op's
     own tiles), forward and the three gradients, against the dense oracle
     on a prefix: under a causal mask the first rows see nothing of the
-    rest."""
+    rest. ``window``: the op's band, the oracle's as a dense mask."""
     from apex_tpu.ops.attention import attention_reference, flash_attention
     q = _rand((batch, t, heads, d), 0, jnp.bfloat16, 0.5)
     k = _rand((batch, t, kv_heads, d), 1, jnp.bfloat16, 0.5)
     v = _rand((batch, t, kv_heads, d), 2, jnp.bfloat16, 0.5)
     w = _rand((batch, prefix, heads, d), 3, jnp.float32, 0.5)
     scale = d ** -0.5
+    band = {} if window is None else {"window": window}
     loss = lambda fn: lambda q, k, v: jnp.sum(
-        fn(q, k, v, None, scale, True)[:, :prefix].astype(jnp.float32) * w)
+        fn(q, k, v, None, scale, True, **band)[:, :prefix].astype(
+            jnp.float32) * w)
     out, grads = jax.jit(lambda *a: (
-        flash_attention(*a, None, scale, True),
+        flash_attention(*a, None, scale, True, **band),
         jax.grad(loss(flash_attention), argnums=(0, 1, 2))(*a)))(q, k, v)
     assert out.shape == q.shape and grads[1].shape == k.shape
     head = tuple(x[:, :prefix].astype(jnp.float32) for x in (q, k, v))
     _rel("gqa fwd", out[:, :prefix], attention_reference(
-        *head, None, scale, True), 3e-2)
+        *head, None, scale, True, **band), 3e-2)
     want = jax.grad(loss(attention_reference), argnums=(0, 1, 2))(*head)
     for name, g, r in zip("qkv", grads, want):
         _rel(f"gqa d{name}", g[:, :prefix], r, 6e-2)
@@ -487,28 +489,32 @@ def _no_causal_skip():
         attention._frontier = real
 
 
-def _causal_skip_case(batch, heads, kv_heads, d, t=8192, tiles=()):
+def _causal_skip_case(batch, heads, kv_heads, d, t=8192, tiles=(),
+                      window=None):
     """A cell's causal call over all its tokens, forward and the three
     gradients, with the tiles above the frontier skipped and not fetched
     against the same kernels running their whole grid: a skipped tile would
     have added ``p = 0`` under ``alpha = 1``, so the two agree bit for bit
     (a zero's sign aside). The oracle cases hold a prefix of the rows; this
-    one holds every row, the last q tiles' long k loops among them."""
+    one holds every row, the last q tiles' long k loops among them. Under a
+    ``window`` the tiles left of the band are skipped too."""
     from apex_tpu.ops.attention import (DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q,
-                                        _causal_tiles, flash_attention)
+                                        _causal_tiles, _window_blocks,
+                                        flash_attention)
     q = _rand((batch, t, heads, d), 0, jnp.bfloat16, 0.5)
     k = _rand((batch, t, kv_heads, d), 1, jnp.bfloat16, 0.5)
     v = _rand((batch, t, kv_heads, d), 2, jnp.bfloat16, 0.5)
     w = _rand((batch, t, heads, d), 3, jnp.float32, 0.5)
-    run, grid = _causal_tiles(*(tiles or (DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K)),
-                              t, t, True)
+    run, grid = _causal_tiles(*(tiles or _window_blocks(
+        window, DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K)), t, t, True, window)
     assert run < grid, (run, grid)
 
     def both():
         # a fresh function a variant: the frontier is consulted while the
         # kernels are traced
         def loss(q, k, v, w):
-            o = flash_attention(q, k, v, None, d ** -0.5, True, *tiles)
+            o = flash_attention(q, k, v, None, d ** -0.5, True, *tiles,
+                                window=window)
             return jnp.sum(o.astype(jnp.float32) * w), o
         return jax.jit(lambda *a: jax.value_and_grad(
             loss, argnums=(0, 1, 2), has_aux=True)(*a))(q, k, v, w)
@@ -543,6 +549,21 @@ def _():
 def _():
     # Qwen3-Next's: 16 q heads on 2 k/v heads of 256, a head a step: 36 of 64
     _causal_skip_case(1, 16, 2, 256)
+
+
+@case("attention/window-band-d128-s4096-cell")
+def _():
+    # the window cell's sliding layers: 72 q heads on 8 k/v heads of 128, a
+    # window of 512 keys, the op's own tiles for it (512 x 512), four heads
+    # a step, against the dense band on the first 1024 rows
+    _gqa_cell_case(1, 72, 8, 128, t=4096, window=512)
+
+
+@case("attention/window-skip-d128-s4096-cell")
+def _():
+    # the same call over every row: 15 of 64 tiles run and fetch, against
+    # the kernels running their whole grid under the same mask
+    _causal_skip_case(1, 72, 8, 128, t=4096, window=512)
 
 
 def _causal_skip_reach_case(t=2048, tile=1024, heads=4, d=64, sharding=None):

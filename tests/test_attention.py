@@ -814,6 +814,210 @@ class TestCausalTileSkip:
             runs, i, np.minimum(fr.first_q(j), nq - 1)))
 
 
+def _band_case(id_, window, sq=512, sk=None, nh=2, nkv=None, d=64,
+               tiles=(128, 128)):
+    return pytest.param(dict(window=window, sq=sq, sk=sk or sq, nh=nh,
+                             nkv=nkv or nh, d=d, tiles=tiles), id=id_)
+
+
+class TestSlidingWindow:
+    """``window=``: query ``i`` attends keys ``i + off − window + 1 … i +
+    off`` (``off = Sk − Sq``). The native kernels, interpreted, against a
+    dense oracle with the band as its mask, forward and the three gradients;
+    and against themselves with the skip disabled (``_frontier`` answering
+    None: every tile runs under the same mask), bit for bit."""
+
+    CASES = [
+        _band_case("w64-tiles-128", 64),
+        _band_case("w200-not-a-tile-multiple-padded-450", 200, sq=450),
+        _band_case("w100-tiles-256x128", 100, tiles=(256, 128)),
+        _band_case("w130-gqa-4-on-2-d128-tiles-128x256", 130, nh=4, nkv=2,
+                   d=128, tiles=(128, 256)),
+        _band_case("w64-kv-longer-bottom-right", 64, sq=256, sk=512),
+        _band_case("w300-kv-longer-tiles-256x128", 300, sq=384, sk=640,
+                   tiles=(256, 128)),
+        _band_case("w1-own-key-alone", 1, sq=256),
+        _band_case("w64-single-block-fused", 64, sq=256,
+                   tiles=(256, 256)),
+    ]
+
+    @staticmethod
+    def _run(case, rng, window):
+        sq, sk, nh, nkv, d = (case[k] for k in ("sq", "sk", "nh", "nkv",
+                                                 "d"))
+        q = jnp.asarray(rng.randn(1, sq, nh, d), jnp.float32)
+        k = jnp.asarray(rng.randn(1, sk, nkv, d), jnp.float32)
+        v = jnp.asarray(rng.randn(1, sk, nkv, d), jnp.float32)
+        w = jnp.asarray(rng.randn(1, sq, nh, d), jnp.float32)
+
+        def loss(q, k, v):
+            o = A.flash_attention(q, k, v, None, None, True, *case["tiles"],
+                                  window=window)
+            return jnp.sum(o * w), o
+        (_, o), grads = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+
+        def want(q, k, v):
+            o = A.attention_reference(q, k, v, None, None, True, window)
+            return jnp.sum(o * w), o
+        with jax.default_matmul_precision("highest"):
+            (_, o_ref), g_ref = jax.jit(jax.value_and_grad(
+                want, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+        return (o, *grads), (o_ref, *g_ref)
+
+    @pytest.mark.parametrize("case", CASES)
+    def test_matches_the_dense_band(self, case):
+        got, want = self._run(case, np.random.RandomState(5),
+                              case["window"])
+        for name, a, b in zip(("o", "dq", "dk", "dv"), got, want):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=2e-4, rtol=2e-4, err_msg=name)
+        # the band is not causal attention: the oldest keys took no part
+        causal, _ = self._run(case, np.random.RandomState(5), None)
+        assert not np.allclose(np.asarray(causal[0]), np.asarray(got[0]),
+                               atol=1e-3)
+
+    @pytest.mark.parametrize("case", CASES)
+    def test_bit_identical_to_the_whole_grid(self, case, monkeypatch):
+        frontiers = []
+        real = A._frontier
+
+        def spy(*a):
+            frontiers.append(real(*a))
+            return frontiers[-1]
+
+        monkeypatch.setattr(A, "_frontier", spy)
+        got, _ = self._run(case, np.random.RandomState(9), case["window"])
+        monkeypatch.setattr(A, "_frontier", lambda *a: None)
+        whole, _ = self._run(case, np.random.RandomState(9), case["window"])
+        bands = [f for f in frontiers if f is not None]
+        if case["tiles"] != (256, 256):
+            assert bands and all(f.window == case["window"] for f in bands)
+        for name, a, b in zip(("o", "dq", "dk", "dv"), got, whole):
+            assert np.array_equal(np.asarray(a), np.asarray(b)), name
+
+    def test_a_window_of_the_whole_sequence_is_causal_attention(self):
+        """``window >= Sk`` keeps every causal key: the call is causal
+        attention's, the same program."""
+        rng = np.random.RandomState(3)
+        q, k, v = rand_qkv(rng, 1, 512, 2, 64)
+
+        def text(window):
+            return jax.jit(lambda q, k, v: A.flash_attention(
+                q, k, v, None, None, True, 128, 128, window=window)).lower(
+                    q, k, v).as_text()
+        assert text(512) == text(4096) == text(None)
+        assert text(511) != text(None)
+
+    @pytest.mark.parametrize("window,blocks,want", [
+        (512, None, (512, 512)), (32, None, (128, 128)),
+        (200, None, (256, 256)), (1000, None, (1024, 1024)),
+        (3000, None, (1024, 1024)), (512, (128, 256), (128, 256)),
+        (None, None, (1024, 1024))])
+    def test_the_op_picks_tiles_no_wider_than_the_window(self, window,
+                                                         blocks, want):
+        """Left at the defaults, a windowed call's tiles are the power of two
+        at or above the window, a lane at least and the default at most;
+        explicit tiles and a call without a window keep theirs."""
+        blocks = blocks or (A.DEFAULT_BLOCK_Q, A.DEFAULT_BLOCK_K)
+        assert A._window_blocks(window, *blocks) == want
+
+    def test_a_windowed_call_at_the_defaults_runs_the_window_s_tiles(self):
+        rng = np.random.RandomState(4)
+        q, k, v = rand_qkv(rng, 1, 512, 2, 64)
+
+        def text(*tiles):
+            return jax.jit(lambda q, k, v: A.flash_attention(
+                q, k, v, None, None, True, *tiles, window=64)).lower(
+                    q, k, v).as_text()
+        assert text() == text(128, 128) != text(256, 256)
+
+    def test_the_lse_variant_takes_the_band(self):
+        rng = np.random.RandomState(6)
+        q, k, v = rand_qkv(rng, 1, 384, 2, 64)
+        o, lse = jax.jit(lambda q, k, v: A.flash_attention_lse(
+            q, k, v, None, None, True, 128, 128, window=96))(q, k, v)
+        with jax.default_matmul_precision("highest"):
+            want = A.attention_reference(q, k, v, None, None, True, 96)
+        np.testing.assert_allclose(np.asarray(o), np.asarray(want),
+                                   atol=2e-4, rtol=2e-4)
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / 8.0
+        lag = jnp.arange(384)[:, None] - jnp.arange(384)[None, :]
+        s = jnp.where((lag >= 0) & (lag < 96), s, -jnp.inf)
+        np.testing.assert_allclose(
+            np.asarray(lse), np.asarray(jax.scipy.special.logsumexp(s, -1)),
+            atol=1e-4, rtol=1e-5)
+
+    @pytest.mark.parametrize("kw", [
+        dict(causal=False), dict(bias=True), dict(dropout_rate=0.1,
+                                                  dropout_seed=1),
+        dict(causal_offset=0), dict(window=0), dict(d=48)])
+    def test_what_the_band_does_not_take(self, kw):
+        kw = dict(kw)
+        d = kw.pop("d", 64)
+        q = k = v = jnp.zeros((1, 256, 2, d), jnp.float32)
+        bias = (jnp.zeros((1, 2, 256, 256), jnp.float32)
+                if kw.pop("bias", False) else None)
+        args = dict(causal=True, window=64)
+        args.update(kw)
+        with pytest.raises((ValueError, NotImplementedError)):
+            A.flash_attention(q, k, v, bias, None, block_q=128, block_k=128,
+                              **args)
+
+    @pytest.mark.parametrize("bq,bk,nq,nk", [
+        (128, 128, 4, 4), (256, 128, 2, 4), (128, 256, 4, 2),
+        (512, 512, 8, 8), (8, 128, 5, 3), (128, 384, 7, 2)])
+    @pytest.mark.parametrize("off", [0, 1, 127, 300, -1, -300])
+    @pytest.mark.parametrize("window", [1, 64, 128, 200, 512, 5000])
+    def test_band_in_tile_units(self, bq, bk, nq, nk, off, window):
+        """``first_k``/``last_k``, ``first_q``/``last_q``, the two index-map
+        clamps and the count against the one predicate, and the predicate
+        against the band's mask."""
+        fr = A._Frontier(bq, bk, nq, nk, off, window)
+        lag = (np.arange(nq * bq)[:, None] + off
+               - np.arange(nk * bk)[None, :])
+        kept = ((lag >= 0) & (lag < window)).reshape(nq, bq, nk, bk).any(
+            (1, 3))
+        runs = np.array([[bool(fr.runs(i, j)) for j in range(nk)]
+                         for i in range(nq)])
+        assert np.array_equal(runs, kept)
+        assert fr.tiles_run() == kept.sum()
+        cols, rows = np.arange(nk), np.arange(nq)
+        for i in range(nq):
+            assert np.array_equal(runs[i], (cols >= fr.first_k(i))
+                                  & (cols <= fr.last_k(i)))
+        for j in range(nk):
+            assert np.array_equal(runs[:, j], (rows >= fr.first_q(j))
+                                  & (rows <= fr.last_q(j)))
+        i, j = np.arange(nq)[:, None], np.arange(nk)[None, :]
+        kb, qb = np.asarray(fr.k_block(i, j)), np.asarray(fr.q_block(i, j))
+        assert 0 <= kb.min() and kb.max() < nk
+        assert 0 <= qb.min() and qb.max() < nq
+        # a running step's own block; a skipped one names a block of its
+        # row's (column's) band where there is one, so nothing is fetched
+        # for it that the band's steps do not fetch
+        assert np.array_equal(np.where(runs, kb, -1), np.where(runs, j, -1))
+        assert np.array_equal(np.where(runs, qb, -1), np.where(runs, i, -1))
+        for r in range(nq):
+            if runs[r].any():
+                assert set(kb[r]) == set(np.flatnonzero(runs[r]))
+        for c in range(nk):
+            if runs[:, c].any():
+                assert set(qb[:, c]) == set(np.flatnonzero(runs[:, c]))
+
+    @pytest.mark.parametrize("t,tile,band,causal", [
+        (4096, 512, 15, 36), (4096, 1024, 7, 10), (4096, 256, 45, 136)])
+    def test_tiles_run_at_the_cells_length(self, t, tile, band, causal):
+        """A window of 512 at 4096 tokens: the band against causal
+        attention's tiles, a head group, of a grid of ``(t / tile)^2``."""
+        grid = (t // tile) ** 2
+        assert A._causal_tiles(tile, tile, t, t, True, 512) == (band, grid)
+        assert A._causal_tiles(tile, tile, t, t, True) == (causal, grid)
+        # the (query, key) pairs the band keeps: 23.4% of causal's
+        pairs = sum(min(i + 1, 512) for i in range(t))
+        assert (pairs, t * (t + 1) // 2) == (1966336, 8390656)
+
+
 def test_lse_variant_offsets_are_keyword_only():
     """A ninth positional argument used to be ``causal_offset`` and would
     now bind to ``dropout_rate``: it must fail at the call site."""
@@ -916,14 +1120,31 @@ MIB = 2 ** 20
     pytest.param(dict(batch=16, s=512, d=64, nh=16, itemsize=2, causal=True,
                       tiles=(1, 1)),
                  (512, 512, 8, None, "fused"), id="single-block-one-tile"),
+    # the window cell's attention: 8 k/v heads of 128 repeated to 72 q heads
+    # (window layers) and 48 (global), 4096 tokens. The window layers take
+    # the op's tiles for a window of 512 (``_window_blocks``: 512 x 512),
+    # four heads a step under the default limit: the band runs 15 of 64
+    # tiles; the global layers keep the op's 1024 x 1024 tiles, two heads a
+    # step under the raised limit (the configuration Qwen3-Next's d 256
+    # compiles with on the v5e): 10 of 16
+    pytest.param(dict(batch=1, s=4096, d=128, nh=72, itemsize=2,
+                      causal=True, window=512, tiles=(15, 64)),
+                 (512, 512, 4, None, "two_kernel"),
+                 id="window-d128-72-heads-s4096-band"),
+    pytest.param(dict(batch=1, s=4096, d=128, nh=48, itemsize=2, causal=True,
+                      tiles=(10, 16)),
+                 (1024, 1024, 2, 32 * MIB, "two_kernel_raised"),
+                 id="global-d128-48-heads-s4096-raised"),
 ])
 def test_backward_plan(shape, want):
     kw = dict(shape)
     batch, s, d, nh, itemsize = (
         kw.pop(k) for k in ("batch", "s", "d", "nh", "itemsize"))
-    blocks = kw.pop("blocks", (A.DEFAULT_BLOCK_Q, A.DEFAULT_BLOCK_K))
+    window = kw.pop("window", None)
+    blocks = kw.pop("blocks", A._window_blocks(
+        window, A.DEFAULT_BLOCK_Q, A.DEFAULT_BLOCK_K))
     causal, tiles = kw.pop("causal", None), kw.pop("tiles", None)
     plan = A._bwd_plan(nh, d, s, s, batch * nh, itemsize, *blocks, **kw)
     assert tuple(plan) == want
     if tiles is not None:
-        assert plan.tiles(s, s, causal) == tiles
+        assert plan.tiles(s, s, causal, window) == tiles

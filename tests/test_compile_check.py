@@ -99,6 +99,20 @@ def test_the_causal_skip_cell_cases_run_at_a_small_size(heads, kv_heads, d,
     cc._causal_skip_case(1, heads, kv_heads, d, t=512, tiles=tiles)
 
 
+def test_the_window_cell_cases_run_at_a_small_size():
+    """``attention/window-{band,skip}-d128-s4096-cell`` hold the window
+    cell's heads, band and tiles over 4096 tokens on the chip: against the
+    dense band on a prefix, and the band's skip against the whole grid bit
+    for bit. Here the same cases, interpreted, at 512 tokens and a window of
+    100 keys (no whole number of tiles), in the op's 128-tiles for it."""
+    names = [n for n, _ in cc.CASES]
+    for name in ("attention/window-band-d128-s4096-cell",
+                 "attention/window-skip-d128-s4096-cell"):
+        assert name in names
+    cc._gqa_cell_case(1, 4, 2, 128, t=512, prefix=256, window=100)
+    cc._causal_skip_case(1, 4, 2, 128, t=512, window=100)
+
+
 def test_the_causal_skip_reaches_the_causal_multi_block_kernels_alone():
     """``attention/causal-skip-no-extra-dispatch`` at 256 tokens in 128-tiles:
     non-causal and single-block programs are the same text with the skip and

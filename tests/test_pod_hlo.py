@@ -595,6 +595,41 @@ def test_grouped_query_attention_compiles_for_the_v5e_at_the_cell_s_shape(
         assert f"({kernel})" in text or f"/{kernel}/" in text, kernel
 
 
+@pytest.mark.parametrize("heads,window", [
+    pytest.param(72, 512, id="window-72-heads-512-tiles"),
+    pytest.param(48, None, id="global-48-heads-own-tiles")])
+def test_window_and_global_attention_compile_for_the_v5e_at_the_cell_s_shape(
+        v5e_chip, monkeypatch, heads, window):
+    """``laguna_s.lm_s4096_b1_v12k``'s two kinds of attention: one sequence
+    of 4096 tokens, 72 (window) or 48 (global) q heads on 8 k/v heads of
+    128, bfloat16, the op's own tiles. The window layers' band at 512 x 512
+    tiles, four heads a step, both bounds of the frontier in the index maps;
+    the global layers' causal frontier at 1024 x 1024 tiles, two heads a
+    step under the raised limit."""
+    from apex_tpu import ops
+    from apex_tpu.ops import _dispatch, attention
+    for mod in (_dispatch, attention):
+        monkeypatch.setattr(mod, "use_interpret", lambda: False)
+    q = jax.ShapeDtypeStruct((1, 4096, heads, 128), jnp.bfloat16,
+                             sharding=v5e_chip)
+    kv = jax.ShapeDtypeStruct((1, 4096, 8, 128), jnp.bfloat16,
+                              sharding=v5e_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(ops.flash_attention(
+            q, k, v, None, 128 ** -0.5, True,
+            window=window).astype(jnp.float32))
+
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            q, kv, kv).compile()
+    text = compiled.as_text()
+    for kernel in ("apex_attn_fwd", "apex_attn_bwd_dq", "apex_attn_bwd_dkv"):
+        assert f"({kernel})" in text or f"/{kernel}/" in text, kernel
+    dk, dv = compiled.out_info[1:]
+    assert dk.shape == dv.shape == (1, 4096, 8, 128)
+
+
 def test_the_causal_skip_leaves_the_other_kernels_mosaic_text_alone(
         v5e_chip, monkeypatch):
     """``attention/causal-skip-no-extra-dispatch`` lowered for the described
