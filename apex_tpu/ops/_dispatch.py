@@ -40,8 +40,9 @@ KERNEL_NAMES = (
     "apex_layer_norm_fwd", "apex_layer_norm_bwd",
     "apex_xentropy_fwd", "apex_xentropy_bwd",
     "apex_mlp_fwd",
-    # delta_rule.py: a chunk's terms, state step and output, and their backward
-    "apex_kda_fwd", "apex_kda_bwd",
+    # delta_rule.py: a chunk's terms, state step and output, and their
+    # backward; a decay a key channel, then one a head
+    "apex_kda_fwd", "apex_kda_bwd", "apex_gdn_fwd", "apex_gdn_bwd",
     # short_conv.py: convolution + SiLU + l2-norm in the scan's layout
     "apex_short_conv_fwd", "apex_short_conv_bwd",
     # grouped_matmul.py: rows sorted by group, a matrix a group (ops/moe.py)

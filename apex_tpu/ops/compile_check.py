@@ -610,22 +610,28 @@ def _():
 
 # --- gated delta rule --------------------------------------------------------
 
-def _delta_rule_cell_case(t=8192, prefix=512, precision=None):
-    """Qwen3-Next's DeltaNet at the cell's shape (16 key heads serving 32
-    value heads of 128, one decay a head, float32 as the model hands them
-    over): the two kernels, fed the decay broadcast and the key heads
-    repeated, against the recurrence, one step a token: the output at every
-    token, all five gradients on a prefix (the recurrence's backward keeps a
-    state a token)."""
-    from apex_tpu.ops.delta_rule import (gated_delta_rule,
-                                         gated_delta_rule_reference)
+def _gdn_cell_inputs(t):
+    """Qwen3-Next's DeltaNet operands at ``t`` tokens: 16 key heads serving
+    32 value heads of 128, one decay a head, float32 as the model hands them
+    over."""
     unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
     q = unit(_rand((1, t, 16, 128), 0)) * 128 ** -0.5
     k = unit(_rand((1, t, 16, 128), 1))
     v = _rand((1, t, 32, 128), 2)
     g = -jnp.abs(_rand((1, t, 32), 3, scale=0.5)) - 1e-3
     beta = jax.nn.sigmoid(_rand((1, t, 32), 4))
-    args = (q, k, v, g, beta)
+    return q, k, v, g, beta
+
+
+def _delta_rule_cell_case(t=8192, prefix=512, precision=None):
+    """Qwen3-Next's DeltaNet at the cell's shape: the two kernels of one
+    decay a head (``apex_gdn_fwd``, ``apex_gdn_bwd``: the decay as it is,
+    the key heads read in place) against the recurrence, one step a token:
+    the output at every token, all five gradients on a prefix (the
+    recurrence's backward keeps a state a token)."""
+    from apex_tpu.ops.delta_rule import (gated_delta_rule,
+                                         gated_delta_rule_reference)
+    args = _gdn_cell_inputs(t)
     loss = lambda fn: lambda *a: jnp.sum(
         fn(*a)[:, :prefix] * jnp.cos(jnp.arange(128.0)))
     with (jax.default_matmul_precision(precision) if precision
@@ -638,7 +644,7 @@ def _delta_rule_cell_case(t=8192, prefix=512, precision=None):
         want = jax.jit(jax.grad(loss(gated_delta_rule_reference),
                                 argnums=range(5)))(
             *(x[:, :prefix] for x in args))
-    assert out.shape == v.shape and out.dtype == jnp.float32
+    assert out.shape == args[2].shape and out.dtype == jnp.float32
     _rel("delta rule fwd", out, ref, 2e-2)
     for name, x, a, b in zip("q k v g beta".split(), args, grads, want):
         assert a.shape == x.shape, name
@@ -655,6 +661,40 @@ def _():
     # a suite or a user under ``highest``: Mosaic refuses float32 passes
     # over bfloat16 operands, and the kernels' sums name their precision
     _delta_rule_cell_case(precision="highest")
+
+
+def _scalar_vs_broadcast_case(t=8192, tol=1e-2):
+    """The kernels of one decay a head against the per-channel kernels fed
+    what it is short for (the decay broadcast over the 128 key channels, the
+    key heads repeated a pair) at the cell's shape: output and all five
+    gradients of a weighted sum over every token, to ``tol`` of each one's
+    largest magnitude. Both forms run the same sums at float32 and the state
+    and output products at the default precision; they differ in the order
+    of a chunk's score sums and in where the shared key heads' cotangents
+    are summed."""
+    from apex_tpu.ops.delta_rule import gated_delta_rule
+    args = _gdn_cell_inputs(t)
+
+    def wide(q, k, v, g, beta):
+        return (jnp.repeat(q, 2, 2), jnp.repeat(k, 2, 2), v,
+                jnp.broadcast_to(g[..., None], v.shape), beta)
+
+    def both(prepare):
+        fn = lambda *a: gated_delta_rule(*prepare(*a))
+        loss = lambda *a: jnp.sum(fn(*a) * jnp.cos(jnp.arange(128.0)))
+        return jax.jit(lambda *a: (fn(*a), jax.grad(
+            loss, argnums=range(5))(*a)))(*args)
+
+    (out, grads), (ref, want) = both(lambda *a: a), both(wide)
+    _rel("scalar against broadcast fwd", out, ref, tol)
+    for name, x, a, b in zip("q k v g beta".split(), args, grads, want):
+        assert a.shape == x.shape, name
+        _rel(f"scalar against broadcast d{name}", a, b, tol)
+
+
+@case("delta_rule/scalar-vs-broadcast-cell")
+def _():
+    _scalar_vs_broadcast_case()
 
 
 # --- short convolution -------------------------------------------------------

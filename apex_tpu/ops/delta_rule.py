@@ -22,15 +22,16 @@ each chunk's start is carried from chunk to chunk. Three parts:
 
 The op has its own backward (``jax.custom_vjp``). Head sizes of whole
 128-lane tiles (the published KDA and gated-DeltaNet sizes) take two Pallas
-kernels, ``apex_kda_fwd`` and ``apex_kda_bwd``, further down: all three
-parts a chunk at a time, chunks in order, the state in VMEM, so that the
-score matrices, the solve, the chunk's terms and every cotangent of them
-never reach HBM. From the forward to the backward they keep the inputs, the
-chunk-start states and ``(I + A)^-1`` (``C`` floats a token and head). Any
-other head size takes the three parts as ``jax.numpy``, ``HEAD_GROUP`` heads
-at a time, and keeps the inputs and the states; its backward runs
-``_prepare`` again. What the op sees of its inputs' shapes picks the path;
-no argument does.
+kernels further down, ``apex_kda_fwd`` and ``apex_kda_bwd`` for a decay a
+key channel, ``apex_gdn_fwd`` and ``apex_gdn_bwd`` for one a head: all
+three parts a chunk at a time, chunks in order, the state in VMEM, so that
+the score matrices, the solve, the chunk's terms and every cotangent of
+them never reach HBM. From the forward to the backward they keep the
+inputs, the chunk-start states and ``(I + A)^-1`` (``C`` floats a token and
+head). Any other head size takes the three parts as ``jax.numpy``,
+``HEAD_GROUP`` heads at a time, and keeps the inputs and the states; its
+backward runs ``_prepare`` again. What the op sees of its inputs' shapes
+picks the path; no argument does.
 
 Under recomputation. The forward rule names what it hands the backward
 beside the inputs (the output, the states and, from the kernel, ``(I +
@@ -50,6 +51,12 @@ exponents <= 0, and inside a sub-block the ``(SUB, SUB, d_k)`` terms are
 summed as they are (the kernels split at the middle of every block of 2, 4,
 ... ``CHUNK`` tokens instead, see there). Nothing that can overflow is
 formed; what underflows is a contribution that is zero in float32 anyway.
+With one decay a head (``g`` of ``(B, T, H)``) the kernels take ``g`` as it
+is: a split's two exponents are one number a token each, so the ``(C, C)``
+decay matrix is built from their outer products on the vector unit, still
+from sums of ``g`` that are ``<= 0``, and the scores are one matmul times
+it; ``d g`` comes back ``(B, T, H)``. The ``jax.numpy`` form broadcasts a
+decay a head over the key channels.
 
 Precision. State, decay and the solve are float32 whatever the inputs
 (``gated_delta_rule`` is a FLOAT op of ``amp/lists.py``); the matmuls take
@@ -148,9 +155,17 @@ def _prepare(q, k, v, g, beta):
 # ``(heads C, d)``, under the same masks, which keep the heads apart because
 # no level reaches across ``C``. A dependent chain of small matmuls waits on
 # the matrix unit's latency once for all of them, and a grid step's fixed
-# cost is shared.
+# cost is shared. Where a step's heads are exactly one key head's group, the
+# kernels of one decay a head read that key head's block by the index map
+# and stack it once a head; the backward sums the heads' cotangents of it
+# before it writes the block, so a key head has one writer.
 
 HEADS_A_STEP = 2
+
+
+def _step_heads(h):
+    """Heads a grid step takes of ``h``."""
+    return HEADS_A_STEP if h % HEADS_A_STEP == 0 else 1
 
 
 def _levels(c):
@@ -196,6 +211,13 @@ def _sums(matrix, x):
     return out
 
 
+def _grown(inverse, a, i, eye):
+    """``(I + A)^-1`` of blocks of ``2 L`` from that of blocks of ``L``
+    (``inverse``) and level ``i``'s part of ``A`` (``a``): block forward
+    substitution, two matmuls; at level 1 the blocks of one are ``I``."""
+    return inverse - _dot(_dot(inverse, a), inverse) if i else eye - a
+
+
 def _chunk_forward(q, k, v, g, beta, sum_matrix, inverse=None):
     """One chunk of a few heads, rows stacked: ``q, k, g`` ``(R, d_k)``, ``v``
     ``(R, d_v)``, ``beta`` ``(R, 1)``, float32, ``R`` = heads x ``CHUNK``;
@@ -223,10 +245,8 @@ def _chunk_forward(q, k, v, g, beta, sum_matrix, inverse=None):
         far = k * e_far
         level = jnp.where(differ < 2 * lv, _dot(near, far, _NT), 0.0)
         scores = scores + level
-        if build:           # (I + A)^-1 of blocks of 2 lv from those of lv
-            a = level[r:] * beta
-            inverse = (inverse - _dot(_dot(inverse, a), inverse) if i
-                       else eye - a)         # level 1: blocks of one are I
+        if build:
+            inverse = _grown(inverse, level[r:] * beta, i, eye)
         kept.append((e_near, e_far, near, far))
     aqk = scores[:r] + eye * jnp.sum(q * k, -1, keepdims=True)
     k_start = k * from_start
@@ -271,6 +291,88 @@ def _chunk_backward(q, k, v, beta, sum_matrix_t, forward, cts):
         d_k = d_k + d_near[r:] * e_near + d_far * e_far
         d_sums.append(d_near[:r] * near[:r] + d_near[r:] * near[r:]
                       + d_far * far)
+    d_g = _sums(sum_matrix_t, jnp.concatenate(d_sums, 0))
+    return d_q, d_k, d_v, d_g, d_beta
+
+
+# One decay a head (gated DeltaNet): a level's exponents are one number a
+# token, so a level's matrix is ``q k^T`` times the outer product of its
+# ``e_near`` and ``e_far`` columns. The six outer products, each under its
+# level's mask, make one decay matrix ``D`` on the vector unit (``D[t, s] =
+# exp(G_t - G_s)`` for ``s < t`` in a head, as a product of two factors
+# ``<= 1``; zero elsewhere), and the scores are one matmul ``[q; k] k^T``
+# times ``D``. The level masks still cut ``A`` for the inverse's chain.
+
+
+def _gdn_chunk_forward(q, k, v, g, beta, sum_matrix, inverse=None):
+    """``_chunk_forward`` for one decay a head: ``g`` ``(R, 1)`` like
+    ``beta``; ``from_start`` comes back ``(R, 1)``."""
+    r, dk = k.shape
+    # every lane of a row holds its token's sums: the transpose of a level's
+    # block is then its exponents along the columns
+    sums = jnp.minimum(_sums(sum_matrix, jnp.broadcast_to(g, (r, r))), 0.0)
+    part = lambda i: sums[i * r:(i + 1) * r]
+    from_start, to_end = (jnp.exp(part(i)[:, :1]) for i in (0, 1))
+    row = lax.broadcasted_iota(jnp.int32, (r, r), 0)
+    col = lax.broadcasted_iota(jnp.int32, (r, r), 1)
+    differ = row ^ col              # the bits two tokens differ in
+    eye = (differ == 0).astype(k.dtype)
+    decay = jnp.zeros((r, r), k.dtype)
+    for i in range(len(_levels(CHUNK))):
+        # the pairs whose highest differing bit is level i's: exp of the sum
+        # from s to the block's middle times exp of the one from there to t
+        e = jnp.exp(part(2 + i))
+        decay = jnp.where((row > col) & (differ >> i == 1), e * e.T, decay)
+    qk = jnp.concatenate([q, k], 0)
+    scores = _dot(qk, k, _NT) * jnp.concatenate([decay, decay], 0)
+    if inverse is None:
+        for i in range(len(_levels(CHUNK))):
+            inverse = _grown(inverse, jnp.where(differ >> i == 1, scores[r:],
+                                                0.0) * beta, i, eye)
+    aqk = scores[:r] + eye * jnp.sum(q * k, -1, keepdims=True)
+    k_start = k * from_start
+    rhs = jnp.concatenate([k_start, v], 1) * beta
+    solved = _dot(inverse, rhs)
+    out = (q * from_start, k * to_end, solved[:, :dk], solved[:, dk:], aqk,
+           from_start)
+    return out, (to_end, k_start, scores, inverse, solved, qk, decay, differ,
+                 eye)
+
+
+def _gdn_chunk_backward(q, k, v, beta, sum_matrix_t, forward, cts):
+    """``_chunk_backward`` for one decay a head: ``d g`` ``(R, 1)``."""
+    d_qg, d_kg, d_w, d_ut, d_aqk, d_from_start = cts
+    r, dk = k.shape
+    (_, kg, _, _, _, from_start), (
+        to_end, k_start, scores, inverse, solved, qk, decay, differ,
+        eye) = forward
+    # the solve: solved = inverse @ rhs, inverse = (I + beta * skk)^-1
+    d_rhs = _dot(_dot(eye, inverse, _NT), jnp.concatenate([d_w, d_ut], 1))
+    d_a = -_dot(d_rhs, solved, _NT)
+    d_beta = (jnp.sum(d_rhs[:, :dk] * k_start, -1, keepdims=True)
+              + jnp.sum(d_rhs[:, dk:] * v, -1, keepdims=True)
+              + jnp.sum(d_a * scores[r:], -1, keepdims=True))
+    d_rhs = d_rhs * beta
+    d_v = d_rhs[:, dk:]
+    on_diagonal = jnp.sum(d_aqk * eye, -1, keepdims=True)
+    d_scores = jnp.concatenate([d_aqk, d_a * beta], 0)
+    # scores = ([q; k] k^T) * D: one matmul for [q; k] on the left, one for
+    # k on the right
+    d_qk = d_scores * jnp.concatenate([decay, decay], 0)
+    d_near = _dot(d_qk, k)
+    d_far = _dot(d_qk, qk, _TN)
+    d_q = d_qg * from_start + on_diagonal * k + d_near[:r]
+    d_k = (d_rhs[:, :dk] * from_start + d_kg * to_end + on_diagonal * q
+           + d_near[r:] + d_far)
+    # a pair's term is exp(x_t) exp(x_s) of its level: its cotangent times
+    # itself goes to both exponents, row t's and column s's
+    d_terms = d_scores[:r] * scores[:r] + d_scores[r:] * scores[r:]
+    d_terms = d_terms + d_terms.T
+    d_sums = [(jnp.sum(d_qg * q + d_rhs[:, :dk] * k, -1, keepdims=True)
+               + d_from_start) * from_start,
+              jnp.sum(d_kg * kg, -1, keepdims=True)]
+    d_sums += [jnp.sum(jnp.where(differ >> i == 1, d_terms, 0.0), -1,
+                       keepdims=True) for i in range(len(_levels(CHUNK)))]
     d_g = _sums(sum_matrix_t, jnp.concatenate(d_sums, 0))
     return d_q, d_k, d_v, d_g, d_beta
 
@@ -321,6 +423,66 @@ def _eye(d):
             == lax.broadcasted_iota(jnp.int32, (d, d), 1)).astype(jnp.float32)
 
 
+def _keys(ref, heads, shared):
+    """``q`` or ``k`` as ``(heads C, d)`` rows: ``_stacked``, or one key
+    head's ``(C, d)`` block once for each of the step's value heads."""
+    if not shared:
+        return _stacked(ref, heads)
+    return jnp.concatenate([ref[...].astype(jnp.float32)] * heads, 0)
+
+
+def _steps(heads, terms, inverse, turn, out_ref, states_ref, inverse_ref,
+           state):
+    """Each head's state step and output from its chunk's terms, and what
+    the forward keeps. ``turn``: a head's last row of ``from_start`` as the
+    decay of its state's rows (a ``(d_k, 1)`` column, or the one number)."""
+    qg, kg, w, ut, aqk, from_start = terms
+    c, dv = CHUNK, ut.shape[-1]
+    for j in range(heads):
+        rows = slice(j * c, (j + 1) * c)
+        start = state[j]
+        states_ref[j] = start
+        inverse_ref[j] = inverse[rows, rows]
+        u = ut[rows] - _mm(w[rows], start)
+        out_ref[:, j * dv:(j + 1) * dv] = (
+            _mm(qg[rows], start) + _mm(aqk[rows, rows], u))
+        decay = turn(from_start[(j + 1) * c - 1:(j + 1) * c])
+        state[j] = decay * start + _mm(kg[rows], u, _TN)
+
+
+def _steps_backward(heads, terms, turn, states_ref, d_out_ref, lam_ref):
+    """The cotangents of each head's ``qg, kg, w, ut, aqk`` and of its
+    decay (in its last row) from ``d out`` and the state's, which runs back
+    through ``lam_ref``; ``turn`` as in :func:`_steps`, and back."""
+    qg, kg, w, ut, aqk, from_start = terms
+    c, dv = CHUNK, ut.shape[-1]
+    last = lax.broadcasted_iota(jnp.int32, (c, 1), 0) == c - 1
+    cts = []
+    for j in range(heads):
+        # o = qg S + aqk u, u = ut - w S, S' = decay S + kg^T u: lam is S''s
+        rows = slice(j * c, (j + 1) * c)
+        start, lam = states_ref[j], lam_ref[j]
+        d_o = d_out_ref[:, j * dv:(j + 1) * dv].astype(jnp.float32)
+        block = aqk[rows, rows]
+        u = ut[rows] - _mm(w[rows], start)
+        d_u = _mm(block, d_o, _TN) + _mm(kg[rows], lam)
+        decay = from_start[(j + 1) * c - 1:(j + 1) * c]
+        d_decay = turn(jnp.sum(start * lam, -1, keepdims=True))
+        cts.append((_mm(d_o, start, _NT), _mm(u, lam, _NT),
+                    -_mm(d_u, start, _NT), d_u, _mm(d_o, u, _NT),
+                    jnp.where(last, d_decay, 0.0)))
+        lam_ref[j] = (_mm(qg[rows], d_o, _TN) + turn(decay) * lam
+                      - _mm(w[rows], d_u, _TN))
+    return zip(*cts)
+
+
+def _whole(x):
+    """``turn`` for one decay a head: a ``(1, 1)`` decay, or a ``(d_k, 1)``
+    column of the state's rows summed, as one number (a scalar: Mosaic
+    broadcasts no ``(1, 1)`` block both ways)."""
+    return jnp.sum(x)
+
+
 def _fwd_kernel(heads, q_ref, k_ref, v_ref, g_ref, beta_ref, sums_ref,
                 out_ref, states_ref, inverse_ref, state):
     from jax.experimental import pallas as pl
@@ -331,20 +493,27 @@ def _fwd_kernel(heads, q_ref, k_ref, v_ref, g_ref, beta_ref, sums_ref,
 
     q, k, v, g = (_stacked(r, heads) for r in (q_ref, k_ref, v_ref, g_ref))
     beta = _columns(beta_ref, pl.program_id(1) * heads, heads)
-    (qg, kg, w, ut, aqk, from_start), kept = _chunk_forward(
-        q, k, v, g, beta, sums_ref[...])
-    c, dv = CHUNK, v.shape[-1]
+    terms, kept = _chunk_forward(q, k, v, g, beta, sums_ref[...])
     eye = _eye(k.shape[-1])
-    for j in range(heads):
-        rows = slice(j * c, (j + 1) * c)
-        start = state[j]
-        states_ref[j] = start
-        inverse_ref[j] = kept[3][rows, rows]
-        u = ut[rows] - _mm(w[rows], start)
-        out_ref[:, j * dv:(j + 1) * dv] = (
-            _mm(qg[rows], start) + _mm(aqk[rows, rows], u))
-        decay = _turned(from_start[(j + 1) * c - 1:(j + 1) * c], eye)
-        state[j] = decay * start + _mm(kg[rows], u, _TN)
+    _steps(heads, terms, kept[3], lambda x: _turned(x, eye), out_ref,
+           states_ref, inverse_ref, state)
+
+
+def _gdn_fwd_kernel(heads, shared, q_ref, k_ref, v_ref, g_ref, beta_ref,
+                    sums_ref, out_ref, states_ref, inverse_ref, state):
+    from jax.experimental import pallas as pl
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        state[...] = jnp.zeros_like(state)
+
+    q, k = (_keys(r, heads, shared) for r in (q_ref, k_ref))
+    v = _stacked(v_ref, heads)
+    g, beta = (_columns(r, pl.program_id(1) * heads, heads)
+               for r in (g_ref, beta_ref))
+    terms, kept = _gdn_chunk_forward(q, k, v, g, beta, sums_ref[...])
+    _steps(heads, terms, kept[3], _whole, out_ref, states_ref, inverse_ref,
+           state)
 
 
 def _bwd_kernel(heads, q_ref, k_ref, v_ref, g_ref, beta_ref, sums_ref,
@@ -361,27 +530,11 @@ def _bwd_kernel(heads, q_ref, k_ref, v_ref, g_ref, beta_ref, sums_ref,
     forward = _chunk_forward(
         q, k, v, g, beta, sums_ref[...],
         _diagonal_blocks([inverse_ref[j] for j in range(heads)]))
-    qg, kg, w, ut, aqk, from_start = forward[0]
-    c, dv = CHUNK, v.shape[-1]
+    c = CHUNK
     eye = _eye(k.shape[-1])
-    last = lax.broadcasted_iota(jnp.int32, (c, 1), 0) == c - 1
-    cts = []
-    for j in range(heads):
-        # o = qg S + aqk u, u = ut - w S, S' = decay S + kg^T u: lam is S''s
-        rows = slice(j * c, (j + 1) * c)
-        start, lam = states_ref[j], lam_ref[j]
-        d_o = d_out_ref[:, j * dv:(j + 1) * dv].astype(jnp.float32)
-        block = aqk[rows, rows]
-        u = ut[rows] - _mm(w[rows], start)
-        d_u = _mm(block, d_o, _TN) + _mm(kg[rows], lam)
-        decay = from_start[(j + 1) * c - 1:(j + 1) * c]
-        d_decay = _turned(jnp.sum(start * lam, -1, keepdims=True), eye)
-        cts.append((_mm(d_o, start, _NT), _mm(u, lam, _NT),
-                    -_mm(d_u, start, _NT), d_u, _mm(d_o, u, _NT),
-                    jnp.where(last, d_decay, 0.0)))
-        lam_ref[j] = (_mm(qg[rows], d_o, _TN) + _turned(decay, eye) * lam
-                      - _mm(w[rows], d_u, _TN))
-    d_qg, d_kg, d_w, d_ut, d_aqk, d_from_start = zip(*cts)
+    d_qg, d_kg, d_w, d_ut, d_aqk, d_from_start = _steps_backward(
+        heads, forward[0], lambda x: _turned(x, eye), states_ref, d_out_ref,
+        lam_ref)
     *grads, d_beta = _chunk_backward(
         q, k, v, beta, sums_t_ref[...], forward,
         tuple(jnp.concatenate(x, 0) for x in (d_qg, d_kg, d_w, d_ut))
@@ -394,31 +547,76 @@ def _bwd_kernel(heads, q_ref, k_ref, v_ref, g_ref, beta_ref, sums_ref,
         dbeta_ref[j] = d_beta[j * c:(j + 1) * c]
 
 
+def _gdn_bwd_kernel(heads, shared, q_ref, k_ref, v_ref, g_ref, beta_ref,
+                    sums_ref, sums_t_ref, states_ref, inverse_ref, d_out_ref,
+                    dq_ref, dk_ref, dv_ref, dgb_ref, lam_ref):
+    from jax.experimental import pallas as pl
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        lam_ref[...] = jnp.zeros_like(lam_ref)
+
+    q, k = (_keys(r, heads, shared) for r in (q_ref, k_ref))
+    v = _stacked(v_ref, heads)
+    g, beta = (_columns(r, pl.program_id(1) * heads, heads)
+               for r in (g_ref, beta_ref))
+    forward = _gdn_chunk_forward(
+        q, k, v, g, beta, sums_ref[...],
+        _diagonal_blocks([inverse_ref[j] for j in range(heads)]))
+    c = CHUNK
+    d_qg, d_kg, d_w, d_ut, d_aqk, d_from_start = _steps_backward(
+        heads, forward[0], _whole, states_ref, d_out_ref, lam_ref)
+    d_q, d_k, d_v, d_g, d_beta = _gdn_chunk_backward(
+        q, k, v, beta, sums_t_ref[...], forward,
+        tuple(jnp.concatenate(x, 0) for x in (d_qg, d_kg, d_w, d_ut))
+        + (_diagonal_blocks(d_aqk), jnp.concatenate(d_from_start, 0)))
+    head = lambda x, j: x[j * c:(j + 1) * c]
+    by_head = [(dq_ref, d_q), (dk_ref, d_k), (dv_ref, d_v)]
+    if shared:      # the step's heads are the key head's group: one writer
+        for ref, x in by_head[:2]:
+            ref[...] = functools.reduce(
+                jnp.add, (head(x, j) for j in range(heads))).astype(ref.dtype)
+        by_head = by_head[2:]
+    for ref, x in by_head:
+        d = x.shape[-1]
+        for j in range(heads):
+            ref[:, j * d:(j + 1) * d] = head(x, j).astype(ref.dtype)
+    for j in range(heads):
+        dgb_ref[j] = jnp.concatenate([head(d_g, j), head(d_beta, j)], 1)
+
+
 def _tiled(dk, dv):
     """Whether the kernels take these head sizes: whole 128-lane tiles."""
     return dk % 128 == 0 and dv % 128 == 0
 
 
-def _specs(q, v, beta, reverse=False):
-    """Grid and block specs of both kernels: ``(batch, heads, chunk)``, the
+def _specs(q, v, beta, reverse=False, group=1):
+    """Grid and block specs of the kernels: ``(batch, heads, chunk)``, the
     chunks in order (``reverse``: last first); the model's ``(B, T, H d)``
-    layout for tokens, chunk-major ``(N, B, H, ., .)`` for the rest."""
+    layout for tokens, chunk-major ``(N, B, H, ., .)`` for the rest.
+    ``inputs``: the per-channel kernels'; ``gdn_inputs``: those of one decay
+    a head, ``g`` read as ``beta`` is and ``q, k`` at the step's key head
+    where the step's heads are one key head's ``group``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     b, t, h = beta.shape
-    dk, dv, chunks, c = q.shape[-1] // h, v.shape[-1] // h, t // CHUNK, CHUNK
-    heads = HEADS_A_STEP if h % HEADS_A_STEP == 0 else 1
+    dk, dv, chunks, c = (q.shape[-1] * group // h, v.shape[-1] // h,
+                         t // CHUNK, CHUNK)
+    heads = _step_heads(h)
     at = (lambda n: chunks - 1 - n) if reverse else (lambda n: n)
     token = lambda d: pl.BlockSpec((None, c, heads * d),
                                    lambda b, h, n: (b, at(n), h))
     chunk = lambda r, d: pl.BlockSpec((None, None, heads, r, d),
                                       lambda b, h, n: (at(n), b, h, 0, 0))
+    column = pl.BlockSpec((None, c, h), lambda b, h, n: (b, at(n), 0))
+    keys = pl.BlockSpec((None, c, heads * dk // group),
+                        lambda b, h, n: (b, at(n), h))
     sums = _sum_matrix(c, heads)
     return dict(
         heads=heads, dk=dk, dv=dv, grid=(b, h // heads, chunks), token=token,
         chunk=chunk,
-        inputs=[token(dk), token(dk), token(dv), token(dk),
-                pl.BlockSpec((None, c, h), lambda b, h, n: (b, at(n), 0))],
+        inputs=[token(dk), token(dk), token(dv), token(dk), column],
+        gdn_inputs=[keys, keys, token(dv), column, column],
         per_chunk=lambda r, d: jax.ShapeDtypeStruct((chunks, b, h, r, d),
                                                     jnp.float32),
         sums=jnp.asarray(sums, jnp.bfloat16),
@@ -471,6 +669,47 @@ def _backward_kernel(q, k, v, g, beta, states, inverse, d_out):
     return (*grads, d_beta.astype(beta.dtype))
 
 
+@jit_launcher(static_argnums=(5,))
+def _gdn_forward_kernel(q, k, v, g, beta, group):
+    """``_forward_kernel`` for one decay a head: ``g`` ``(B, T, H)``; ``q, k``
+    ``(B, T, H d_k / group)``, ``group`` 1 or the heads of a grid step."""
+    sp = _specs(q, v, beta, group=group)
+    (b, t, h), dk, dv, c = beta.shape, sp["dk"], sp["dv"], CHUNK
+    out, states, inverse = pallas_call(
+        functools.partial(_gdn_fwd_kernel, sp["heads"], group > 1),
+        name="apex_gdn_fwd", grid=sp["grid"],
+        in_specs=sp["gdn_inputs"] + [sp["whole"](sp["sums"])],
+        out_specs=[sp["token"](dv), sp["chunk"](dk, dv), sp["chunk"](c, c)],
+        out_shape=[jax.ShapeDtypeStruct((b, t, h * dv), jnp.float32),
+                   sp["per_chunk"](dk, dv), sp["per_chunk"](c, c)],
+        scratch_shapes=[sp["state"]], compiler_params=sp["params"],
+    )(q, k, v, g, beta, sp["sums"])
+    return out.reshape(b, t, h, dv), states, inverse
+
+
+@jit_launcher(static_argnums=(8,))
+def _gdn_backward_kernel(q, k, v, g, beta, states, inverse, d_out, group):
+    """Cotangents of ``_gdn_forward_kernel``'s inputs, in their layout and
+    dtypes: a key head's from the one grid step that reads it."""
+    sp = _specs(q, v, beta, reverse=True, group=group)
+    (b, t, h), dk, dv, c = beta.shape, sp["dk"], sp["dv"], CHUNK
+    *grads, d_gb = pallas_call(
+        functools.partial(_gdn_bwd_kernel, sp["heads"], group > 1),
+        name="apex_gdn_bwd", grid=sp["grid"],
+        in_specs=sp["gdn_inputs"] + [
+            sp["whole"](sp["sums"]), sp["whole"](sp["sums_t"]),
+            sp["chunk"](dk, dv), sp["chunk"](c, c), sp["token"](dv)],
+        out_specs=sp["gdn_inputs"][:3] + [sp["chunk"](c, 2)],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
+                   for x in (q, k, v)] + [sp["per_chunk"](c, 2)],
+        scratch_shapes=[sp["state"]], compiler_params=sp["params"],
+    )(q, k, v, g, beta, sp["sums"], sp["sums_t"], states, inverse,
+      d_out.reshape(b, t, h * dv))
+    d_g, d_beta = (jnp.transpose(d_gb[..., i], (1, 0, 3, 2)).reshape(b, t, h)
+                   for i in (0, 1))
+    return (*grads, d_g.astype(g.dtype), d_beta.astype(beta.dtype))
+
+
 def _chunk_step(state, w, ut, kg, decay):
     u = ut - w @ state
     return decay[..., None] * state + jnp.swapaxes(kg, -1, -2) @ u
@@ -517,14 +756,19 @@ def _prepared(q, k, v, g, beta):
                     _chunked(beta[..., None])[..., 0])
 
 
-def _forward(q, k, v, g, beta):
+def _forward(q, k, v, g, beta, group):
     """The output ``(B, T, H, d_v)`` and what the backward keeps beside the
-    inputs: the chunk-start states and, from the kernel, ``(I + A)^-1``."""
+    inputs: the chunk-start states and, from the kernel, ``(I + A)^-1``.
+    ``group``: value heads a key head of ``q, k`` serves, 1 but where the
+    kernels of one decay a head read shared key heads in place."""
     from apex_tpu.amp.functional_patch import suspend
     with suspend():                     # float32 here whatever the policy
         with jax.named_scope("kda/scan"):
             if q.ndim == 3:             # (B, T, H d): the kernels' layout
-                out, *kept = _forward_kernel(q, k, v, g, beta)
+                if g.shape == beta.shape:           # one decay a head
+                    out, *kept = _gdn_forward_kernel(q, k, v, g, beta, group)
+                else:
+                    out, *kept = _forward_kernel(q, k, v, g, beta)
                 return out, tuple(kept)
             qg, kg, w, ut, aqk, decay = _prepared(q, k, v, g, beta)
             states = _propagate(w, ut, kg, decay)
@@ -534,21 +778,24 @@ def _forward(q, k, v, g, beta):
     return out, (states,)
 
 
-@jax.custom_vjp
-def _scan(q, k, v, g, beta):
-    return _forward(q, k, v, g, beta)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _scan(q, k, v, g, beta, group):
+    return _forward(q, k, v, g, beta, group)[0]
 
 
-def _scan_fwd(q, k, v, g, beta):
-    out, kept = _forward(q, k, v, g, beta)
+def _scan_fwd(q, k, v, g, beta, group):
+    out, kept = _forward(q, k, v, g, beta, group)
     out, *kept = (checkpoint_name(x, KEPT_KDA) for x in (out, *kept))
     return out, (q, k, v, g, beta, *kept)
 
 
-def _scan_bwd(res, d_out):
+def _scan_bwd(group, res, d_out):
     from apex_tpu.amp.functional_patch import suspend
     q, k, v, g, beta, states, *inverse = res
     with suspend(), jax.named_scope("kda/scan"):
+        if inverse and g.shape == beta.shape:
+            return _gdn_backward_kernel(q, k, v, g, beta, states, *inverse,
+                                        d_out, group)
         if inverse:
             return _backward_kernel(q, k, v, g, beta, states, *inverse, d_out)
         (qg, kg, w, ut, aqk, decay), back = jax.vjp(_prepared, q, k, v, g, beta)
@@ -582,11 +829,14 @@ def gated_delta_rule(q, k, v, g, beta, head_dim=None):
     arrangement of the TPU's ``(8, 128)`` tiles: a copy each way). The
     output is ``(B, T, H, d_v)`` either way.
 
-    What the op sees of the shapes picks the form, no argument does. A
-    decay a head and shared key heads reach the per-channel form (and its
-    kernels) as what they are short for: ``g`` broadcast over the key
-    channels, ``q`` and ``k`` repeated a group. Exact, and the cotangents
-    come back summed by the broadcast's own transpose.
+    What the op sees of the shapes picks the form, no argument does. For
+    head sizes the kernels take, a decay a head takes kernels of its own
+    (``apex_gdn_fwd``, ``apex_gdn_bwd``) that read ``g`` as it is, and key
+    heads shared by exactly the value heads of a grid step
+    (``HEADS_A_STEP``) where they are; other sharing reaches the kernels as
+    what it is short for, ``q`` and ``k`` repeated a group, as does a decay
+    a head the ``jax.numpy`` form, broadcast over the key channels. Exact,
+    and the cotangents come back summed by the repeat's own transpose.
     """
     (b, t, h), flat = beta.shape, q.ndim == 3
     dk = head_dim if flat else q.shape[-1]
@@ -595,16 +845,14 @@ def gated_delta_rule(q, k, v, g, beta, head_dim=None):
     group = h * dk // (q.size // (b * t))    # value heads a key head
     if _tiled(dk, dv):
         # the kernels' layout, (B, T, H d). A head is a lane range there:
-        # the repeat and the broadcast are written as ranges side by side,
-        # because (B, T, H, group, d) is another arrangement of the tiles
+        # a repeat is written as ranges side by side, because (B, T, H,
+        # group, d) is another arrangement of the tiles
         q, k, v, g = (x.reshape(b, t, -1) for x in (q, k, v, g))
-        if group > 1:
+        if group > 1 and not (one_a_head and group == _step_heads(h)):
             q, k = (jnp.concatenate(
                 [x[..., i:i + dk] for i in range(0, x.shape[-1], dk)
                  for _ in range(group)], -1) for x in (q, k))
-        if one_a_head:
-            g = jnp.concatenate([jnp.broadcast_to(g[..., i:i + 1], (b, t, dk))
-                                 for i in range(h)], -1)
+            group = 1
     else:
         q, k = (jnp.broadcast_to(x.reshape(b, t, -1, 1, dk),
                                  (b, t, h // group, group, dk))
@@ -612,6 +860,7 @@ def gated_delta_rule(q, k, v, g, beta, head_dim=None):
         v = v.reshape(b, t, h, dv)
         g = (jnp.broadcast_to(g[..., None], (b, t, h, dk)) if one_a_head
              else g.reshape(b, t, h, dk))
+        group = 1
     pad = -t % CHUNK
     if pad:
         q, k, v, g, beta = (
@@ -620,11 +869,11 @@ def gated_delta_rule(q, k, v, g, beta, head_dim=None):
     if (h > HEAD_GROUP and h % HEAD_GROUP == 0 and q.ndim == 4):
         grouped = lambda x: jnp.moveaxis(x.reshape(
             *x.shape[:2], h // HEAD_GROUP, HEAD_GROUP, *x.shape[3:]), 2, 0)
-        out = lax.map(lambda xs: _scan(*xs),
+        out = lax.map(lambda xs: _scan(*xs, 1),
                       tuple(map(grouped, (q, k, v, g, beta))))
         out = jnp.moveaxis(out, 0, 2).reshape(*q.shape[:3], -1)
     else:
-        out = _scan(q, k, v, g, beta)
+        out = _scan(q, k, v, g, beta, group)
     return out[:, :t]
 
 

@@ -66,6 +66,15 @@ def test_the_qwen3_next_cell_cases_run_at_a_small_size():
     cc._delta_rule_cell_case(t=128, prefix=64, precision="highest")
 
 
+def test_the_scalar_vs_broadcast_case_runs_at_a_small_size():
+    """``delta_rule/scalar-vs-broadcast-cell``: the kernels of one decay a
+    head against the per-channel kernels fed the broadcast, at the cell's
+    heads; on the chip at 8192 tokens to 1e-2, here interpreted at two
+    chunks, where both are float32 throughout, to 1e-5."""
+    assert "delta_rule/scalar-vs-broadcast-cell" in [n for n, _ in cc.CASES]
+    cc._scalar_vs_broadcast_case(t=128, tol=1e-5)
+
+
 def test_the_expert_layers_cell_cases_run_at_a_small_size(monkeypatch):
     """``moe/{4-of-64,8-of-256,10-of-512}-cell`` hold each decoder cell's
     routing (tokens, chosen of routed, held) and widths; on the chip they

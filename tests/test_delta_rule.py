@@ -151,21 +151,26 @@ def shared(args, scalar=True, ratio=2):
             g[..., 0] if scalar else g, beta)
 
 
-@pytest.mark.parametrize("g_scale", [0.1, 5.0])
+@pytest.mark.parametrize("g_scale", [0.1, 5.0, 1.0])
 @pytest.mark.parametrize("length", [64, 100])
 @pytest.mark.parametrize("dim", [16, 128])
 def test_scalar_decay_and_shared_key_heads_equal_recurrent(dim, length,
                                                            g_scale):
     """``g`` of ``(B, T, H)`` and 2 value heads a key head: the chunked form
-    (``dim`` 16) and the kernels (128, interpreted) against the recurrence,
-    output and all five gradients, in the shapes they came in."""
+    (``dim`` 16) and the kernels (128, interpreted; a grid step's two value
+    heads read their key head in place) against the recurrence, output and
+    all five gradients, in the shapes they came in."""
     args = shared(inputs(length, 1, length, 4, dim, dim, g_scale))
     assert args[0].shape[2] == 2 and args[3].shape == (1, length, 4)
     if dim == 128:
-        assert "apex_kda_fwd/" in jax.jit(gated_delta_rule).lower(
+        assert "apex_gdn_fwd/" in jax.jit(gated_delta_rule).lower(
             *args).as_text(debug_info=True)
+    _equal_recurrent(args, dim, length)
+
+
+def _equal_recurrent(args, dim, length):
     out, ref = gated_delta_rule(*args), gated_delta_rule_reference(*args)
-    assert out.shape == ref.shape == (1, length, 4, dim)
+    assert out.shape == ref.shape == (1, length) + args[2].shape[2:]
     assert bool(jnp.all(jnp.isfinite(out)))
     assert float(jnp.max(jnp.abs(out - ref))) <= 2e-6
     got = jax.grad(weighted(gated_delta_rule), argnums=range(5))(*args)
@@ -178,24 +183,72 @@ def test_scalar_decay_and_shared_key_heads_equal_recurrent(dim, length,
             jnp.max(jnp.abs(b))), name
 
 
+@pytest.mark.parametrize("g_scale", [0.1, 1.0, 5.0])
+@pytest.mark.parametrize("heads,key_heads", [
+    (4, 4),             # own key heads, two a grid step
+    (8, 2),             # four value heads a key head: repeated a group
+    (3, 3),             # an odd count: one head a grid step
+], ids=["own_keys", "shared_by_four", "odd_heads"])
+def test_scalar_kernels_equal_recurrent(heads, key_heads, g_scale):
+    """The kernels of one decay a head (interpreted, ``dim`` 128) against
+    the recurrence at 100 tokens (two chunks, the second padded), output and
+    all five gradients in the shapes they came in, where the key heads meet
+    a grid step's value heads otherwise than in the test above."""
+    full = inputs(heads, 1, 100, heads, 128, 128, g_scale)
+    args = shared(full, ratio=heads // key_heads)
+    assert args[0].shape[2] == key_heads and args[3].shape == (1, 100, heads)
+    lowered = jax.jit(gated_delta_rule).lower(*args).as_text(debug_info=True)
+    assert "apex_gdn_fwd/" in lowered and "apex_kda" not in lowered
+    _equal_recurrent(args, 128, 100)
+
+
+def test_strong_decay_forms_nothing_unbounded_in_the_scalar_kernel():
+    """One decay a head at g = -5 a step, key heads shared in place: what
+    the forward kernel writes, and every term of a chunk (the decay matrix,
+    the scores, the inverse), bounded."""
+    q, k, v, g, beta = shared(inputs(0, 1, 128, 4, 128, 128, 5.0))
+    flat = lambda x: x.reshape(1, 128, -1)
+    for term in delta_rule._gdn_forward_kernel(*map(flat, (q, k, v)), g,
+                                               beta, 2):
+        assert bool(jnp.all(jnp.isfinite(term)))
+        assert float(jnp.max(jnp.abs(term))) < 1e3
+    # the first chunk of value heads 0 and 1, both on key head 0
+    rows = lambda x, heads=(0, 1): jnp.concatenate(
+        [x[0, :64, j] for j in heads], 0)
+    column = lambda x: rows(x[..., None])
+    terms = delta_rule._gdn_chunk_forward(
+        rows(q, (0, 0)), rows(k, (0, 0)), rows(v), column(g), column(beta),
+        jnp.asarray(delta_rule._sum_matrix(64, 2), jnp.bfloat16))
+    for term in jax.tree_util.tree_leaves(terms):
+        assert bool(jnp.all(jnp.isfinite(term)))
+        assert float(jnp.max(jnp.abs(term))) < 1e3
+    decay = terms[1][6]
+    assert float(jnp.max(decay)) <= 1.0 and float(jnp.min(decay)) >= 0.0
+
+
 @pytest.mark.parametrize("dim", [16, 128])
 def test_scalar_path_equals_per_channel_path_fed_a_broadcast(dim):
     """A decay a head is the per-channel form's with every channel alike,
-    shared key heads are that form's repeated: to the bit in the output, and
-    the cotangents are the broadcast's summed."""
+    shared key heads are that form's repeated, and the cotangents are the
+    broadcast's summed. In the ``jax.numpy`` form (``dim`` 16) the op is
+    that form, to the bit in the output; the kernels (128) have a form of
+    their own, which sums a chunk's scores in another order: float32
+    against float32, to ``2e-6`` of each result's largest magnitude."""
     full = inputs(5, 1, 100, 4, dim, dim, 1.0)
     q, k, v, g, beta = shared(full)
     wide = (jnp.repeat(q, 2, 2), jnp.repeat(k, 2, 2), v,
             jnp.broadcast_to(g[..., None], v.shape[:3] + (dim,)), beta)
-    assert bool(jnp.all(gated_delta_rule(q, k, v, g, beta)
-                        == gated_delta_rule(*wide)))
+    out, out_wide = gated_delta_rule(q, k, v, g, beta), gated_delta_rule(*wide)
+    out_tol, grad_tol = (0.0, 1e-6) if dim == 16 else (2e-6, 2e-6)
+    assert float(jnp.max(jnp.abs(out - out_wide))) <= out_tol * float(
+        jnp.max(jnp.abs(out_wide)))
     got = jax.grad(weighted(gated_delta_rule), argnums=range(5))(
         q, k, v, g, beta)
     want = jax.grad(weighted(gated_delta_rule), argnums=range(5))(*wide)
     pairs = lambda x: x.reshape(*x.shape[:2], 2, 2, -1).sum(3)
     for a, b in zip(got, (pairs(want[0]), pairs(want[1]), want[2],
                           want[3].sum(-1), want[4])):
-        assert float(jnp.max(jnp.abs(a - b))) <= 1e-6 * max(
+        assert float(jnp.max(jnp.abs(a - b))) <= grad_tol * max(
             1.0, float(jnp.max(jnp.abs(b))))
     # each alone: shared heads with a decay a channel, a decay a head with
     # every head its own keys
@@ -214,7 +267,9 @@ def test_heads_side_by_side_equal_heads_apart(dim, ratio, decay):
     T, H, d)``: the output to the bit and the gradients in the layout each
     came in; with a decay a channel and a head, own and shared key heads;
     in the kernels (``dim`` 128, where the flat operands reach
-    ``apex_kda_fwd`` untouched) and in the chunked form (16)."""
+    ``apex_kda_fwd`` or, with a decay a head, ``apex_gdn_fwd`` untouched:
+    there ``g`` is not broadcast and shared key heads are not repeated) and
+    in the chunked form (16)."""
     args = shared(inputs(7, 1, 100, 4, dim, dim, 0.5),
                   scalar=decay == "a_head", ratio=ratio)
     flat = lambda x: x.reshape(*x.shape[:2], -1) if x.ndim == 4 else x
@@ -234,6 +289,33 @@ def test_heads_side_by_side_equal_heads_apart(dim, ratio, decay):
         assert float(jnp.max(jnp.abs(a - flat(b)))) <= 1e-6 * max(
             1.0, float(jnp.max(jnp.abs(b)))), name
     if dim == 128:
-        traced = str(jax.make_jaxpr(fn)(*side_by_side))
-        before = traced.split("apex_kda_fwd")[0]
+        before = _bound_before(jax.make_jaxpr(fn)(*side_by_side).jaxpr,
+                               "apex_gdn_fwd" if decay == "a_head"
+                               else "apex_kda_fwd")
+        assert before is not None
         assert "reshape" not in before and "transpose" not in before
+        if decay == "a_head":
+            assert "concatenate" not in before
+            assert not any(p.startswith("broadcast") for p in before)
+
+
+def _bound_before(jaxpr, kernel):
+    """The primitives bound ahead of the first ``pallas_call`` named
+    ``kernel`` (calls inside calls walked through, no kernel's body), or
+    None where there is no such call."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+    bound = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            if eqn.params["name"] == kernel:
+                return bound
+            continue
+        inner = [p for p in eqn.params.values()
+                 if isinstance(p, (Jaxpr, ClosedJaxpr))]
+        if inner:               # jit, custom_vjp_call, checkpoint
+            found = _bound_before(getattr(inner[0], "jaxpr", inner[0]),
+                                  kernel)
+            if found is not None:
+                return bound + found
+        bound.append(eqn.primitive.name)
+    return None

@@ -107,9 +107,21 @@ def _delta_rule_case():
     return delta_rule, "apex_kda_fwd", loss, (q, k, v, g, beta)
 
 
+def _gated_deltanet_case():
+    """One decay a head and a key head for every two value heads: the
+    kernels of their own keep the same three names."""
+    keys = jax.random.split(jax.random.PRNGKey(8), 5)
+    q, k = (jax.random.normal(key, (1, 128, 1, 128)) for key in keys[:2])
+    v = jax.random.normal(keys[2], (1, 128, 2, 128))
+    g = -jax.nn.softplus(jax.random.normal(keys[3], (1, 128, 2)))
+    beta = jax.nn.sigmoid(jax.random.normal(keys[4], (1, 128, 2)))
+    loss = lambda *xs: jnp.sum(ops.gated_delta_rule(*xs))
+    return delta_rule, "apex_gdn_fwd", loss, (q, k, v, g, beta)
+
+
 CASES = pytest.mark.parametrize(
-    "case", [_attention_case, _delta_rule_case],
-    ids=["flash_attention", "gated_delta_rule"])
+    "case", [_attention_case, _delta_rule_case, _gated_deltanet_case],
+    ids=["flash_attention", "gated_delta_rule", "gated_deltanet"])
 
 
 @CASES
@@ -140,5 +152,5 @@ def test_a_users_checkpoint_keeps_them(case):
     assert kernels(grad(), *args)[forward] == 2
     assert kernels(grad(policy=KEEP), *args)[forward] == 1
     one = jax.checkpoint_policies.save_only_these_names(
-        ops.KEPT_ATTN if forward == "apex_kda_fwd" else ops.KEPT_KDA)
+        ops.KEPT_KDA if forward == "apex_attn_fwd" else ops.KEPT_ATTN)
     assert kernels(grad(policy=one), *args)[forward] == 2

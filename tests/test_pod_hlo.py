@@ -651,25 +651,45 @@ def test_kda_kernels_take_a_scalar_decay_and_shared_key_heads_for_the_v5e(
         v5e_chip, monkeypatch, dtype, precision):
     """``qwen3_next.lm_s8192_b1_v19k``'s delta rule: 16 key heads serving 32
     value heads of 128, one decay a head, 8192 tokens: the two kernels of
-    the Kimi cell, fed the decay broadcast and the key heads repeated, and
-    the cotangents summed back into the shapes that came in."""
+    one decay a head, which read the decay as it is and a key head where it
+    is, and write a key head's cotangent once. What interpret mode cannot
+    see: the transposes of ``(128, 128)`` blocks (the decay matrix's
+    columns, the exponents' cotangents), the ``(C, 1)`` columns through the
+    0/1 sums, a key head's ``(C, 128)`` block read at its own index."""
+    _gdn_compiles(v5e_chip, monkeypatch, 8192, 16, 32, dtype, precision)
+
+
+def _gdn_compiles(v5e_chip, monkeypatch, tokens, key_heads, heads, dtype,
+                  precision):
     from apex_tpu.ops import _dispatch, delta_rule
     monkeypatch.setattr(_dispatch, "use_interpret", lambda: False)
     shape = lambda *s, dt=jnp.float32: jax.ShapeDtypeStruct(
         s, dt, sharding=v5e_chip)
-    qk = shape(1, 8192, 16, 128, dt=dtype)
-    args = (qk, qk, shape(1, 8192, 32, 128, dt=dtype),
-            shape(1, 8192, 32), shape(1, 8192, 32))
+    qk = shape(1, tokens, key_heads, 128, dt=dtype)
+    args = (qk, qk, shape(1, tokens, heads, 128, dt=dtype),
+            shape(1, tokens, heads), shape(1, tokens, heads))
     loss = lambda *a: jnp.sum(delta_rule.gated_delta_rule(*a))
     with jax.default_matmul_precision(precision):
         compiled = jax.jit(jax.grad(loss, argnums=range(5))).lower(
             *args).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 2
-    for kernel in ("apex_kda_fwd", "apex_kda_bwd"):
+    for kernel in ("apex_gdn_fwd", "apex_gdn_bwd"):
         assert f"({kernel})" in text or f"/{kernel}/" in text, kernel
+    assert "apex_kda" not in text
     assert "triangular-solve" not in text and "while" not in text
     assert [o.shape for o in compiled.out_info] == [a.shape for a in args]
+
+
+@pytest.mark.parametrize("key_heads,heads", [(3, 3), (3, 6), (2, 8)],
+                         ids=["odd_heads", "shared_in_place", "shared_by_four"])
+def test_gdn_kernels_compile_for_the_v5e_at_other_head_counts(
+        v5e_chip, monkeypatch, key_heads, heads):
+    """One decay a head at 256 tokens where a grid step holds one head (an
+    odd count: ``(64, 64)`` blocks to transpose), where the steps' pairs of
+    heads share a key head, and where four value heads do (repeated)."""
+    _gdn_compiles(v5e_chip, monkeypatch, 256, key_heads, heads, jnp.float32,
+                  "default")
 
 
 @pytest.mark.parametrize("cell,wide,channels,norm", [

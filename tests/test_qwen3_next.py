@@ -221,9 +221,10 @@ def test_remat_changes_nothing(toy):
 
 def test_kernels_in_the_lowered_step(monkeypatch):
     """The toy's DeltaNet heads are 16 wide and take the ``jax.numpy`` chunked
-    form: at the published head size of 128 the scan is the two KDA kernels,
-    fed the scalar decay as a broadcast and the 2 key heads repeated. One
-    period of four layers, every block recomputed: lowered for the TPU the
+    form: at the published head size of 128 the scan is the two kernels of
+    one decay a head, which read the decay as it is and each of the 2 key
+    heads where it is (no KDA kernel, no broadcast, no repeat). One period
+    of four layers, every block recomputed: lowered for the TPU the
     differentiated loss holds each forward kernel once and each backward
     kernel once a layer (three DeltaNet layers, one attention layer on the
     two-kernel or the fused backward), and no triangular solve."""
@@ -241,8 +242,9 @@ def test_kernels_in_the_lowered_step(monkeypatch):
         text = step.trace(params).lower(
             lowering_platforms=("tpu",)).as_text(debug_info=True)
     kernels = _dispatch.kernel_calls(text)
-    assert kernels["apex_kda_fwd"] == 3
-    assert kernels["apex_kda_bwd"] == 3
+    assert kernels["apex_gdn_fwd"] == 3
+    assert kernels["apex_gdn_bwd"] == 3
+    assert kernels["apex_kda_fwd"] == kernels["apex_kda_bwd"] == 0
     # one convolution over q, k and v a layer: forward, rerun, backward
     assert kernels["apex_short_conv_fwd"] == 6
     assert kernels["apex_short_conv_bwd"] == 3

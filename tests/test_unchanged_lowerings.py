@@ -12,7 +12,10 @@ of its StableHLO text to the one the tree before the window read. The text
 prints no source locations, so the digests hold for any checkout path; they
 move with any change of what the program computes, which is the point: a
 change that means to alter one of these programs updates its digest here,
-and says why.
+and says why. ``delta-rule-per-channel-kernels-d128`` holds the delta
+rule's per-channel kernels (Kimi's cell) to the text they had before the
+kernels of one decay a head were added beside them: no cell's toy step
+reaches either at its 16-wide heads.
 """
 
 import hashlib
@@ -50,6 +53,8 @@ OP_DIGESTS = {
         "6fea0cfbfabd4baf7e8d570bcdf4c75adf98e6048a7ace0c46ae8472dabe2e3f",
     "partial-rotary-half-of-64":
         "23102f09e7f780b725728cab96575a38087161dd098ba8dec639f5a1d526b9ac",
+    "delta-rule-per-channel-kernels-d128":
+        "c57d1fe6ed85de729307e16edef08e4d7a97486a4137ae8c0782ba626ae5a086",
 }
 
 
@@ -92,6 +97,14 @@ def op_text(case):
         x = jax.ShapeDtypeStruct((1, 64, 2, 64), jnp.float32)
         return jax.jit(jax.grad(lambda x: jnp.sum(
             partial_rotary(x, 32, 1e6) ** 2))).lower(x).as_text()
+    if case == "delta-rule-per-channel-kernels-d128":
+        # the KDA kernels (interpreted), a decay a key channel: what the
+        # kernels of one decay a head were added beside
+        x = jax.ShapeDtypeStruct((1, 128, 4, 128), jnp.float32)
+        beta = jax.ShapeDtypeStruct((1, 128, 4), jnp.float32)
+        return jax.jit(jax.value_and_grad(lambda *a: jnp.sum(
+            ops.gated_delta_rule(*a) ** 2), argnums=range(5))).lower(
+                x, x, x, x, beta).as_text()
     t, h, hkv, d, causal, tiles = shapes[case]
     q = jax.ShapeDtypeStruct((1, t, h, d), jnp.bfloat16)
     kv = jax.ShapeDtypeStruct((1, t, hkv, d), jnp.bfloat16)
