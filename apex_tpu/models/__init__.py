@@ -1,16 +1,19 @@
 """apex_tpu.models — the model families the reference's examples/configs
 exercise (BASELINE.json): ResNet (imagenet example), DCGAN (multi-loss amp
 example), BERT-style transformer (FusedLAMB config), RNN stacks
-(`apex.RNN`), and three decoders over one shell (`decoder.py`: pre-norm
+(`apex.RNN`), and five decoders over one shell (`decoder.py`: pre-norm
 blocks, routed experts at one expert-parallel rank's share, next-token loss):
 Kimi-Linear (delta-rule linear attention with a decay a channel, latent
 attention), Qwen3-Next (gated DeltaNet with a decay a head and shared key
-heads, gated grouped-query attention with partial rotary, softmax router) and
+heads, gated grouped-query attention with partial rotary, softmax router),
 LFM2-MoE (gated short convolutions, grouped-query attention with rotary over
-the whole head behind per-head q/k norms, no shared expert, a tied head) and
+the whole head behind per-head q/k norms, no shared expert, a tied head),
 Laguna (sliding-window and global grouped-query attention, 3 : 1, with a
 sigmoid gate a head and YaRN rotary on the global layers, softmax-routed
-experts beside an ungated shared one).
+experts beside an ungated shared one) and DeepSeek-V3 (latent attention
+with an interleaved rotary part in every layer, sigmoid-routed experts beside
+a shared one); Kimi-Linear and DeepSeek-V3 share the latent attention of
+``mla.py``.
 """
 
 from apex_tpu.models.resnet import (
@@ -23,12 +26,12 @@ from apex_tpu.models.transformer import (
 )
 from apex_tpu.models.dcgan import Generator, Discriminator
 from apex_tpu.models.decoder import (
-    Block, Decoder, ExpertFFN, RMSNorm, SwiGLU, lm_loss, partial_rotary,
-    yarn_frequencies,
+    Block, Decoder, ExpertFFN, RMSNorm, SwiGLU, interleaved_rotary, lm_loss,
+    partial_rotary, yarn_frequencies,
 )
+from apex_tpu.models.mla import LatentAttention
 from apex_tpu.models.kimi_linear import (
-    KimiLinear, KimiLinearDims, KimiDeltaAttention, LatentAttention,
-    kimi_linear_from_config,
+    KimiLinear, KimiLinearDims, KimiDeltaAttention, kimi_linear_from_config,
 )
 from apex_tpu.models.qwen3_next import (
     Qwen3Next, Qwen3NextDims, GatedDeltaNet, GatedAttention,
@@ -40,6 +43,9 @@ from apex_tpu.models.lfm2 import (
 )
 from apex_tpu.models.laguna import (
     Laguna, LagunaDims, HeadGatedAttention, laguna_from_config,
+)
+from apex_tpu.models.deepseek_v3 import (
+    DeepseekV3, DeepseekV3Dims, deepseek_v3_from_config,
 )
 
 __all__ = [
@@ -56,4 +62,6 @@ __all__ = [
     "Lfm2Moe", "Lfm2Dims", "GatedShortConv", "GroupedQueryAttention",
     "lfm2_moe_from_config", "yarn_frequencies",
     "Laguna", "LagunaDims", "HeadGatedAttention", "laguna_from_config",
+    "DeepseekV3", "DeepseekV3Dims", "deepseek_v3_from_config",
+    "interleaved_rotary",
 ]

@@ -1,12 +1,14 @@
 """What the decoders share: the pre-norm block shell over a float32 residual
 stream, RMSNorm, SwiGLU, the expert layer of one expert-parallel rank, the
-short convolution's taps, the rotary embedding, the head (its own matrix or
-the embedding's) and the next-token loss.
+short convolution's taps, the rotary embedding (half-split or interleaved
+pairs), the head (its own matrix or the embedding's) and the next-token
+loss.
 
 A decoder is :class:`Decoder` over a ``dims`` of its own (a frozen
 dataclass: ``models/kimi_linear.py``, ``models/qwen3_next.py``,
-``models/lfm2.py``, ``models/laguna.py``). The shell asks ``dims`` for what
-differs between them and holds no model's name:
+``models/lfm2.py``, ``models/laguna.py``, ``models/deepseek_v3.py``). The
+shell asks ``dims`` for what differs between them and holds no model's
+name:
 
 ``dims.mixer(kind)``   the token mixer of a layer of that kind, a module
                        named by its kind (``kda``, ``mla``, ``gdn``,
@@ -118,6 +120,18 @@ def partial_rotary(x, rotary_dim, theta, positions=None, inv_freq=None,
     a, b = x[..., :half], x[..., half:rotary_dim]
     return jnp.concatenate(
         [a * cos - b * sin, b * cos + a * sin, x[..., rotary_dim:]], -1)
+
+
+def interleaved_rotary(x, theta):
+    """Rotary position embedding over every channel of each head in
+    interleaved pairs ``(2i, 2i + 1)``, as ``transformers``'
+    ``apply_rotary_pos_emb_interleave``: pair ``i`` turns by ``p
+    theta^(-2i / D)`` at position ``p``, and the result is laid out
+    de-interleaved (the pairs' first channels, then their second). q and k
+    both taken through it, their scores equal those of pairs turned in
+    place. ``x`` ``(B, T, H, D)``; float32, as :func:`partial_rotary`."""
+    return partial_rotary(jnp.concatenate([x[..., 0::2], x[..., 1::2]], -1),
+                          x.shape[-1], theta)
 
 
 def yarn_frequencies(rope, rotary_dim):

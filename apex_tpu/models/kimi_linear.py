@@ -7,9 +7,10 @@ Built from a configuration in the keys of the model's own ``config.json``
 :func:`kimi_linear_from_config` reads each layer's kind from it. The block
 shell, the norms, the expert layer of one expert-parallel rank's share, the
 untied head and the next-token loss are ``models/decoder.py``'s, which
-``models/qwen3_next.py`` shares; the two mixers are here. Written for
-``amp.auto_cast``: the projections are ``nn.Dense`` (half under O1); the
-decay, the delta-rule state, the router and the norms are float32
+``models/qwen3_next.py`` shares; MLA is ``models/mla.py``'s (without
+positions here), shared with ``models/deepseek_v3.py``; KDA is here.
+Written for ``amp.auto_cast``: the projections are ``nn.Dense`` (half under
+O1); the decay, the delta-rule state, the router and the norms are float32
 (``amp/lists.py``).
 
 Every part runs under a ``jax.named_scope`` a device trace can be cut by:
@@ -27,17 +28,11 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from apex_tpu import ops
 from apex_tpu.models.decoder import (
     Decoder, ExpertFFN, RMSNorm, _conv_init, _dense, _init, lm_loss)
+from apex_tpu.models.mla import LatentAttention
 from apex_tpu.ops.delta_rule import gated_delta_rule
 from apex_tpu.ops.short_conv import short_conv
-
-#: (block_q, block_k) of MLA's attention. The kernels' VMEM ledger was
-#: fitted at a head size of 64: at 192 the v5e's compiler refuses their
-#: default 1024 x 1024 (17.7 MiB of the 16 MiB scoped VMEM in the forward)
-#: and 512 x 512 (19.6 MiB in the dk/dv backward), and takes this
-_ATTN_TILES = (1024, 256)
 
 
 def _a_log_init(key, shape, dtype=jnp.float32):
@@ -91,41 +86,6 @@ class KimiDeltaAttention(nn.Module):
             o = RMSNorm(self.eps, name="o_norm")(o) * jax.nn.sigmoid(
                 heads(gate).astype(jnp.float32))
             return _dense(self.hidden, "o_proj")(o.reshape(b, t, h * d))
-
-
-class LatentAttention(nn.Module):
-    """Multi-head latent attention, no position encoding: the shared part of
-    the key (``rope_dim`` wide) goes in unrotated."""
-    hidden: int
-    heads: int
-    kv_rank: int
-    nope_dim: int
-    rope_dim: int
-    v_dim: int
-    eps: float = 1e-5
-
-    @nn.compact
-    def __call__(self, x):
-        b, t, _ = x.shape
-        h, dq = self.heads, self.nope_dim + self.rope_dim
-        with jax.named_scope("mla/proj"):
-            q = _dense(h * dq, "q_proj")(x).reshape(b, t, h, dq)
-            c = _dense(self.kv_rank + self.rope_dim, "kv_a")(x)
-            c_kv = RMSNorm(self.eps, name="kv_norm")(c[..., :self.kv_rank])
-            kv = _dense(h * (self.nope_dim + self.v_dim), "kv_b")(c_kv)
-            kv = kv.reshape(b, t, h, self.nope_dim + self.v_dim)
-            k_pe = jnp.broadcast_to(c[:, :, None, self.kv_rank:].astype(
-                kv.dtype), (b, t, h, self.rope_dim))
-            k = jnp.concatenate([kv[..., :self.nope_dim], k_pe], -1)
-            # the kernels take one head size: v's tail is zeros, dropped below
-            v = jnp.pad(kv[..., self.nope_dim:],
-                        [(0, 0)] * 3 + [(0, dq - self.v_dim)])
-        with jax.named_scope("mla/attn"):
-            o = ops.flash_attention(q, k, v, None, dq ** -0.5, True,
-                                    *_ATTN_TILES)
-            o = o[..., :self.v_dim].reshape(b, t, h * self.v_dim)
-        with jax.named_scope("mla/out"):
-            return _dense(self.hidden, "o_proj")(o)
 
 
 @dataclasses.dataclass(frozen=True)

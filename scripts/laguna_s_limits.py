@@ -1,18 +1,21 @@
-"""The readings the window cell's limits are set from, on the chip.
+"""The readings a decoder cell's limits are set from, on the chip.
 
     python scripts/laguna_s_limits.py --seeds 1,2,3 [--control-seeds 1]
-        [--compare-seeds 1] [--steps 48] [--rehearse] [--out FILE]
+        [--compare-seeds 1] [--steps 48] [--workload CELL] [--rehearse]
+        [--out FILE]
 
-Builds ``laguna_s.lm_s4096_b1_v12k`` as ``benchmark/run.py`` does (pool,
-weights and state from ``--seed``; ``--rehearse`` at the toy size on the
-CPU). For the seeds named it runs ``benchmark/reference/laguna_s.py``'s
-``compare`` on the untrained state: on the system (``--compare-seeds``),
-and on the control (``--control-seeds``: the reference's own loss and
-logits in bfloat16, ``reference.control``), which has to come out not
-correct. Then every seed trains ``--steps`` steps, step ``i`` on batch ``i
-mod pool`` as the harness's warm-up and window do, and the losses are
-printed: the traffic's ``loss_band`` is read from them. One JSON line a
-seed; ``--out`` keeps them all.
+Builds the cell (default ``laguna_s.lm_s4096_b1_v12k``; also
+``kanana2.lm_s8192_b1_v16k``, or any cell whose reference has a
+``control``) as ``benchmark/run.py`` does (pool, weights and state from
+``--seed``; ``--rehearse`` at the toy size on the CPU). For the seeds named
+it runs ``benchmark/reference/<config>.py``'s ``compare`` on the untrained
+state: on the system (``--compare-seeds``), and on the control
+(``--control-seeds``: the reference's own loss and logits in bfloat16,
+``reference.control``), which has to come out not correct. Then every seed
+trains ``--steps`` steps, step ``i`` on batch ``i mod pool`` as the
+harness's warm-up and window do, and the losses are printed: the traffic's
+``loss_band`` is read from them. One JSON line a seed; ``--out`` keeps them
+all.
 """
 
 import argparse
@@ -34,6 +37,7 @@ def main(argv=None):
     ap.add_argument("--control-seeds", type=_seeds, default=[])
     ap.add_argument("--compare-seeds", type=_seeds, default=[])
     ap.add_argument("--steps", type=int, default=48)
+    ap.add_argument("--workload", default=CELL)
     ap.add_argument("--rehearse", action="store_true")
     ap.add_argument("--out", default=None)
     args = ap.parse_args(argv)
@@ -49,7 +53,7 @@ def main(argv=None):
     if not args.rehearse:
         enable_compile_cache()
 
-    cell = run.load_json("workloads", CELL + ".json")
+    cell = run.load_json("workloads", args.workload + ".json")
     sizes = run.load_json("configs", cell["config"] + ".json")
     traffic = run.load_json("traffic", cell["traffic"] + ".json")
     if args.rehearse:

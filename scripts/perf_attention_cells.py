@@ -27,7 +27,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 import jax
 import jax.numpy as jnp
 
-from apex_tpu.models.kimi_linear import _ATTN_TILES
+from apex_tpu.models.mla import _ATTN_TILES
 from apex_tpu.ops import attention as A
 
 #: name, batch, q heads, k/v heads, head size, tiles (empty: the op's own)

@@ -476,13 +476,14 @@ def v5e_chip():
 
 def test_mla_attention_compiles_for_the_v5e_at_the_cell_s_shape(
         v5e_chip, monkeypatch):
-    """``kimi_linear.lm_s8192_b1``'s attention: 32 heads of 192, causal, 8192
-    tokens, forward and both backward kernels through Mosaic, at the tiles
-    ``models/kimi_linear.py`` passes. Interpret mode cannot see what this
-    sees: the kernels' default 1024 x 1024 tiles take 17.7 MiB of the 16 MiB
-    of scoped VMEM at this head size (PR 28)."""
+    """``kimi_linear.lm_s8192_b1``'s attention, and
+    ``kanana2.lm_s8192_b1_v16k``'s: 32 heads of 192, causal, 8192 tokens,
+    forward and both backward kernels through Mosaic, at the tiles
+    ``models/mla.py`` passes. Interpret mode cannot see what this sees: the
+    kernels' default 1024 x 1024 tiles take 17.7 MiB of the 16 MiB of scoped
+    VMEM at this head size."""
     from apex_tpu import ops
-    from apex_tpu.models import kimi_linear
+    from apex_tpu.models import mla
     from apex_tpu.ops import _dispatch, attention
     for mod in (_dispatch, attention):
         monkeypatch.setattr(mod, "use_interpret", lambda: False)
@@ -492,7 +493,7 @@ def test_mla_attention_compiles_for_the_v5e_at_the_cell_s_shape(
     def loss(q, k, v):
         return jnp.sum(ops.flash_attention(
             q, k, v, None, 192 ** -0.5, True,
-            *kimi_linear._ATTN_TILES).astype(jnp.float32))
+            *mla._ATTN_TILES).astype(jnp.float32))
 
     # the suite's "highest" is for float32 oracles; the chip runs the default
     with jax.default_matmul_precision("default"):
