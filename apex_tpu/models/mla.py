@@ -26,16 +26,17 @@ from apex_tpu import ops
 from apex_tpu.models.decoder import RMSNorm, _dense, interleaved_rotary
 
 #: (block_q, block_k) of MLA's attention. The kernels' VMEM ledger was
-#: fitted at a head size of 64: at 192 the v5e's compiler refuses their
-#: default 1024 x 1024 (17.7 MiB of the 16 MiB scoped VMEM in the forward)
-#: and 512 x 512 (19.6 MiB in the dk/dv backward), and takes this
+#: fitted at a head size of 64: at q/k's 192 (v then padded to 192) the
+#: v5e's compiler refused their default 1024 x 1024 (17.7 MiB of the 16 MiB
+#: scoped VMEM in the forward) and 512 x 512 (19.6 MiB in the dk/dv
+#: backward), and took this, which v at its own 128 keeps
 _ATTN_TILES = (1024, 256)
 
 
 class LatentAttention(nn.Module):
     """Causal multi-head latent attention, softmax scale ``(nope_dim +
-    rope_dim)^-1/2``; ``v`` is zero-padded to the q/k head size for the
-    kernels, which take one head size, and its tail dropped after."""
+    rope_dim)^-1/2``; ``v`` goes to the attention op at its own head size
+    ``v_dim``, below q's and k's, and ``o`` comes back at it."""
     hidden: int
     heads: int
     kv_rank: int
@@ -67,11 +68,10 @@ class LatentAttention(nn.Module):
             k_pe = jnp.broadcast_to(k_pe.astype(kv.dtype),
                                     (b, t, h, self.rope_dim))
             k = jnp.concatenate([kv[..., :self.nope_dim], k_pe], -1)
-            v = jnp.pad(kv[..., self.nope_dim:],
-                        [(0, 0)] * 3 + [(0, dq - self.v_dim)])
+            v = kv[..., self.nope_dim:]
         with jax.named_scope("mla/attn"):
             o = ops.flash_attention(q, k, v, None, dq ** -0.5, True,
                                     *_ATTN_TILES)
-            o = o[..., :self.v_dim].reshape(b, t, h * self.v_dim)
+            o = o.reshape(b, t, h * self.v_dim)
         with jax.named_scope("mla/out"):
             return _dense(self.hidden, "o_proj")(o)

@@ -759,27 +759,32 @@ def _flash_bwd(q3, k3, v3, bias_g, bidx, o3, lse, do3, scale, causal,
 # transposed path would have used.
 
 
-def _native_g0(nh: int, d: int) -> Optional[int]:
-    """Smallest head-group g with (g·d) lane-aligned; None = no native
-    path (heads not groupable into a lane-aligned block)."""
+def _native_g0(nh: int, d: int,
+               d_v: Optional[int] = None) -> Optional[int]:
+    """Smallest head-group g with g·d and g·d_v (v's head size, ``d`` when
+    None) both lane-aligned; None = no native path (heads not groupable
+    into a lane-aligned block)."""
     if d <= 0:
         return None
-    g0 = 128 // int(np.gcd(d, 128))
+    g0 = int(np.lcm(*(128 // np.gcd(x, 128) for x in (d, d_v or d))))
     if nh % g0:
         return None
     return g0
 
 
 def _native_g(nh, d, dropout_rate, bq, bk, itemsize, *, bias_isz=0,
-              bias_per_head=False, carry_scratch=True):
+              bias_per_head=False, carry_scratch=True, d_v=None):
     """Heads per grid step on the native path: at least g0 (lane
     alignment), more when the forward kernel's VMEM ledger fits the
     16 MiB scoped budget (in-blocks, scratch, score tile, out-blocks;
     packing amortizes per-step DMA setup). Dropout adds a (bq, bk)
     keep-mask/hash temporary; a bias adds its double-buffered
-    (g|1, bq, bk) in-block. Always one of g0·{4, 2, 1} that divides
-    ``nh``."""
-    g0 = _native_g0(nh, d)
+    (g|1, bq, bk) in-block. ``d_v`` (v's head size, ``d`` when None)
+    sizes the v block, the accumulator and the o block: the backward's
+    plan asks at its own v, the forward kernel takes v padded to ``d``.
+    Always one of g0·{4, 2, 1} that divides ``nh``."""
+    d_v = d if d_v is None else d_v
+    g0 = _native_g0(nh, d, d_v)
     # full ledger of what the fwd kernel keeps in scoped VMEM: the
     # double-buffered q/k/v in-blocks, the m/l/acc scratch, the f32
     # score tile, the o and lse out-blocks (also double-buffered), and
@@ -791,8 +796,8 @@ def _native_g(nh, d, dropout_rate, bq, bk, itemsize, *, bias_isz=0,
         g = g0 * mult
         if nh % g:
             continue
-        gd = g * d
-        half_bufs = (bq + 2 * bk) * gd * itemsize * 2
+        gd, gd_v = g * d, g * d_v
+        half_bufs = ((bq + bk) * gd + bk * gd_v) * itemsize * 2
         # single-k (no carry): the m/l/acc scratch disappears, but the
         # fp32 PV result and its divided copy live as stack temps (the
         # acc role, twice) and up to three score-class tiles coexist
@@ -800,10 +805,10 @@ def _native_g(nh, d, dropout_rate, bq, bk, itemsize, *, bias_isz=0,
         # measured 16.73 MiB OOM at fp32 S=512 g=8 (the ledger must
         # reject g=8 there — a single-temp estimate lands at exactly
         # the 16 MiB boundary and slips through; g=4 fits).
-        scratch = (g * bq * 2 * LANES * 4 + bq * gd * 4
-                   if carry_scratch else 2 * bq * gd * 4)
+        scratch = (g * bq * 2 * LANES * 4 + bq * gd_v * 4
+                   if carry_scratch else 2 * bq * gd_v * 4)
         score = bq * bk * 4 * (1 if carry_scratch else 3)
-        outs = bq * gd * itemsize * 2 + g * bq * LANES * 4 * 2
+        outs = bq * gd_v * itemsize * 2 + g * bq * LANES * 4 * 2
         bias_buf = ((g if bias_per_head else 1) * bq * bk * bias_isz * 2
                     if bias_isz else 0)
         if (half_bufs + scratch + score + outs + mask_tmp + bias_buf
@@ -968,21 +973,21 @@ def _fwd_kernel_nl(scale, causal, kv_len, q_len, dropout_rate, d, g,
                 + jnp.zeros((bq_, lse_ref.shape[1]), jnp.float32)
 
 
-def _head_specs(nh, g, bq, bk, gd, fr=None):
-    """(q, k, bias index map) over (B, S, H) with head columns in the lane
-    axis; grid dim 0 enumerates (batch, head-group) pairs group-minor,
-    k innermost. Under a static frontier ``fr`` a skipped step's k / v /
-    bias block is the one its row's last running step holds: no fetch."""
+def _head_specs(nh, g, bq, bk, gd, gd_v, fr=None):
+    """(q, k, v, o, bias index map) over (B, S, H) with head columns in
+    the lane axis, v and o (and dO) ``gd_v`` wide; grid dim 0 enumerates
+    (batch, head-group) pairs group-minor, k innermost. Under a static
+    frontier ``fr`` a skipped step's k / v / bias block is the one its
+    row's last running step holds: no fetch."""
     hg = nh // g
     kb = (lambda i, j: j) if fr is None else fr.k_block
-    q_spec = pl.BlockSpec((1, bq, gd),
-                          lambda t, i, j: (t // hg, i, t % hg),
-                          memory_space=pltpu.VMEM)
-    k_spec = pl.BlockSpec((1, bk, gd),
-                          lambda t, i, j: (t // hg, kb(i, j), t % hg),
-                          memory_space=pltpu.VMEM)
+    q_map = lambda t, i, j: (t // hg, i, t % hg)
+    k_map = lambda t, i, j: (t // hg, kb(i, j), t % hg)
+    spec = lambda rows, w, index: pl.BlockSpec((1, rows, w), index,
+                                               memory_space=pltpu.VMEM)
     bias_idx = lambda row: lambda t, i, j: (row(t), i, kb(i, j))
-    return q_spec, k_spec, bias_idx
+    return (spec(bq, gd, q_map), spec(bk, gd, k_map), spec(bk, gd_v, k_map),
+            spec(bq, gd_v, q_map), bias_idx)
 
 
 def _lse_reorder(lse_rows, bh, g, nq, bq):
@@ -1071,7 +1076,7 @@ def _flash_fwd_nl(q2, k2, v2, nh, d, scale, causal, block_q, block_k,
     # the arithmetic alone (the maps cannot read it)
     fr = (None if nk == 1 or causal_off is not None
           else _frontier(causal, bq, bk, sq, sk, sk - sq, window))
-    q_spec, k_spec, bias_idx = _head_specs(nh, g, bq, bk, gd, fr)
+    q_spec, k_spec, _, _, bias_idx = _head_specs(nh, g, bq, bk, gd, gd, fr)
     in_specs = [q_spec, k_spec, k_spec]
     args = [qp, kp, vp]
     _append_common(
@@ -1112,8 +1117,8 @@ def _flash_fwd_nl(q2, k2, v2, nh, d, scale, causal, block_q, block_k,
     return o[:, :sq, :], lse
 
 
-def _bwd_dq_kernel_nl(scale, causal, kv_len, q_len, dropout_rate, d, g,
-                      has_off, has_bias, bias_per_head, has_dbo, refs,
+def _bwd_dq_kernel_nl(scale, causal, kv_len, q_len, dropout_rate, d, d_v,
+                      g, has_off, has_bias, bias_per_head, has_dbo, refs,
                       window=None):
     refs = list(refs)
     q_ref, k_ref, v_ref = refs[:3]
@@ -1131,9 +1136,9 @@ def _bwd_dq_kernel_nl(scale, causal, kv_len, q_len, dropout_rate, d, g,
                           window)
     t = _group_id_outside(fr, dropout_rate)
 
-    def tile(h, sl):
-        q, k, v = q_ref[0][:, sl], k_ref[0][:, sl], v_ref[0][:, sl]
-        do = do_ref[0][:, sl]
+    def tile(h, sl, slv):
+        q, k, v = q_ref[0][:, sl], k_ref[0][:, sl], v_ref[0][:, slv]
+        do = do_ref[0][:, slv]
         bq, bk = q.shape[0], k.shape[0]
         lse = lse_ref[h * bq:(h + 1) * bq, :1]
         delta = dl_ref[h * bq:(h + 1) * bq, :1]
@@ -1162,17 +1167,17 @@ def _bwd_dq_kernel_nl(scale, causal, kv_len, q_len, dropout_rate, d, g,
             preferred_element_type=jnp.float32) * scale
 
     for h in range(g):
-        sl = slice(h * d, (h + 1) * d)
+        sl, slv = slice(h * d, (h + 1) * d), slice(h * d_v, (h + 1) * d_v)
         # as the forward: the skipped tiles trail a row
-        _when_tile_runs(fr, iq, ik)(functools.partial(tile, h, sl))
+        _when_tile_runs(fr, iq, ik)(functools.partial(tile, h, sl, slv))
 
         @pl.when(ik == nk - 1)
         def _(sl=sl):
             dq_ref[0, :, sl] = dq_acc[0][:, sl].astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel_nl(scale, causal, kv_len, q_len, dropout_rate, d, g,
-                       has_off, has_bias, bias_per_head, has_dbo, refs,
+def _bwd_dkv_kernel_nl(scale, causal, kv_len, q_len, dropout_rate, d, d_v,
+                       g, has_off, has_bias, bias_per_head, has_dbo, refs,
                        window=None):
     refs = list(refs)
     q_ref, k_ref, v_ref = refs[:3]
@@ -1191,9 +1196,9 @@ def _bwd_dkv_kernel_nl(scale, causal, kv_len, q_len, dropout_rate, d, g,
                           window)
     t = _group_id_outside(fr, dropout_rate)
 
-    def tile(h, sl):
-        q, k, v = q_ref[0][:, sl], k_ref[0][:, sl], v_ref[0][:, sl]
-        do = do_ref[0][:, sl]
+    def tile(h, sl, slv):
+        q, k, v = q_ref[0][:, sl], k_ref[0][:, sl], v_ref[0][:, slv]
+        do = do_ref[0][:, slv]
         bq, bk = q.shape[0], k.shape[0]
         lse = lse_ref[h * bq:(h + 1) * bq, :1]
         delta = dl_ref[h * bq:(h + 1) * bq, :1]
@@ -1220,7 +1225,7 @@ def _bwd_dkv_kernel_nl(scale, causal, kv_len, q_len, dropout_rate, d, g,
             inv_keep = 1.0 / (1.0 - dropout_rate)
             pv = jnp.where(keep, p * inv_keep, 0.0)
             dp = jnp.where(keep, dp * inv_keep, 0.0)
-        dv_acc[0, :, sl] = dv_acc[0][:, sl] + jax.lax.dot_general(
+        dv_acc[0, :, slv] = dv_acc[0][:, slv] + jax.lax.dot_general(
             pv.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         ds = (p * (dp - delta)).astype(q.dtype)
@@ -1233,7 +1238,8 @@ def _bwd_dkv_kernel_nl(scale, causal, kv_len, q_len, dropout_rate, d, g,
     @_when_tile_runs(fr, iq, ik)
     def _():
         for h in range(g):
-            tile(h, slice(h * d, (h + 1) * d))
+            tile(h, slice(h * d, (h + 1) * d),
+                 slice(h * d_v, (h + 1) * d_v))
 
     @pl.when(iq == nq - 1)
     def _():
@@ -1406,11 +1412,13 @@ class _BwdPlan(NamedTuple):
 
 def _bwd_plan(nh, d, sq, sk, bh, itemsize, block_q, block_k, *,
               dropout_rate=0.0, bias_isz=0, bias_per_head=False,
-              delta_shifted=False) -> _BwdPlan:
+              delta_shifted=False, d_v=None) -> _BwdPlan:
     """Tiles, head group, VMEM limit and kernel form of the native
     backward, from static values alone (tests/test_attention.py
     ``test_backward_plan`` states one shape on each side of each
-    decision). ``bias_isz`` is the bias element size, 0 without a bias.
+    decision). ``bias_isz`` is the bias element size, 0 without a bias;
+    ``d_v`` v's head size (``d`` when None), which v, dO, dv and the dv
+    accumulator have a head.
 
     Every head group here is one of g0·{4, 2, 1} dividing ``nh``
     (:func:`_native_g`), so halving one that is above g0 lands on a
@@ -1419,11 +1427,13 @@ def _bwd_plan(nh, d, sq, sk, bh, itemsize, block_q, block_k, *,
                                   dropout_rate)
     bq = _choose_block(block_q, sq)
     bk = _choose_block(block_k, sk, lane=True)
-    g0 = _native_g0(nh, d)
+    d_v = d if d_v is None else d_v
+    g0 = _native_g0(nh, d, d_v)
 
     def heads(bq_, bk_):
         return _native_g(nh, d, dropout_rate, bq_, bk_, itemsize,
-                         bias_isz=bias_isz, bias_per_head=bias_per_head)
+                         bias_isz=bias_isz, bias_per_head=bias_per_head,
+                         d_v=d_v)
 
     def bias_buf(g_, bufs):
         return ((g_ if bias_per_head else 1) * bq * bk * bias_isz * bufs
@@ -1444,10 +1454,10 @@ def _bwd_plan(nh, d, sq, sk, bh, itemsize, block_q, block_k, *,
         # own bwd ledger — in/out blocks with cross-group
         # triple-buffering, both lane arrays, accumulators, and the
         # live f32 score temporaries — would exceed the raised limit.
-        gd = g * d
-        bwd_est = ((2 * bq + 2 * bk) * gd * itemsize * 3
+        gd, gd_v = g * d, g * d_v
+        bwd_est = ((bq + bk) * (gd + gd_v) * itemsize * 3
                    + 2 * g * bq * LANES * 4 * 3
-                   + 2 * bk * gd * itemsize * 2 + 2 * bk * gd * 4
+                   + bk * (gd + gd_v) * itemsize * 2 + bk * (gd + gd_v) * 4
                    + 3 * bq * bk * 4
                    + bias_buf(g, 3))
         if bwd_est > 32 * 2 ** 20:
@@ -1465,11 +1475,11 @@ def _bwd_plan(nh, d, sq, sk, bh, itemsize, block_q, block_k, *,
         # does not (large-S fp32 shapes).
 
         def fused_est(g_):
-            gd = g_ * d
+            gd, gd_v = g_ * d, g_ * d_v
             lanes = (2 * g_ * bq * LANES * 4 * 2 if delta_shifted
                      else bq * bk * 4)   # self-delta: one extra f32 tile
-            return ((2 * bq + 2 * bk) * gd * itemsize * 2
-                    + (bq + 2 * bk) * gd * itemsize * 2
+            return ((bq + bk) * (gd + gd_v) * itemsize * 2
+                    + ((bq + bk) * gd + bk * gd_v) * itemsize * 2
                     + bq * bk * 4 * 3 + lanes + bias_buf(g_, 2))
 
         gf = g
@@ -1497,12 +1507,13 @@ def _flash_bwd_nl(q2, k2, v2, nh, d, lse, delta, do2, scale, causal,
     b, sq, H = q2.shape
     sk = k2.shape[1]
     bh = b * nh
+    d_v = v2.shape[2] // nh
     bias_per_head = bias_mode in ("head", "full")
     plan = _bwd_plan(
         nh, d, sq, sk, bh, q2.dtype.itemsize, block_q, block_k,
         dropout_rate=dropout_rate,
         bias_isz=bias_g.dtype.itemsize if bias_g is not None else 0,
-        bias_per_head=bias_per_head, delta_shifted=delta_shifted)
+        bias_per_head=bias_per_head, delta_shifted=delta_shifted, d_v=d_v)
     bq, bk, g = plan.bq, plan.bk, plan.g
     sqp = -(-sq // bq) * bq
     skp = -(-sk // bk) * bk
@@ -1529,27 +1540,28 @@ def _flash_bwd_nl(q2, k2, v2, nh, d, lse, delta, do2, scale, causal,
                                    bias_mode=bias_mode, dbo=dbo,
                                    window=window)
 
-    gd = g * d
+    gd, gd_v = g * d, g * d_v
     lse_l = _lanes_nl(lse, bh, g, nq, bq, sq)
     delta_l = _lanes_nl(delta, bh, g, nq, bq, sq)
 
     hg = nh // g
     fr = (None if causal_off is not None
           else _frontier(causal, bq, bk, sq, sk, sk - sq, window))
-    q_spec, k_spec, bias_idx = _head_specs(nh, g, bq, bk, gd, fr)
+    q_spec, k_spec, v_spec, o_spec, bias_idx = _head_specs(
+        nh, g, bq, bk, gd, gd_v, fr)
     lane_spec = pl.BlockSpec((g * bq, LANES),
                              lambda t, i, j: (t * nq + i, 0),
                              memory_space=pltpu.VMEM)
 
     bias_p = None if bias_g is None else _pad_bias_nl(bias_g, sqp, skp)
-    in_specs = [q_spec, k_spec, k_spec]
+    in_specs = [q_spec, k_spec, v_spec]
     args = [qp, kp, vp]
     _append_common(
         in_specs, args, bias_p=bias_p, bias_mode=bias_mode, g=g, hg=hg,
         bias_dims=(bq, bk), bias_idx=bias_idx,
         dropout_rate=dropout_rate, seed=seed, dbo=dbo,
         causal_off=causal_off)
-    in_specs += [q_spec, lane_spec, lane_spec]
+    in_specs += [o_spec, lane_spec, lane_spec]
     args += [dop, lse_l, delta_l]
 
     extra = {}
@@ -1559,7 +1571,7 @@ def _flash_bwd_nl(q2, k2, v2, nh, d, lse, delta, do2, scale, causal,
     dq = pallas_call(
         lambda *refs: functools.partial(
             _bwd_dq_kernel_nl, scale, causal, sk, sq, dropout_rate, d,
-            g, causal_off is not None, bias_p is not None,
+            d_v, g, causal_off is not None, bias_p is not None,
             bias_per_head, dbo is not None, window=window)(refs),
         grid=(bh // g, nq, nk),
         in_specs=in_specs,
@@ -1573,16 +1585,18 @@ def _flash_bwd_nl(q2, k2, v2, nh, d, lse, delta, do2, scale, causal,
     # dk/dv: grid loops q innermost; a skipped step's q / do / lse /
     # delta / bias block is the one its column's first running step takes
     qb = (lambda i, j: i) if fr is None else fr.q_block
-    q_spec_k = pl.BlockSpec((1, bq, gd),
-                            lambda t, j, i: (t // hg, qb(i, j), t % hg),
-                            memory_space=pltpu.VMEM)
-    k_spec_k = pl.BlockSpec((1, bk, gd),
-                            lambda t, j, i: (t // hg, j, t % hg),
-                            memory_space=pltpu.VMEM)
+    q_map_k = lambda t, j, i: (t // hg, qb(i, j), t % hg)
+    k_map_k = lambda t, j, i: (t // hg, j, t % hg)
+    spec_k = lambda rows, w, index: pl.BlockSpec((1, rows, w), index,
+                                                 memory_space=pltpu.VMEM)
+    q_spec_k = spec_k(bq, gd, q_map_k)
+    k_spec_k = spec_k(bk, gd, k_map_k)
+    v_spec_k = spec_k(bk, gd_v, k_map_k)
+    do_spec_k = spec_k(bq, gd_v, q_map_k)
     lane_spec_k = pl.BlockSpec((g * bq, LANES),
                                lambda t, j, i: (t * nq + qb(i, j), 0),
                                memory_space=pltpu.VMEM)
-    in_specs2 = [q_spec_k, k_spec_k, k_spec_k]
+    in_specs2 = [q_spec_k, k_spec_k, v_spec_k]
     args2 = [qp, kp, vp]
     _append_common(
         in_specs2, args2, bias_p=bias_p, bias_mode=bias_mode, g=g,
@@ -1590,19 +1604,21 @@ def _flash_bwd_nl(q2, k2, v2, nh, d, lse, delta, do2, scale, causal,
         bias_idx=lambda row: lambda t, j, i: (row(t), qb(i, j), j),
         dropout_rate=dropout_rate, seed=seed, dbo=dbo,
         causal_off=causal_off)
-    in_specs2 += [q_spec_k, lane_spec_k, lane_spec_k]
+    in_specs2 += [do_spec_k, lane_spec_k, lane_spec_k]
     args2 += [dop, lse_l, delta_l]
 
     dk, dv = pallas_call(
         lambda *refs: functools.partial(
             _bwd_dkv_kernel_nl, scale, causal, sk, sq, dropout_rate, d,
-            g, causal_off is not None, bias_p is not None,
+            d_v, g, causal_off is not None, bias_p is not None,
             bias_per_head, dbo is not None, window=window)(refs),
         grid=(bh // g, nk, nq),
         in_specs=in_specs2,
-        out_specs=(k_spec_k, k_spec_k),
-        out_shape=(jax.ShapeDtypeStruct((b, skp, H), k2.dtype),) * 2,
-        scratch_shapes=[pltpu.VMEM((1, bk, gd), jnp.float32)] * 2,
+        out_specs=(k_spec_k, v_spec_k),
+        out_shape=(jax.ShapeDtypeStruct((b, skp, H), k2.dtype),
+                   jax.ShapeDtypeStruct((b, skp, nh * d_v), k2.dtype)),
+        scratch_shapes=[pltpu.VMEM((1, bk, gd), jnp.float32),
+                        pltpu.VMEM((1, bk, gd_v), jnp.float32)],
         name="apex_attn_bwd_dkv",
         **extra,
     )(*args2)
@@ -1623,9 +1639,16 @@ def flash_attention(q, k, v, bias=None, scale=None, causal=False,
     ``h // (H / H_kv)``); bias: optional additive
     (B|1, H|1, Sq|1, Sk|1) — the additive-mask variants of the reference
     (`self_multihead_attn_func.py` additive mask path). Returns
-    (B, Sq, H, D). ``bias`` is differentiable (learned relative-position
+    (B, Sq, H, D_v). ``bias`` is differentiable (learned relative-position
     biases work); its gradient path materializes O(S²) scores, computed
     only when actually requested (see ``_bias_grad``).
+
+    ``v`` may have a smaller head size ``D_v`` than q and k (latent
+    attention's 128 beside 192). The two native multi-block backward
+    kernels read ``v`` and ``dO`` and write ``dv`` at ``D_v``
+    (:func:`_v_as_it_is`), and the forward's ``o`` is kept at ``D_v``; the
+    forward kernel, and every other path, gets ``v`` zero-padded to ``D``
+    and ``o`` sliced back, which is the same result.
 
     Shared k/v heads reach the kernels repeated a group (in HBM, outside
     the ``custom_vjp``: the repeat's own transpose sums ``dk`` and ``dv``
@@ -1673,9 +1696,37 @@ def flash_attention(q, k, v, bias=None, scale=None, causal=False,
         k, v = (jnp.repeat(x, q.shape[2] // x.shape[2], 2) for x in (k, v))
     window = _band(window, q, k, bias, causal, dropout_rate, causal_offset)
     block_q, block_k = _window_blocks(window, block_q, block_k)
-    return _flash_attention(q, k, v, bias, scale, causal, block_q, block_k,
-                            dropout_rate, dropout_seed, causal_offset,
-                            window)
+    d_v = v.shape[-1]
+    if d_v != q.shape[-1] and not _v_as_it_is(q, k, v, bias, block_q,
+                                              block_k, dropout_rate):
+        v = _v_padded(v, q.shape[-1])
+    o = _flash_attention(q, k, v, bias, scale, causal, block_q, block_k,
+                         dropout_rate, dropout_seed, causal_offset, window)
+    return o if o.shape[-1] == d_v else o[..., :d_v]
+
+
+def _v_padded(v, d):
+    """``v`` zero-padded to the q/k head size ``d``, for the paths that
+    take one head size: the zero columns give ``o`` zero columns, which
+    the caller slices off."""
+    if v.shape[-1] > d:
+        raise ValueError(f"v's head size {v.shape[-1]} is above q's {d}")
+    return jnp.pad(v, [(0, 0)] * 3 + [(0, d - v.shape[-1])])
+
+
+def _v_as_it_is(q, k, v, bias, block_q, block_k, dropout_rate):
+    """Whether a ``v`` narrower than q and k reaches the backward kernels
+    at its own head size: on the native path, without a bias, where the
+    backward takes the two multi-block kernels (which read v and dO and
+    write dv ``g·D_v`` lanes wide). The single-block fused backward, the
+    transposed kernels and the bias gradient take one head size."""
+    b, sq, h, d = q.shape
+    if bias is not None or _native_g0(h, d, v.shape[-1]) is None:
+        return False
+    block_q, block_k = _tuned_qk(q, k, block_q, block_k, dropout_rate)
+    return _bwd_plan(h, d, sq, k.shape[1], b * h, q.dtype.itemsize, block_q,
+                     block_k, dropout_rate=dropout_rate,
+                     d_v=v.shape[-1]).form != "fused"
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 11))
@@ -1854,7 +1905,8 @@ def _flash_attention_fwd_res(q, k, v, bias, dropout_seed, scale, causal,
                 f"kernel blocks; this geometry realizes "
                 f"({bq_r}, {bk_r}) — shard lengths must be multiples "
                 f"of {DROPOUT_TILE}")
-    if _native_g0(h, d) is not None:
+    d_v = v.shape[-1]
+    if _native_g0(h, d, d_v) is not None:
         # native-layout path: (B, S, H) operands straight through — no
         # transpose copies, no D zero-pad (see the native-kernel block).
         # An additive bias rides the native grid as (g|1, bq, bk)
@@ -1862,12 +1914,18 @@ def _flash_attention_fwd_res(q, k, v, bias, dropout_seed, scale, causal,
         bias_nl, bias_mode = _bias_group_nl(bias, b, h, sq, k.shape[1])
         q2 = q.reshape(b, sq, h * d)
         k2 = k.reshape(b, k.shape[1], h * d)
-        v2 = v.reshape(b, v.shape[1], h * d)
+        # the forward kernel takes v at q's head size: on the v5e it ran
+        # slower with v at its own 128 beside q's 192 than with v
+        # zero-padded (23.3 against 19.2 ms a call at the MLA cells'
+        # shape), where the two backward kernels ran 15% faster
+        v2 = (v if d_v == d else _v_padded(v, d)).reshape(
+            b, v.shape[1], h * d)
         o2, lse = _flash_fwd_nl(q2, k2, v2, h, d, scale, causal,
                                 block_q, block_k, dropout_rate, seed,
                                 causal_off=off, bias_g=bias_nl,
                                 bias_mode=bias_mode, dbo=dbo, window=window)
-        o, lse = _kept(o2.reshape(b, sq, h, d), lse)
+        o = o2.reshape(b, sq, h, d)
+        o, lse = _kept(o if d_v == d else o[..., :d_v], lse)
         return o, (q, k, v, bias, dropout_seed, o, lse, causal_offset)
     eff_bias, eff_causal = bias, causal
     if off is not None:
@@ -1898,12 +1956,13 @@ def _fa_bwd(scale, causal, block_q, block_k, dropout_rate, window, res, do):
     sk = k.shape[1]
     scale_ = scale if scale is not None else 1.0 / np.sqrt(d)
     seed = _seed_arr(dropout_seed, dropout_rate)
-    if _native_g0(h, d) is not None:
+    d_v = v.shape[-1]
+    if _native_g0(h, d, d_v) is not None:
         bias_nl, bias_mode = _bias_group_nl(bias, b, h, sq, sk)
         q2 = q.reshape(b, sq, h * d)
         k2 = k.reshape(b, sk, h * d)
-        v2 = v.reshape(b, sk, h * d)
-        do2 = do.reshape(b, sq, h * d)
+        v2 = v.reshape(b, sk, h * d_v)
+        do2 = do.reshape(b, sq, h * d_v)
         # delta = rowsum(do·o) per head, straight from the (B, S, H)
         # layout: only the tiny (B, S, nh) per-head sums transpose
         delta = jnp.sum(
@@ -1919,7 +1978,7 @@ def _fa_bwd(scale, causal, block_q, block_k, dropout_rate, window, res, do):
             dropout_rate=dropout_rate, seed=seed,
             block_q=block_q, block_k=block_k)
         return (dq2.reshape(b, sq, h, d), dk2.reshape(b, sk, h, d),
-                dv2.reshape(b, sk, h, d), dbias, None, None)
+                dv2.reshape(b, sk, h, d_v), dbias, None, None)
     eff_bias, eff_causal = bias, causal
     off = _off_arr(causal_offset, causal)
     if off is not None:
@@ -2071,13 +2130,18 @@ def flash_attention_lse(q, k, v, bias=None, scale=None, causal=False,
     of ``causal_offset`` historically, so a positional caller would
     silently bind an offset to ``dropout_rate`` — now it fails loudly
     at the call site (ADVICE r5). ``window`` is :func:`flash_attention`'s
-    (q and k with the same heads here).
+    (q and k with the same heads here). A ``v`` narrower than q and k is
+    zero-padded to their head size and ``o`` sliced back.
     """
     window = _band(window, q, k, bias, causal, dropout_rate, causal_offset)
     block_q, block_k = _window_blocks(window, block_q, block_k)
-    return _flash_attention_lse(q, k, v, bias, scale, causal, block_q,
-                                block_k, dropout_rate, dropout_seed,
-                                causal_offset, dropout_block_offset, window)
+    d_v = v.shape[-1]
+    if d_v != q.shape[-1]:
+        v = _v_padded(v, q.shape[-1])
+    o, lse = _flash_attention_lse(q, k, v, bias, scale, causal, block_q,
+                                  block_k, dropout_rate, dropout_seed,
+                                  causal_offset, dropout_block_offset, window)
+    return (o if o.shape[-1] == d_v else o[..., :d_v]), lse
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 12))

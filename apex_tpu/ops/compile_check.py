@@ -490,21 +490,22 @@ def _no_causal_skip():
 
 
 def _causal_skip_case(batch, heads, kv_heads, d, t=8192, tiles=(),
-                      window=None):
+                      window=None, d_v=None):
     """A cell's causal call over all its tokens, forward and the three
     gradients, with the tiles above the frontier skipped and not fetched
     against the same kernels running their whole grid: a skipped tile would
     have added ``p = 0`` under ``alpha = 1``, so the two agree bit for bit
     (a zero's sign aside). The oracle cases hold a prefix of the rows; this
     one holds every row, the last q tiles' long k loops among them. Under a
-    ``window`` the tiles left of the band are skipped too."""
+    ``window`` the tiles left of the band are skipped too. ``d_v``: v's head
+    size where it is not q's and k's."""
     from apex_tpu.ops.attention import (DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q,
                                         _causal_tiles, _window_blocks,
                                         flash_attention)
     q = _rand((batch, t, heads, d), 0, jnp.bfloat16, 0.5)
     k = _rand((batch, t, kv_heads, d), 1, jnp.bfloat16, 0.5)
-    v = _rand((batch, t, kv_heads, d), 2, jnp.bfloat16, 0.5)
-    w = _rand((batch, t, heads, d), 3, jnp.float32, 0.5)
+    v = _rand((batch, t, kv_heads, d_v or d), 2, jnp.bfloat16, 0.5)
+    w = _rand((batch, t, heads, d_v or d), 3, jnp.float32, 0.5)
     run, grid = _causal_tiles(*(tiles or _window_blocks(
         window, DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K)), t, t, True, window)
     assert run < grid, (run, grid)
@@ -533,9 +534,10 @@ def _causal_skip_case(batch, heads, kv_heads, d, t=8192, tiles=(),
 
 @case("attention/causal-skip-mla-d192-s8192-cell")
 def _():
-    # Kimi's latent attention: 32 heads of 192 at the 1024 x 256 tiles
-    # models/kimi_linear.py passes, two heads a step: 144 of 256 tiles run
-    _causal_skip_case(1, 32, 32, 192, tiles=(1024, 256))
+    # both MLA cells' latent attention: 32 heads of 192, v at its own 128,
+    # at the 1024 x 256 tiles models/mla.py passes, two heads a step: 144 of
+    # 256 tiles run
+    _causal_skip_case(1, 32, 32, 192, tiles=(1024, 256), d_v=128)
 
 
 @case("attention/causal-skip-gqa-d64-s8192-cell")

@@ -1,9 +1,12 @@
 """The decoder cells' causal attention alone, timed on the chip: the op
 (`apex_attn_fwd`, `apex_attn_bwd_dq`, `apex_attn_bwd_dkv`) forward, and
-forward with the three gradients, at the shapes the three decoder cells run
-it: Kimi's latent attention (32 heads of 192, 1024 x 256 tiles), LFM2's
-grouped-query attention (two sequences, 32 heads on 8 of 64) and Qwen3-Next's
-(16 heads on 2 of 256), bfloat16, 8192 tokens, causal.
+forward with the three gradients, at the shapes the decoder cells run it:
+the two MLA cells' latent attention (32 heads of 192 with v at 128, 1024 x 256
+tiles; "kimi mla d192" with v zero-padded to 192 and o sliced back around the
+op, as the model passed it before the kernels took v at its own head size,
+"mla d192 dv128" as it passes it now), LFM2's grouped-query attention (two
+sequences, 32 heads on 8 of 64) and Qwen3-Next's (16 heads on 2 of 256),
+bfloat16, 8192 tokens, causal.
 
 Usage: python scripts/perf_attention_cells.py [--tokens 8192] [--iters 20]
            [--whole-grid] [--fetch-all]
@@ -13,7 +16,9 @@ tiles above the causal frontier (every grid step runs and fetches);
 `--fetch-all` again with the arithmetic skipped and the index maps left
 unclamped, which is what a traced `causal_offset` gets. Each line gives the
 time, the tiles run of the grid, and TFLOP/s on the causal count (half of
-the full square's matmuls: 2 in the forward, 7 with the backward's).
+the full square's matmuls: 2 in the forward, 7 with the backward's, each at
+the head size of q and k or of v, whichever it contracts or makes; the padded
+row is counted at v's 128 too).
 """
 
 import argparse
@@ -30,11 +35,13 @@ import jax.numpy as jnp
 from apex_tpu.models.mla import _ATTN_TILES
 from apex_tpu.ops import attention as A
 
-#: name, batch, q heads, k/v heads, head size, tiles (empty: the op's own)
+#: name, batch, q heads, k/v heads, q/k head size, v head size, v padded to
+#: the q/k head size around the op, tiles (empty: the op's own)
 SHAPES = [
-    ("kimi mla d192", 1, 32, 32, 192, _ATTN_TILES),
-    ("lfm2 gqa d64", 2, 32, 8, 64, ()),
-    ("qwen gqa d256", 1, 16, 2, 256, ()),
+    ("kimi mla d192", 1, 32, 32, 192, 128, True, _ATTN_TILES),
+    ("mla d192 dv128", 1, 32, 32, 192, 128, False, _ATTN_TILES),
+    ("lfm2 gqa d64", 2, 32, 8, 64, 64, False, ()),
+    ("qwen gqa d256", 1, 16, 2, 256, 256, False, ()),
 ]
 
 
@@ -49,14 +56,19 @@ def measure(fn, args, iters):
     return (time.perf_counter() - start) / iters * 1e3
 
 
-def cases(d, tiles, weight):
+def cases(d, d_v, padded, tiles, weight):
+    """(what, matmuls at the q/k head size and at v's, function)."""
     # fresh functions a call: `jax.jit` caches by the function it is given,
     # and the frontier is consulted while the kernels are traced
-    op = lambda q, k, v: A.flash_attention(q, k, v, None, d ** -0.5, True,
-                                           *tiles)
+    def op(q, k, v):
+        if padded:
+            v = jnp.pad(v, [(0, 0)] * 3 + [(0, d - d_v)])
+        o = A.flash_attention(q, k, v, None, d ** -0.5, True, *tiles)
+        return o[..., :d_v]
     loss = lambda q, k, v: jnp.sum(op(q, k, v).astype(jnp.float32) * weight)
-    return [("forward", 2, op),
-            ("forward + backward", 9, jax.grad(loss, argnums=(0, 1, 2)))]
+    return [("forward", (1, 1), op),
+            ("forward + backward", (5, 4),
+             jax.grad(loss, argnums=(0, 1, 2)))]
 
 
 def main():
@@ -79,12 +91,13 @@ def main():
     if a.whole_grid:
         forms.append(("whole grid", {"frontier": lambda *args: None}))
     t = a.tokens
-    for name, batch, heads, kv_heads, d, tiles in SHAPES:
+    for name, batch, heads, kv_heads, d, d_v, padded, tiles in SHAPES:
         keys = jax.random.split(jax.random.PRNGKey(0), 4)
         q = jax.random.normal(keys[0], (batch, t, heads, d), jnp.bfloat16)
         k = jax.random.normal(keys[1], (batch, t, kv_heads, d), jnp.bfloat16)
-        v = jax.random.normal(keys[2], (batch, t, kv_heads, d), jnp.bfloat16)
-        weight = jax.random.normal(keys[3], (batch, t, heads, d))
+        v = jax.random.normal(keys[2], (batch, t, kv_heads, d_v),
+                              jnp.bfloat16)
+        weight = jax.random.normal(keys[3], (batch, t, heads, d_v))
         bq, bk = tiles or (A.DEFAULT_BLOCK_Q, A.DEFAULT_BLOCK_K)
         run, grid = A._causal_tiles(A._choose_block(bq, t),
                                     A._choose_block(bk, t, lane=True),
@@ -94,9 +107,11 @@ def main():
             A._Frontier.k_block = patch.get("k_block", k_block)
             A._Frontier.q_block = patch.get("q_block", q_block)
             ran = grid if "frontier" in patch else run
-            for what, matmuls, fn in cases(d, tiles, weight):
+            for what, (at_d, at_v), fn in cases(d, d_v, padded, tiles,
+                                                weight):
                 ms = measure(fn, (q, k, v), a.iters)
-                flops = matmuls * batch * heads * t * t * d  # causal: half
+                # causal: half the square
+                flops = (at_d * d + at_v * d_v) * batch * heads * t * t
                 print(f"{name}, {tag}, {what}: {ms:.3f} ms, {ran} of {grid} "
                       f"tiles, {flops / ms / 1e9:.1f} TFLOP/s causal",
                       flush=True)

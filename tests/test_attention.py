@@ -175,6 +175,107 @@ class TestGroupedQueryAndHead256:
                                    np.asarray(want), atol=3e-2)
 
 
+def _narrow_v_case(id_, d_v_native, s=512, h=2, hkv=2, d=192, d_v=128,
+                   tiles=(128, 256), **kw):
+    return pytest.param(dict(s=s, h=h, hkv=hkv, d=d, d_v=d_v, tiles=tiles,
+                             native=d_v_native, kw=kw), id=id_)
+
+
+class TestValueHeadSize:
+    """``v`` with a smaller head size than q and k (latent attention: 128
+    beside 192). The native multi-block backward kernels take it as it is;
+    the forward kernel and every other path take it through the op's own
+    zero-pad. Either way forward and the three gradients are the
+    reference's, and the padded call's (what the model passed before) to
+    float32 rounding."""
+
+    @pytest.mark.parametrize("case", [
+        # the cells' shape cut down: causal, several tiles, two heads a step
+        _narrow_v_case("causal-multi-block-g2", True),
+        _narrow_v_case("shared-kv-heads", True, h=4, hkv=2),
+        _narrow_v_case("non-causal-padded-rows", True, s=450, causal=False),
+        _narrow_v_case("dropout", True, dropout_rate=0.2, dropout_seed=3),
+        _narrow_v_case("transposed-fallback", False, s=200, d=48, d_v=32,
+                       tiles=(128, 128)),
+        _narrow_v_case("single-block-fused", False, s=256,
+                       tiles=(256, 256)),
+        _narrow_v_case("bias", False, bias=True),
+        _narrow_v_case("lse-variant", False, lse=True),
+    ])
+    def test_matches_the_reference_and_the_padded_call(self, case,
+                                                       monkeypatch):
+        s, h, hkv, d, d_v = (case[k] for k in ("s", "h", "hkv", "d", "d_v"))
+        kw = dict(case["kw"])
+        causal, lse = kw.pop("causal", True), kw.pop("lse", False)
+        rng = np.random.RandomState(11)
+        q = jnp.asarray(rng.randn(1, s, h, d), jnp.float32)
+        k = jnp.asarray(rng.randn(1, s, hkv, d), jnp.float32)
+        v = jnp.asarray(rng.randn(1, s, hkv, d_v), jnp.float32)
+        w = jnp.asarray(rng.randn(1, s, h, d_v), jnp.float32)
+        bias = (jnp.asarray(rng.randn(1, h, s, s), jnp.float32) * 0.3
+                if kw.pop("bias", False) else None)
+        widths = []             # v's head size at the native backward
+        real = A._flash_bwd_nl
+
+        def spy(q2, k2, v2, *a, **kw):
+            widths.append(v2.shape[-1] // h)
+            return real(q2, k2, v2, *a, **kw)
+
+        monkeypatch.setattr(A, "_flash_bwd_nl", spy)
+
+        def op(q, k, v):
+            if lse:
+                return A.flash_attention_lse(q, k, v, bias, None, causal,
+                                             *case["tiles"])[0]
+            return A.flash_attention(q, k, v, bias, None, causal,
+                                     *case["tiles"], **kw)
+
+        def padded(q, k, v):
+            return op(q, k, jnp.pad(v, [(0, 0)] * 3 + [(0, d - d_v)]))[
+                ..., :d_v]
+
+        def reference(q, k, v):
+            return A.attention_reference(q, k, v, bias, None, causal)
+
+        def run(fn):
+            return jax.jit(jax.value_and_grad(
+                lambda q, k, v: jnp.sum(fn(q, k, v) * w),
+                argnums=(0, 1, 2)))(q, k, v), jax.jit(fn)(q, k, v)
+
+        (_, got), o = run(op)
+        assert (d_v in widths) == case["native"], widths
+        assert o.shape == (1, s, h, d_v) and got[2].shape == v.shape
+        (_, was), o_was = run(padded)
+        for name, a, b in zip(("o", "dq", "dk", "dv"), (o, *got),
+                              (o_was, *was)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-6, atol=1e-6, err_msg=name)
+        if kw.get("dropout_rate"):
+            return                  # the reference draws no keep mask
+        (_, want), o_want = run(reference)
+        for name, a, b in zip(("o", "dq", "dk", "dv"), (o, *got),
+                              (o_want, *want)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=2e-4, err_msg=name)
+
+    @pytest.mark.parametrize("nh,d,d_v,g0,g", [
+        (32, 192, 128, 2, 2),       # latent attention: v's 128 adds nothing
+        (32, 192, 192, 2, 2),       # the same with v padded to 192
+        (16, 64, 32, 4, 4),         # v's 32 asks for four heads a step
+        (2, 64, 32, None, None),    # ... which two heads cannot give
+    ])
+    def test_native_head_group(self, nh, d, d_v, g0, g):
+        """The smallest head group lane-aligns both head sizes; the forward
+        ledger counts v, o and the accumulator at ``d_v``, and with ``d_v``
+        equal to ``d`` it is the ledger without it."""
+        assert A._native_g0(nh, d, d_v) == g0
+        if g0 is None:
+            return
+        assert A._native_g(nh, d, 0.0, 1024, 256, 2, d_v=d_v) == g
+        if d_v == d:
+            assert A._native_g(nh, d, 0.0, 1024, 256, 2) == g
+
+
 class TestMHAModules:
     @pytest.mark.parametrize("norm_add", [False, True])
     def test_self_attn_fast_vs_default(self, norm_add):
@@ -645,9 +746,9 @@ class TestCausalOffset:
 
 
 def _causal_case(id_, sq=512, sk=None, nh=2, d=64, tiles=(128, 128),
-                 off=None, **kw):
+                 off=None, d_v=None, **kw):
     return pytest.param(dict(sq=sq, sk=sk or sq, nh=nh, d=d, tiles=tiles,
-                             off=off, kw=kw), id=id_)
+                             off=off, d_v=d_v or d, kw=kw), id=id_)
 
 
 class TestCausalTileSkip:
@@ -657,19 +758,26 @@ class TestCausalTileSkip:
     added ``p = 0`` under ``alpha = 1``: forward and the three gradients
     are the unskipped kernels' bit for bit (a zero's sign aside), which is
     what every case holds them to, the skip disabled by patching
-    ``_frontier``."""
+    ``_frontier``. A ``v`` narrower than q and k goes through
+    ``flash_attention``, which hands it to the backward kernels as it is
+    (the lse variant pads it): no lse to compare there."""
 
     @staticmethod
     def _run(case, rng, bias=None):
-        sq, sk, nh, d = (case[k] for k in ("sq", "sk", "nh", "d"))
+        sq, sk, nh, d, d_v = (case[k]
+                              for k in ("sq", "sk", "nh", "d", "d_v"))
         q = jnp.asarray(rng.randn(1, sq, nh, d), jnp.float32)
         k = jnp.asarray(rng.randn(1, sk, nh, d), jnp.float32)
-        v = jnp.asarray(rng.randn(1, sk, nh, d), jnp.float32)
-        w = jnp.asarray(rng.randn(1, sq, nh, d), jnp.float32)
+        v = jnp.asarray(rng.randn(1, sk, nh, d_v), jnp.float32)
+        w = jnp.asarray(rng.randn(1, sq, nh, d_v), jnp.float32)
         bq, bk = case["tiles"]
 
         def both(q, k, v, off):
             def loss(q, k, v):
+                if d_v != d:
+                    o = A.flash_attention(q, k, v, bias, None, True, bq, bk,
+                                          causal_offset=off, **case["kw"])
+                    return jnp.sum(o * w), (o, None)
                 o, lse = A.flash_attention_lse(
                     q, k, v, bias, None, True, bq, bk, causal_offset=off,
                     **case["kw"])
@@ -710,6 +818,10 @@ class TestCausalTileSkip:
                      tiles=(256, 128)),
         _causal_case("traced-offset-minus-200-d192", off=-200, d=192,
                      tiles=(128, 256)),
+        _causal_case("square-128x256-g2-d192-dv128", d=192, d_v=128,
+                     tiles=(128, 256)),
+        _causal_case("traced-offset-minus-200-d192-dv128", off=-200, d=192,
+                     d_v=128, tiles=(128, 256)),
     ])
     def test_bit_identical_to_the_unskipped_kernels(self, case, monkeypatch):
         case = dict(case, kw=dict(case["kw"]))
@@ -735,6 +847,8 @@ class TestCausalTileSkip:
         off = sk - sq if case["off"] is None else case["off"]
         seen = np.arange(sq) + off >= 0     # rows with a key to attend
         for name, a, b in zip(("o", "lse", "dq", "dk", "dv"), got, want):
+            if a is None:
+                continue
             a, b = np.asarray(a), np.asarray(b)
             assert np.all(np.isfinite(a)), name
             if name == "o":
@@ -1072,6 +1186,12 @@ MIB = 2 ** 20
     pytest.param(dict(batch=1, s=8192, d=192, nh=32, itemsize=2,
                       blocks=(1024, 256)),
                  (1024, 256, 2, None, "two_kernel"), id="mla-not-raised"),
+    # the same with v at its own 128 (both MLA cells): v, dO, dv and the dv
+    # accumulator a third narrower, the same plan
+    pytest.param(dict(batch=1, s=8192, d=192, nh=32, itemsize=2,
+                      blocks=(1024, 256), d_v=128, causal=True,
+                      tiles=(144, 256)),
+                 (1024, 256, 2, None, "two_kernel"), id="mla-v128"),
     pytest.param(dict(batch=4, s=2048, d=64, nh=16, itemsize=2),
                  (1024, 1024, 2, 32 * MIB, "two_kernel_raised"),
                  id="1024sq-raised"),

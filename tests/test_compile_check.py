@@ -89,23 +89,23 @@ def test_the_expert_layers_cell_cases_run_at_a_small_size(monkeypatch):
     cc._experts_cell_case(64, 2, 16, 4, 32, 16)
 
 
-@pytest.mark.parametrize("heads,kv_heads,d,tiles", [
-    pytest.param(2, 2, 192, (128, 256), id="mla-d192"),
-    pytest.param(4, 1, 64, (128, 128), id="gqa-d64"),
-    pytest.param(2, 1, 256, (256, 128), id="gqa-d256")])
+@pytest.mark.parametrize("heads,kv_heads,d,tiles,d_v", [
+    pytest.param(2, 2, 192, (128, 256), 128, id="mla-d192"),
+    pytest.param(4, 1, 64, (128, 128), None, id="gqa-d64"),
+    pytest.param(2, 1, 256, (256, 128), None, id="gqa-d256")])
 def test_the_causal_skip_cell_cases_run_at_a_small_size(heads, kv_heads, d,
-                                                         tiles):
+                                                         tiles, d_v):
     """``attention/causal-skip-*-s8192-cell`` hold the three decoder cells'
-    heads and tiles over 8192 tokens on the chip: the skipped grid against
-    the whole grid, bit for bit. Here the same case, interpreted, at 512
-    tokens in several tiles."""
+    heads and tiles over 8192 tokens on the chip (MLA's v at its own 128):
+    the skipped grid against the whole grid, bit for bit. Here the same
+    case, interpreted, at 512 tokens in several tiles."""
     names = [n for n, _ in cc.CASES]
     for name in ("attention/causal-skip-mla-d192-s8192-cell",
                  "attention/causal-skip-gqa-d64-s8192-cell",
                  "attention/causal-skip-gqa-d256-s8192-cell",
                  "attention/causal-skip-no-extra-dispatch"):
         assert name in names
-    cc._causal_skip_case(1, heads, kv_heads, d, t=512, tiles=tiles)
+    cc._causal_skip_case(1, heads, kv_heads, d, t=512, tiles=tiles, d_v=d_v)
 
 
 def test_the_window_cell_cases_run_at_a_small_size():

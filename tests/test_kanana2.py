@@ -317,6 +317,68 @@ def test_kernels_in_the_lowered_step(monkeypatch):
     assert kernels["apex_attn_bwd_dq"] == kernels["apex_attn_bwd_dkv"] == 2
 
 
+def _attention_operands_and_pads(model, length):
+    """The differentiated O1 loss of ``model`` on ``length`` tokens: for
+    each attention kernel the columns of its v operand, and for every
+    ``pad`` under ``mla/`` its last size and scope path."""
+    tokens = jnp.zeros((1, length), jnp.int32)
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(3), tokens))["params"]
+    policy = amp.Policy.from_opt_level("O1")
+
+    def loss(p):
+        with amp.auto_cast(policy):
+            return models.lm_loss(model, {"params": p}, tokens)[0]
+
+    kernels, pads = [], []
+
+    def walk(jaxpr, outer=""):
+        for eqn in jaxpr.eqns:
+            path = f"{outer}/{eqn.source_info.name_stack}"
+            if eqn.primitive.name == "pallas_call":
+                if eqn.params["name"].startswith("apex_attn"):
+                    kernels.append((eqn.params["name"],
+                                    eqn.invars[2].aval.shape[-1]))
+                continue
+            if eqn.primitive.name == "pad" and "mla/" in path:
+                pads.append((eqn.outvars[0].aval.shape[-1], path))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub, path)
+
+    walk(jax.make_jaxpr(jax.value_and_grad(loss))(params).jaxpr)
+    return kernels, pads
+
+
+@pytest.mark.parametrize("name", ["kanana2", "kimi_linear"])
+def test_the_kernels_take_v_at_its_own_head_size(name):
+    """Both MLA cells' step at their published head sizes (q and k 128 +
+    64, v 128; two heads, one kernel step's group) over 1024 tokens in the
+    tiles ``models/mla.py`` passes: the two backward kernels read ``v`` at
+    two heads of 128 columns, the forward kernel at two of 192 (the op pads
+    it there, inside ``mla/attn``'s forward), and nothing else under
+    ``mla/`` pads to the q/k head size of 192: not the model (``v`` under
+    ``mla/proj``), not the backward (``dO``); the rotation's backward,
+    which puts q's two parts back together, aside."""
+    config = json.loads((ROOT / "benchmark" / "configs" / f"{name}.json")
+                        .read_text())
+    sizes = {**config, **config["toy"], "num_attention_heads": 2,
+             "num_key_value_heads": 2, "qk_nope_head_dim": 128,
+             "qk_rope_head_dim": 64, "v_head_dim": 128}
+    if name == "kanana2":
+        sizes.update(num_hidden_layers=2, head_dim=64, qk_head_dim=192)
+        model = models.deepseek_v3_from_config(sizes, remat=True)
+    else:
+        model = models.kimi_linear_from_config(sizes, remat=True)
+    kernels, pads = _attention_operands_and_pads(model, 1024)
+    assert set(kernels) == {("apex_attn_fwd", 2 * 192),
+                            ("apex_attn_bwd_dq", 2 * 128),
+                            ("apex_attn_bwd_dkv", 2 * 128)}
+    padded = [path for width, path in pads
+              if width == 128 + 64 and "mla/rope" not in path]
+    assert padded and all("mla/attn" in path and "transpose(" not in path
+                          for path in padded), padded
+
+
 def test_o1_model_is_near_the_reference(toy):
     """Under ``auto_cast`` the matmuls run in bfloat16 with float32
     accumulation; the rotation, the router and the norms stay float32. On

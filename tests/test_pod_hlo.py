@@ -477,17 +477,20 @@ def v5e_chip():
 def test_mla_attention_compiles_for_the_v5e_at_the_cell_s_shape(
         v5e_chip, monkeypatch):
     """``kimi_linear.lm_s8192_b1``'s attention, and
-    ``kanana2.lm_s8192_b1_v16k``'s: 32 heads of 192, causal, 8192 tokens,
-    forward and both backward kernels through Mosaic, at the tiles
-    ``models/mla.py`` passes. Interpret mode cannot see what this sees: the
-    kernels' default 1024 x 1024 tiles take 17.7 MiB of the 16 MiB of scoped
-    VMEM at this head size."""
+    ``kanana2.lm_s8192_b1_v16k``'s: 32 heads of 192 with v at its own 128,
+    causal, 8192 tokens, forward and both backward kernels through Mosaic,
+    at the tiles ``models/mla.py`` passes. Interpret mode cannot see what
+    this sees: the kernels' default 1024 x 1024 tiles took 17.7 MiB of the
+    16 MiB of scoped VMEM at this head size (v padded to 192), and the v
+    side's blocks, 256 lanes a step, are whole lane tiles."""
     from apex_tpu import ops
     from apex_tpu.models import mla
     from apex_tpu.ops import _dispatch, attention
     for mod in (_dispatch, attention):
         monkeypatch.setattr(mod, "use_interpret", lambda: False)
     x = jax.ShapeDtypeStruct((1, 8192, 32, 192), jnp.bfloat16,
+                             sharding=v5e_chip)
+    v = jax.ShapeDtypeStruct((1, 8192, 32, 128), jnp.bfloat16,
                              sharding=v5e_chip)
 
     def loss(q, k, v):
@@ -498,7 +501,7 @@ def test_mla_attention_compiles_for_the_v5e_at_the_cell_s_shape(
     # the suite's "highest" is for float32 oracles; the chip runs the default
     with jax.default_matmul_precision("default"):
         compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-            x, x, x).compile()
+            x, x, v).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 3      # forward, dq, dk/dv
     for kernel in ("apex_attn_fwd", "apex_attn_bwd_dq", "apex_attn_bwd_dkv"):

@@ -15,7 +15,12 @@ change that means to alter one of these programs updates its digest here,
 and says why. ``delta-rule-per-channel-kernels-d128`` holds the delta
 rule's per-channel kernels (Kimi's cell) to the text they had before the
 kernels of one decay a head were added beside them: no cell's toy step
-reaches either at its 16-wide heads.
+reaches either at its 16-wide heads. Kimi's digest held when latent
+attention stopped padding ``v`` to the q/k head size in the model: its toy's
+two heads of 32 (``v`` 16) take the transposed kernels, where the op pads
+``v`` and slices ``o`` itself, op for op as the model did; only the cells'
+real heads (192 and 128, two a kernel step) reach the kernels that take
+``v`` at its own head size.
 """
 
 import hashlib
